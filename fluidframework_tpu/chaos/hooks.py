@@ -1,8 +1,7 @@
 """Seam installers: arm/disarm a FaultPlane across the service seams.
 
 Every seam is a duck-typed ``fault_plane`` attribute (``None`` when
-disarmed — one predictable branch on the hot path, see BENCH_r05
-criterion) or, for the socket transport, a module-global hook captured
+disarmed — one predictable branch on the hot path) or, for the socket transport, a module-global hook captured
 at connection construction. The service never imports chaos; chaos
 reaches down and installs itself — which is exactly the layering the
 fluidlint DAG enforces (``chaos`` may import service/driver/utils;

@@ -55,6 +55,8 @@ from ..mergetree.client import MergeTreeClient
 from ..mergetree.ops import op_to_wire
 from ..obs import get_recorder, tier_counters
 from ..protocol.messages import DocumentMessage, MessageType
+from ..service.durable_log import log_channel_stream
+from ..service.synthetic import chanop_of
 from ..utils.telemetry import Counters
 from .hooks import install
 from .monitor import InvariantMonitor, InvariantViolation, doc_fingerprint
@@ -82,16 +84,7 @@ def _chan_msg(cseq: int, ref_seq: int, wire_op: dict) -> DocumentMessage:
 
 def _chan_contents(m):
     """The merge-tree wire op inside a sequenced message, or None."""
-    if m.type != MessageType.OPERATION:
-        return None
-    env = m.contents
-    if type(env) is not dict or env.get("kind") != "chanop" \
-            or env.get("address") != DS_ID:
-        return None
-    inner = env["contents"]
-    if inner.get("address") != CHANNEL_ID or "attach" in inner:
-        return None
-    return inner["contents"]
+    return chanop_of(m, DS_ID, CHANNEL_ID)
 
 
 def _replica_fingerprint(replica: MergeTreeClient) -> str:
@@ -298,19 +291,8 @@ class DeviceStage:
         when this stage's handler is dispatched ahead of scriptorium's.
         Re-ticketed duplicate windows (orderer hard-crash) are deduped by
         sequence number."""
-        topic = f"deltas/{tenant_id}/{document_id}"
-        last = 0
-        for off in range(self.server.log.length(topic)):
-            value = self.server.log.read(topic, off)
-            batch = value.get("boxcar")
-            for m in (batch if batch is not None else [value["message"]]):
-                if m.sequence_number <= last:
-                    continue
-                wire = _chan_contents(m)
-                if wire is None:
-                    continue
-                last = m.sequence_number
-                yield replace(m, contents=wire)
+        return log_channel_stream(self.server.log, tenant_id, document_id,
+                                  DS_ID, CHANNEL_ID)
 
     def _subscribe(self, from_offset: int) -> None:
         def on_deltas(message):
@@ -1227,23 +1209,13 @@ def main(argv=None) -> int:
     parser.add_argument("--mesh-shards", type=int, default=0,
                         help="run phase A's applier stage over a "
                              "doc-sharded device mesh of this many shards "
-                             "(forces host virtual devices if needed)")
+                             "(host virtual devices on a JAX_PLATFORMS=cpu "
+                             "run)")
     args = parser.parse_args(argv)
     if args.mesh_shards > 1:
-        # XLA parses the virtual-device flag once, at first backend init
-        # (same dance as __graft_entry__.dryrun_multichip)
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count="
-                f"{args.mesh_shards}").strip()
-        import jax
+        from ..parallel.mesh import require_devices
 
-        if len(jax.devices()) < args.mesh_shards:
-            from jax.extend import backend as _jax_backend
-
-            jax.config.update("jax_platforms", "cpu")
-            _jax_backend.clear_backends()
+        require_devices(args.mesh_shards)
     try:
         result = run_soak(args.seed, quick=args.quick,
                           break_dedupe=args.break_dedupe,

@@ -34,17 +34,17 @@ class Config:
     applier_min_wave_ops: int = 0        # async worker dispatch threshold
     applier_overflow_check_every: int = 64  # dispatches between fences
     # use the Pallas VMEM-resident apply (ops/pallas_apply.py) in the
-    # applier's dense step (requires max_docs % 8 == 0; measured ~8%
-    # faster than the XLA scan on TPU). Deprecated in favor of
+    # applier's dense step (requires max_docs % 8 == 0; which lane is
+    # faster on the chip is not measured yet). Deprecated in favor of
     # ``applier_kernel``; None defers to it, an explicit bool wins (the
     # pre-kernel-selection API keeps working).
     applier_use_pallas: Optional[bool] = None
     # contract-kernel selection for the apply step: "auto" picks the
     # Pallas VMEM-resident kernel on real TPU devices and the XLA scan
-    # everywhere else (falling back to XLA when the doc geometry cannot
-    # tile, i.e. docs-per-shard % 8 != 0); "pallas"/"xla" force a lane
-    # (a forced "pallas" raises on incompatible geometry instead of
-    # silently degrading).
+    # everywhere else (on a TPU it falls back to XLA, with a warning,
+    # when the doc geometry cannot tile, i.e. docs-per-shard % 8 != 0);
+    # "pallas"/"xla" force a lane (a forced "pallas" raises on
+    # incompatible geometry).
     applier_kernel: str = "auto"
     # overlap-staged dispatch: stage wave N+1 on the host (pack +
     # per-shard scatter + device_put) while wave N executes

@@ -1,4 +1,4 @@
-"""Windowed SLO engine: declarative p99 budgets over the hop taxonomy.
+"""Windowed SLO engine: declarative p99 budgets over the hop catalog.
 
 PR 5 gave the process windowed per-hop observations; this module reads
 them. An :class:`SloSpec` names a hop pair (and optionally a tenant)

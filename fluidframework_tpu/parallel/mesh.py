@@ -19,19 +19,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: newer releases promote it to
-    the top-level namespace (param ``check_vma``); older ones ship it as
-    ``jax.experimental.shard_map`` (param ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def make_mesh(
     n_devices: int | None = None,
     seg_shards: int = 1,
@@ -49,30 +36,27 @@ def make_mesh(
     return Mesh(grid, ("docs", "seg"))
 
 
-def force_host_devices(n_devices: int) -> None:
-    """Ensure ``len(jax.devices()) >= n_devices`` by forcing host-platform
-    virtual devices (CPU dev boxes, CI, the multichip bench/soak gates).
+def require_devices(n_devices: int) -> None:
+    """At least ``n_devices`` devices. On an explicit CPU run
+    (``JAX_PLATFORMS=cpu``: tests, CI, the multichip smoke) host-platform
+    virtual devices are forced; otherwise the devices present must do —
+    a chip is never swapped for host devices.
 
     XLA parses ``XLA_FLAGS`` exactly once, at the very first backend
-    init, so the flag must land in the environment before anything
-    queries devices; if a backend already initialized with fewer devices
-    (e.g. an accelerator plugin pinned ``jax_platforms``), fall back to
-    the CPU platform and drop the initialized backend set. No-op when
-    enough devices already exist."""
+    init, so a CPU run calls this before anything queries devices."""
     import os
 
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n_devices}"
-        ).strip()
-    if len(jax.devices()) < n_devices:
-        from jax.extend import backend as _jax_backend
-
-        jax.config.update("jax_platforms", "cpu")
-        _jax_backend.clear_backends()
-        if len(jax.devices()) < n_devices:
-            raise RuntimeError(
-                f"could not force {n_devices} host devices "
-                f"(have {len(jax.devices())}); was a backend already "
-                "initialized with XLA_FLAGS set differently?")
+    cpu_run = jax.config.jax_platforms == "cpu"
+    if cpu_run:
+        flags = os.environ.get("XLA_FLAGS", "")
+        if "xla_force_host_platform_device_count" not in flags:
+            os.environ["XLA_FLAGS"] = (
+                flags + f" --xla_force_host_platform_device_count={n_devices}"
+            ).strip()
+    have = jax.devices()
+    if len(have) < n_devices:
+        raise RuntimeError(
+            f"{n_devices} devices asked for, {len(have)} "
+            f"{have[0].platform} devices present"
+            + ("; was a backend already initialized with XLA_FLAGS set "
+               "differently?" if cpu_run else ""))

@@ -17,6 +17,10 @@ def _stable_hash(key: str) -> int:
     return int.from_bytes(hashlib.sha1(key.encode()).digest()[:8], "little")
 
 
+class PlacementFull(RuntimeError):
+    """Every slot of every shard holds a doc; the new doc has no home."""
+
+
 @dataclass
 class DocPlacement:
     """Assigns each (tenant, doc) a (shard, slot) and tracks occupancy."""
@@ -50,7 +54,9 @@ class DocPlacement:
                 slot = self._free[shard].pop()
                 self._map[k] = (shard, slot)
                 return shard, slot
-        raise RuntimeError("all shards full; grow slots_per_shard or n_shards")
+        raise PlacementFull(
+            f"all {self.n_shards * self.slots_per_shard} doc slots are "
+            f"taken; {k} has none")
 
     def lookup(self, tenant_id: str, document_id: str) -> tuple[int, int] | None:
         return self._map.get(self.key(tenant_id, document_id))

@@ -31,7 +31,6 @@ from ..ops.apply import (
 )
 from ..ops.doc_state import DocState
 from ..utils.contracts import register_kernel_contract
-from .mesh import shard_map
 
 
 def doc_sharding(mesh: Mesh) -> NamedSharding:
@@ -79,7 +78,7 @@ def make_sharded_step(mesh: Mesh, donate: Optional[bool] = None):
         return state, stats
 
     dp = P("docs")
-    sharded = shard_map(
+    sharded = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(dp, dp),
@@ -153,10 +152,11 @@ def make_sharded_packed_step(mesh: Mesh, donate: Optional[bool] = None,
 
     dp = P("docs")
     don = (0,) if donate else ()
-    packed = shard_map(_local_packed, mesh=mesh, in_specs=(dp, dp, dp),
-                       out_specs=(dp, P()), check_vma=False)
-    wide = shard_map(_local_wide, mesh=mesh, in_specs=(dp, dp),
-                     out_specs=(dp, P()), check_vma=False)
+    packed = jax.shard_map(_local_packed, mesh=mesh,
+                           in_specs=(dp, dp, dp), out_specs=(dp, P()),
+                           check_vma=False)
+    wide = jax.shard_map(_local_wide, mesh=mesh, in_specs=(dp, dp),
+                         out_specs=(dp, P()), check_vma=False)
     fn = (jax.jit(packed, donate_argnums=don),
           jax.jit(wide, donate_argnums=don))
     _PACKED_STEP_CACHE[key] = fn

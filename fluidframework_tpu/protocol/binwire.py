@@ -574,7 +574,7 @@ def scan_ops(body: bytes):
 # parsing any frame content: read body[-1], splice 9 bytes before it,
 # bump the count (append_hop). Unsampled frames carry k = 0 — a single
 # NUL byte — so the disarmed hot-path cost is one byte per frame. Hop
-# ids index utils.telemetry.HOPS (the taxonomy's single source of
+# ids index utils.telemetry.HOPS (the catalog's single source of
 # truth). The trailer sits OUTSIDE the ``cols`` section, so the deli
 # stamp splice and the encode-once fan-out caches never touch it.
 #

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import os
 import struct
+from dataclasses import replace
 from typing import Any, Optional
 
 import numpy as np
@@ -51,6 +52,7 @@ from .log_compat import (  # noqa: F401  (re-exported legacy codec names)
     encode_json_value,
 )
 from .segment_store import SegmentReader
+from .synthetic import chanop_of
 
 # --------------------------------------------------- binary fast path
 # The split deployment's hot records are (a) a raw ArrayBoxcar on the
@@ -259,9 +261,10 @@ def _desanitize(name: str) -> str:
     return "".join(out)
 
 
-def _legacy_messages(value: Any) -> list:
-    """Materialize the sequenced messages a legacy deltas record holds
-    (the backfill door's compat shim for SEG_JSON blocks)."""
+def record_messages(value: Any) -> list:
+    """Materialize the sequenced messages a deltas record holds (the
+    backfill door's compat shim for SEG_JSON blocks, and the applier
+    stage's escalation replay)."""
     if not isinstance(value, dict):
         return []
     abatch = value.get("abatch")
@@ -272,6 +275,25 @@ def _legacy_messages(value: Any) -> list:
         return list(boxcar)
     msg = value.get("message")
     return [msg] if msg is not None else []
+
+
+def log_channel_stream(log, tenant: str, doc: str, ds_id: str,
+                       channel_id: str):
+    """One channel's merge-tree messages read from the doc's deltas
+    topic in the durable log — the applier stage's escalation replay
+    source and the smoke's oracle input. A window re-ticketed after an
+    orderer crash repeats sequence numbers; its channel ops are skipped
+    by seq, as the stage's live feed skips them."""
+    topic = f"deltas/{tenant}/{doc}"
+    last = 0
+    for off in range(log.length(topic)):
+        for m in record_messages(log.read(topic, off)):
+            if m.sequence_number <= last:
+                continue
+            wire = chanop_of(m, ds_id, channel_id)
+            if wire is not None:
+                last = m.sequence_number
+                yield replace(m, contents=wire)
 
 
 _UNSET = object()
@@ -626,7 +648,7 @@ class DurableLog(OrderedLogBase):
                 payloads.append(payload)
             else:
                 self.counters.inc("storage.log.legacy_json")
-                for m in _legacy_messages(_decode_value(payload)):
+                for m in record_messages(_decode_value(payload)):
                     if from_seq < m.sequence_number < to_seq:
                         legacy.append(m)
         if payloads:

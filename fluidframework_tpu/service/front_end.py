@@ -116,7 +116,7 @@ def _stamp_abatch(batch, topic=None, tenant=None) -> bytes:
         reg = get_registry()
         unknown = count_unknown_hops(hops)
         if unknown:
-            # a hop id past this build's taxonomy (version-skewed
+            # a hop id past this build's catalog (version-skewed
             # client): COUNT it rather than silently dropping, so a
             # breakdown that quietly lost legs is visible in the scrape
             reg.inc("obs.trace.unknown_hops", unknown)

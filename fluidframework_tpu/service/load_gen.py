@@ -25,9 +25,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..protocol.messages import MessageType
 from .local_server import LocalServer
-from .synthetic import CHANNEL_ID, DS_ID, SyntheticEditor  # noqa: F401
+from .synthetic import CHANNEL_ID, DS_ID, SyntheticEditor, chanop_of  # noqa: F401
 # SyntheticEditor lives in synthetic.py (import-light for load workers);
 # re-exported here for the existing consumers
 
@@ -74,8 +73,6 @@ def wire_applier(server: LocalServer, applier, tenant: str, docs: list[str]):
     carry batches; the applier stages each batch in one call."""
     from .broadcaster import BroadcasterLambda
 
-    op_t = MessageType.OPERATION
-
     def make_cb(doc):
         def cb(batch):
             if type(batch) is not list:  # array lane: bulk ingest
@@ -85,17 +82,9 @@ def wire_applier(server: LocalServer, applier, tenant: str, docs: list[str]):
                 return
             pairs = []
             for msg in batch:
-                if msg.type is not op_t:
-                    continue
-                env = msg.contents
-                if type(env) is not dict or env.get("kind") != "chanop":
-                    continue
-                if env["address"] != DS_ID:
-                    continue
-                inner = env["contents"]
-                if inner.get("address") != CHANNEL_ID or "attach" in inner:
-                    continue
-                pairs.append((msg, inner["contents"]))
+                wire = chanop_of(msg, DS_ID, CHANNEL_ID)
+                if wire is not None:
+                    pairs.append((msg, wire))
             if pairs:
                 applier.ingest_batch(tenant, doc, pairs)
         return cb
@@ -139,7 +128,13 @@ def run_inproc(
     stats = LoadStats()
 
     if applier is not None:
-        applier.set_replay_source(lambda t, d: [])
+        from .durable_log import log_channel_stream
+
+        # an escalated doc replays from the log the server appends to
+        # (a record is in the log before any subscriber sees it)
+        applier.set_replay_source(
+            lambda t, d: log_channel_stream(server.log, t, d, DS_ID,
+                                            CHANNEL_ID))
         wire_applier(server, applier, tenant, docs)
 
     sessions = []  # (conn, editor)

@@ -40,14 +40,16 @@ The core consumes STATE with ``front_end --consume-backchannel STATE``.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import signal
+import sys
 import time
 from typing import Optional
 
-from ..protocol.messages import MessageType
 from .core import InMemoryDb, summary_versions_collection
-from .durable_log import DurableLog
+from .durable_log import DurableLog, log_channel_stream
+from .synthetic import chanop_of
 
 BACKCHANNEL_TOPIC = "backchannel"
 POLL_INTERVAL_S = 0.002
@@ -81,6 +83,10 @@ class _StageHostBase:
     #: the two windows whose replay/idempotency story must hold on a real
     #: kill -9. None = disarmed, one branch per checkpoint.
     fault_plane = None
+
+    #: set to drain-stop (the applier's SIGINT): run_forever finishes
+    #: its pass, checkpoints and returns
+    stopping = False
 
     def _fault(self, point: str, **ctx) -> None:
         if self.fault_plane is not None:
@@ -140,9 +146,6 @@ class _StageHostBase:
     # reproduces op-by-op, each step observable through the backchannel.
 
     def _read_ctl(self) -> None:
-        import json
-        import os
-
         path = os.path.join(self.state.directory, "ctl.json")
         try:
             mtime = os.stat(path).st_mtime_ns
@@ -166,12 +169,13 @@ class _StageHostBase:
         return False
 
     def run_forever(self) -> None:
-        print("READY", flush=True)
+        """Tail, drain and checkpoint until :attr:`stopping` is set, then
+        checkpoint once more."""
         last_discover = 0.0
         self._ctl = {"mode": "run"}
         self._ctl_mtime = None
         self._steps_done = 0
-        while True:
+        while not self.stopping:
             now = time.monotonic()
             if now - last_discover >= 0.25:  # listdir is not free at 2ms
                 last_discover = now
@@ -204,6 +208,8 @@ class _StageHostBase:
             self.state.flush()
             if not moved:
                 time.sleep(POLL_INTERVAL_S)
+        self.checkpoint()
+        self.state.flush()
 
     def run_once(self) -> bool:
         """ONE deterministic iteration of the run_forever loop body:
@@ -314,10 +320,14 @@ class ScribeStage(_StageHostBase):
 
 
 class ApplierStage(_StageHostBase):
-    """TpuDocumentApplier out of process: device work off the core GIL."""
+    """TpuDocumentApplier out of process: device work off the core GIL.
+
+    Its geometry is ``applier_max_docs`` x ``applier_max_slots`` from
+    the config registry (``FLUID_TPU_APPLIER_MAX_DOCS`` etc. in the
+    launcher's environment); a doc past ``applier_max_docs`` ends the
+    process (:class:`..parallel.placement.PlacementFull`)."""
 
     def __init__(self, log_dir: str, state_dir: str,
-                 max_docs: int = 64, max_slots: int = 256,
                  ds_id: str = "default", channel_id: str = "text",
                  partition=None):
         super().__init__(log_dir, state_dir, partition=partition)
@@ -328,9 +338,13 @@ class ApplierStage(_StageHostBase):
         if os.path.exists(ckpt + ".json"):
             self.applier = load_applier_checkpoint(ckpt)
         else:
-            self.applier = TpuDocumentApplier(max_docs=max_docs,
-                                              max_slots=max_slots)
-        self.applier.set_replay_source(lambda t, d: [])
+            self.applier = TpuDocumentApplier()
+        # an escalated doc rebuilds on the host oracle from the same
+        # deltas topics this stage tails (read-only, durable before any
+        # consumer sees a record)
+        self.applier.set_replay_source(
+            lambda t, d: log_channel_stream(self.shared, t, d,
+                                            self.ds_id, self.channel_id))
         self._ckpt_path = ckpt
         self._offsets: dict[str, int] = {}
         # highest sequence number CONSUMED per topic (the consumer-group
@@ -370,21 +384,48 @@ class ApplierStage(_StageHostBase):
             for m in msgs:
                 if m.sequence_number <= applied:
                     continue
-                if m.type is not MessageType.OPERATION:
-                    continue
-                env = m.contents
-                if type(env) is not dict or env.get("kind") != "chanop" \
-                        or env.get("address") != self.ds_id:
-                    continue
-                inner = env["contents"]
-                if inner.get("address") != self.channel_id \
-                        or "attach" in inner:
-                    continue
-                pairs.append((m, inner["contents"]))
+                wire = chanop_of(m, self.ds_id, self.channel_id)
+                if wire is not None:
+                    pairs.append((m, wire))
             if pairs:
                 self.applier.ingest_batch(tenant, doc, pairs)
 
         self.shared.subscribe(topic, on_deltas, from_offset=start)
+
+    @classmethod
+    def serve(cls, log_dir: str, state_dir: str, partition=None) -> None:
+        """The applier process: hold the chip, print ``READY`` and a
+        ``STAGE {...}`` line (device, kernel lane, geometry), run until
+        SIGINT drain-stops it, then print ``STOPPED {...}``. A doc past
+        ``applier_max_docs`` exits 1 naming the setting."""
+        from ..parallel.placement import PlacementFull
+        from ..utils.chip import (CompileClock, init_compile_cache,
+                                  peak_bytes_in_use, require_tpu)
+
+        init_compile_cache()
+        dev = require_tpu()
+        clock = CompileClock()
+        stage = cls(log_dir, state_dir, partition=partition)
+        a = stage.applier
+        signal.signal(signal.SIGINT,
+                      lambda *_: setattr(stage, "stopping", True))
+        print("READY", flush=True)
+        print("STAGE " + json.dumps({
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "kernel_lane": a.kernel_lane, "max_docs": a.max_docs,
+            "max_slots": a.max_slots, "ops_per_dispatch": a.K}), flush=True)
+        try:
+            stage.run_forever()
+        except PlacementFull as e:
+            sys.exit(f"applier stage: {e}: more docs than applier_max_docs="
+                     f"{a.max_docs} (set FLUID_TPU_APPLIER_MAX_DOCS)")
+        print("STOPPED " + json.dumps({
+            "docs": len(a.placement.snapshot()["map"]),
+            "ops_applied": a.ops_applied, "dispatches": a.dispatches,
+            "host_escalations": a.host_escalations,
+            "compile_s": clock.seconds, "compiles": clock.compiles,
+            "peak_bytes_in_use": peak_bytes_in_use(dev)}),
+            flush=True)
 
     def checkpoint(self) -> None:
         from .tpu_applier import save_applier_checkpoint
@@ -438,9 +479,13 @@ def main() -> None:
     if args.partition:
         k, _, n = args.partition.partition("/")
         partition = (int(k), int(n))
-    STAGES[args.stage](args.log_dir, args.state_dir,
-                       partition=partition).run_forever()
-
+    if args.stage == "applier":
+        ApplierStage.serve(args.log_dir, args.state_dir, partition)
+        return
+    stage = STAGES[args.stage](args.log_dir, args.state_dir,
+                               partition=partition)
+    print("READY", flush=True)
+    stage.run_forever()
 
 if __name__ == "__main__":
     main()

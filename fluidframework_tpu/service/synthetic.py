@@ -22,6 +22,21 @@ CHANNEL_ID = "text"
 _TEXT_POOL = "abcdefgh" * 4  # payload source: slicing beats per-char joins
 
 
+def chanop_of(m, ds_id: str, channel_id: str):
+    """The merge-tree wire op a sequenced message carries on one
+    channel, or None (joins, summaries, other channels, attaches)."""
+    if m.type is not MessageType.OPERATION:
+        return None
+    env = m.contents
+    if type(env) is not dict or env.get("kind") != "chanop" \
+            or env.get("address") != ds_id:
+        return None
+    inner = env["contents"]
+    if inner.get("address") != channel_id or "attach" in inner:
+        return None
+    return inner["contents"]
+
+
 class SyntheticEditor:
     """One synthetic client's op source for one document.
 

@@ -14,7 +14,7 @@ at import time and nothing per call (the function object is returned
 unwrapped). The checker reads them from the AST, so even un-imported
 fixture trees are checkable.
 
-Taxonomy (the context strings the checker propagates):
+Vocabulary (the context strings the checker propagates):
 
 - ``@loop_only("core")`` — must only ever run on the named event-loop
   thread. The front end's pipeline, admission, presence, and the

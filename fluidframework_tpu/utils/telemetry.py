@@ -22,12 +22,12 @@ from typing import Any, Callable, Optional
 
 Sink = Callable[[dict], None]
 
-# --------------------------------------------------------------- hop taxonomy
+# --------------------------------------------------------------- hop catalog
 #
 # The per-tier trace-hop vocabulary. Columnar wire frames carry hops
 # as compact (hop id, timestamp) pairs (the binwire hoptail); rec
 # frames carry the (service, action) strings. Both sides map through
-# THIS table — it is the taxonomy's single source of truth — and the
+# THIS table — it is the catalog's single source of truth — and the
 # breakdown pair names (``submit_to_deli``, ``deli_to_ack``,
 # ``admit_to_deli``, …) derive from the SHORT keys of consecutive
 # PRESENT hops, so the legacy two-pair split falls out as the special
@@ -99,7 +99,7 @@ def hop_pairs(hops) -> list[tuple[str, float]]:
 
 
 def count_unknown_hops(hops) -> int:
-    """Entries whose id falls outside the taxonomy — a version-skewed
+    """Entries whose id falls outside the catalog — a version-skewed
     stamper. Callers surface the count as ``obs.trace.unknown_hops``
     (this module sits below obs/, so it cannot reach the registry)."""
     return sum(1 for i, _ in hops if not 0 <= i < len(HOPS))
@@ -245,7 +245,7 @@ class Counters:
 class TraceAggregator:
     """Consume wire trace hops into an ordered hop-pair breakdown.
 
-    Each tier stamps its hop from the :data:`HOPS` taxonomy (client/
+    Each tier stamps its hop from the :data:`HOPS` catalog (client/
     submit, gateway/relay, frontend/admit, deli/sequence, broadcast/
     fanout); the ack observer calls ``record(msg)`` when its own op
     comes back. Every leg between consecutive PRESENT hops becomes a
@@ -255,7 +255,7 @@ class TraceAggregator:
 
     def __init__(self):
         self._hops: dict[str, list[float]] = defaultdict(list)
-        #: hops dropped for an id outside the taxonomy — a
+        #: hops dropped for an id outside the catalog — a
         #: version-skewed stamper; surfaced in ``report()`` (and by
         #: service consumers as ``obs.trace.unknown_hops``) instead of
         #: vanishing silently.

@@ -1,7 +1,7 @@
 """MULTICHIP artifact schema (ISSUE 9/11 satellite): the v3 reader must
-fold the whole r01..rNN series — new rung-bearing artifacts verbatim, v2
-(r06) rungs gaining null overlap fields, old dryrun-era {n_devices, rc,
-ok, skipped, tail} files normalized — and the ci smoke's structural
+fold the committed series — rung-bearing v3 artifacts verbatim, v2
+(r06) rungs gaining null overlap fields, the removed dryrun-era schema
+refused — and the ci smoke's structural
 counter-asserts (bytes scale with active shards, one compile per shape,
 positive overlap with pipelined waves) must hold under pytest's
 8-virtual-device config too."""
@@ -16,26 +16,14 @@ from tools.bench_multichip import _V3_RUNG_FIELDS, read_multichip, run_smoke
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_reader_normalizes_old_dryrun_schema(tmp_path):
-    old = {"n_devices": 8, "rc": 0, "ok": True, "skipped": False,
-           "tail": "..."}
-    p = tmp_path / "MULTICHIP_r05.json"
-    p.write_text(json.dumps(old))
-    got = read_multichip(str(p))
-    assert got["schema"] == 3
-    assert got["n_devices"] == 8
-    assert got["ok"] is True
-    assert got["rc"] == 0
-    assert got["rungs"] == []
-    assert got["overlap"] is False
-    assert got["local_dense_ab"] is None
-
-
-def test_reader_treats_old_skipped_as_not_ok(tmp_path):
+def test_reader_refuses_dryrun_schema(tmp_path):
+    """The dryrun-era {n_devices, rc, ok, skipped, tail} files carried no
+    rungs and are gone from the tree; the reader refuses, not guesses."""
     p = tmp_path / "m.json"
-    p.write_text(json.dumps({"n_devices": 1, "rc": 0, "ok": True,
-                             "skipped": True}))
-    assert read_multichip(str(p))["ok"] is False
+    p.write_text(json.dumps({"n_devices": 8, "rc": 0, "ok": True,
+                             "skipped": False, "tail": "..."}))
+    with pytest.raises(ValueError, match="schema"):
+        read_multichip(str(p))
 
 
 def test_reader_folds_v2_rungs_to_v3(tmp_path):
@@ -71,8 +59,7 @@ def test_reader_passes_v3_through(tmp_path):
     assert v3 == json.load(open(os.path.join(REPO, "MULTICHIP_r07.json")))
 
 
-@pytest.mark.parametrize(
-    "rev", ["r01", "r02", "r03", "r04", "r05", "r06", "r07"])
+@pytest.mark.parametrize("rev", ["r06", "r07"])
 def test_reader_loads_committed_artifact_series(rev):
     path = os.path.join(REPO, f"MULTICHIP_{rev}.json")
     if not os.path.exists(path):
@@ -80,13 +67,12 @@ def test_reader_loads_committed_artifact_series(rev):
     got = read_multichip(path)
     assert got["schema"] == 3
     assert got["ok"] is True
-    # the r06+ generations must carry real throughput rungs
-    if rev >= "r06":
-        assert len(got["rungs"]) == 4
-        for r in got["rungs"]:
-            assert r["ops_per_sec"] > 0
-            assert 0 < r["scaling_efficiency"] <= 1.25
-        assert got["mesh_vs_local_1shard"] >= 0.9  # acceptance: ≤10% tax
+    # every committed generation carries real throughput rungs
+    assert len(got["rungs"]) == 4
+    for r in got["rungs"]:
+        assert r["ops_per_sec"] > 0
+        assert 0 < r["scaling_efficiency"] <= 1.25
+    assert got["mesh_vs_local_1shard"] >= 0.9  # acceptance: ≤10% tax
     # the overlap-era artifact must prove the pipeline did overlap
     if rev >= "r07":
         assert got["overlap"] is True

@@ -314,7 +314,7 @@ def test_soak_fails_when_recovery_disabled():
 def test_soak_full_campaign_both_phases():
     out = run_soak(seed=0)
     assert set(out["coverage"]) == {"network", "log", "fanout", "stage",
-                                    "device", "snapshot"}
+                                    "device", "snapshot", "history"}
 
 
 @pytest.mark.slow

@@ -1,0 +1,147 @@
+"""The main path's device programs compile for a TPU v5e, without a chip.
+
+Compiles for a described v5e:2x2 (``jax.experimental.topologies``): the
+served packed step and the batched apply on both kernel lanes at
+1024 docs x 256 slots x 64 ops, and the 4-chip doc-mesh packed step at
+8192 x 256 x 64. What the chip's compiler would refuse (a Mosaic kernel
+that does not tile, a program past the chip's 16 GB) fails here at no
+chip time. Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load libtpu, and each xdist worker imports every
+test file. Keep these tests in this one file.
+"""
+
+from __future__ import annotations
+
+import pytest
+
+D, S, K = 1024, 256, 64
+MESH_D = 8192
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        # libtpu's compiler logs would otherwise land under /tmp
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means "no v5e"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    import jax
+
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _state_shapes(n_docs: int, sharding):
+    import jax
+    import jax.numpy as jnp
+
+    from fluidframework_tpu.ops.doc_state import DocState
+
+    return _shapes(jax.eval_shape(
+        lambda: jax.vmap(lambda _: DocState.empty(S))(jnp.arange(n_docs))),
+        sharding)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _packed_wave(n_docs: int, sharding):
+    import jax
+    import jax.numpy as jnp
+
+    from fluidframework_tpu.ops.apply import OP_FIELDS
+
+    return (jax.ShapeDtypeStruct((n_docs, K, OP_FIELDS), jnp.int16,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((n_docs, 2), jnp.int32, sharding=sharding))
+
+
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_served_packed_step_compiles(topo, no_compile_cache, lane):
+    """The applier's dense step (int16 wave unpack + apply + zamboni)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fluidframework_tpu.service.tpu_applier import _dense_step_for
+
+    one = SingleDeviceSharding(topo.devices[0])
+    packed_fn, _wide = _dense_step_for(D, K, use_pallas=lane == "pallas")
+    compiled = packed_fn.lower(_state_shapes(D, one),
+                               *_packed_wave(D, one)).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (lane == "pallas")
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_batched_apply_compiles(topo, no_compile_cache, lane):
+    """The kernel alone on an int32 wave, with the fused zamboni."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from fluidframework_tpu.ops.apply import (OP_FIELDS, apply_ops_batch,
+                                              compact_batch, wave_min_seq)
+    from fluidframework_tpu.ops.pallas_apply import pallas_apply_ops_batch
+
+    apply_fn = pallas_apply_ops_batch if lane == "pallas" else apply_ops_batch
+
+    def step(state, ops):
+        return compact_batch(apply_fn(state, ops), wave_min_seq(ops))
+
+    one = SingleDeviceSharding(topo.devices[0])
+    ops = jax.ShapeDtypeStruct((D, K, OP_FIELDS), jnp.int32, sharding=one)
+    compiled = jax.jit(step).lower(_state_shapes(D, one), ops).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (lane == "pallas")
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_mesh_packed_step_compiles(topo, no_compile_cache, lane):
+    """The doc-mesh lane's packed step over four chips, 2048 docs each:
+    per-device bytes fit one chip, and the stats psum is an all-reduce."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fluidframework_tpu.parallel.mesh import make_mesh
+    from fluidframework_tpu.parallel.sharded_apply import (
+        make_sharded_packed_step)
+
+    mesh = make_mesh(devices=list(topo.devices)[:4])
+    docs = NamedSharding(mesh, P("docs"))
+    packed_fn, _wide = make_sharded_packed_step(
+        mesh, donate=True, use_pallas=lane == "pallas")
+    compiled = packed_fn.lower(_state_shapes(MESH_D, docs),
+                               *_packed_wave(MESH_D, docs)).compile()
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (lane == "pallas")
+    assert "all-reduce" in hlo
+    assert _device_bytes(compiled) < HBM_BYTES

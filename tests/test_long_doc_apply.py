@@ -16,7 +16,7 @@ from fluidframework_tpu.ops.apply import (
 from fluidframework_tpu.ops.doc_state import DocState
 from fluidframework_tpu.ops.opgen import generate_batch_ops
 from fluidframework_tpu.parallel.long_doc import sharded_apply_ops
-from fluidframework_tpu.parallel.mesh import make_mesh, shard_map
+from fluidframework_tpu.parallel.mesh import make_mesh
 
 N_SHARDS = 8
 S_LOCAL = 64
@@ -75,7 +75,7 @@ def _run_pair(seed, n_ops, remove_fraction=0.3, annotate_fraction=0.1):
         out = sharded_apply_ops(local, ops, axis="seg")
         return jax.tree.map(lambda a: a[None], out)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(specs, P()), out_specs=specs,
         check_vma=False))
     out = fn(sharded, ops)
@@ -131,7 +131,7 @@ def _run_chunked_with_rebalancing(ops_all, chunk=8):
         out = sharded_apply_ops(local, ops, axis="seg")
         return jax.tree.map(lambda a: a[None], out)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(specs, P()), out_specs=specs,
         check_vma=False))
 

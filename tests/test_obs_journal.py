@@ -12,7 +12,7 @@ Five planes under test:
   cross-core causality correct under deliberate wall-clock skew;
 - the metrics history ring — retired buckets survive past the live
   window, the horizon prunes, and ``window_history`` label-filters;
-- the appended hop taxonomy e2e — ``relay_to_relay`` from a real
+- the appended hop catalog e2e — ``relay_to_relay`` from a real
   2-level relay tree (core ← mid ← leaf, separate processes) and
   ``stage_to_execute`` from the applier backchannel fold, plus the
   full forced-migration journal chain on in-proc shard hosts.
@@ -283,7 +283,7 @@ def test_hop_pairs_full_pipeline_with_new_ids():
         ("admit_to_deli", 1000.0), ("deli_to_stage", 1000.0),
         ("stage_to_execute", 1500.0), ("execute_to_fanout", 500.0),
         ("fanout_to_ack", 500.0)]
-    # out-of-taxonomy ids are ignored by pairs but counted for the
+    # out-of-catalog ids are ignored by pairs but counted for the
     # obs.trace.unknown_hops surface
     skewed = hops + [(42, 3.3), (99, 1.1)]
     assert hop_pairs(skewed) == pairs

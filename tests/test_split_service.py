@@ -27,6 +27,9 @@ import pytest
 from fluidframework_tpu.driver.network import NetworkDocumentServiceFactory
 from fluidframework_tpu.loader import Loader
 
+# the checkout under test (``python -m`` puts the cwd first on sys.path)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def wait_for(cond, timeout=30.0):
     t0 = time.time()
@@ -48,7 +51,7 @@ def _spawn(args, ready_line):
     proc = subprocess.Popen(
         [sys.executable, "-m"] + args,
         stdout=subprocess.PIPE, stderr=errf, text=True,
-        cwd="/root/repo")
+        cwd=REPO)
     proc._stderr_path = errf.name
     line = proc.stdout.readline().strip()
     assert line.startswith(ready_line), line
@@ -452,3 +455,126 @@ def test_full_production_composition(tmp_path):
         raise
     finally:
         _cleanup(procs)
+
+
+# ---------------------------------------------------- applier stage repairs
+
+def _drive_docs(port: int, n_docs: int, prefix: str) -> None:
+    """One raw load_async client per doc, one 4-op boxcar each."""
+    out = subprocess.run(
+        [sys.executable, "-m", "fluidframework_tpu.service.load_async",
+         "--port", str(port), "--docs", str(n_docs), "--clients-per-doc",
+         "1", "--rounds", "1", "--batch", "4", "--rate", "10",
+         "--doc-prefix", prefix, "--tenant", "t", "--timeout", "60"],
+        stdout=subprocess.PIPE, text=True, cwd=REPO, timeout=120,
+        check=True)
+    import json as _json
+
+    r = _json.loads(out.stdout)
+    assert r["acked"] == r["ops"] == n_docs * 4, r
+
+
+def test_applier_stage_takes_max_docs_from_env(tmp_path, monkeypatch):
+    """FLUID_TPU_APPLIER_MAX_DOCS sizes the stage's farm (it used to be
+    pinned at 64): 70 docs land, and a drain-stop (SIGINT) checkpoints
+    and reports them with exit code 0."""
+    import json as _json
+
+    monkeypatch.setenv("FLUID_TPU_APPLIER_MAX_DOCS", "72")
+    monkeypatch.setenv("FLUID_TPU_APPLIER_MAX_SLOTS", "64")
+    with split_deployment(tmp_path, stages=("applier",)) as (
+            port, procs, state_dirs, _):
+        stage = procs["applier"]
+        report = _json.loads(stage.stdout.readline().split(" ", 1)[1])
+        assert report["max_docs"] == 72 and report["max_slots"] == 64
+        assert report["kernel_lane"] == "xla"  # the CPU: no Pallas lane
+        _drive_docs(port, 70, "many")
+        assert wait_for(lambda: all(
+            _applied_seq(state_dirs["applier"], "t", f"many{i}") > 0
+            for i in range(70)), timeout=120)
+        stage.send_signal(signal.SIGINT)
+        stopped = stage.stdout.readline()
+        assert stopped.startswith("STOPPED "), stopped
+        stats = _json.loads(stopped.split(" ", 1)[1])
+        assert stats["docs"] == 70 and stats["host_escalations"] == 0
+        assert stage.wait(timeout=30) == 0
+
+
+def test_applier_stage_exits_when_slots_run_out(tmp_path, monkeypatch):
+    """More docs than applier_max_docs ends the stage with a non-zero
+    exit naming the setting — never a stage dead but still running."""
+    monkeypatch.setenv("FLUID_TPU_APPLIER_MAX_DOCS", "8")
+    monkeypatch.setenv("FLUID_TPU_APPLIER_MAX_SLOTS", "64")
+    with split_deployment(tmp_path, stages=("applier",)) as (
+            port, procs, _, _):
+        _drive_docs(port, 9, "full")
+        stage = procs["applier"]
+        assert stage.wait(timeout=120) != 0
+        with open(stage._stderr_path) as f:
+            assert "FLUID_TPU_APPLIER_MAX_DOCS" in f.read()
+
+
+def test_escalated_doc_in_stage_replays_from_the_log(tmp_path):
+    """A doc forced to host escalation inside the applier stage rebuilds
+    from the deltas topic the stage tails: its text is the oracle's, not
+    the empty replica the old replay source produced."""
+    from fluidframework_tpu.mergetree.client import MergeTreeClient
+    from fluidframework_tpu.service.durable_log import (DurableLog,
+                                                        log_channel_stream)
+    from fluidframework_tpu.service.load_gen import run_inproc
+    from fluidframework_tpu.service.stage_runner import ApplierStage
+    from fluidframework_tpu.service.synthetic import CHANNEL_ID, DS_ID
+
+    log = DurableLog(str(tmp_path / "log"))
+    run_inproc(n_docs=2, clients_per_doc=2, ops_per_client=24,
+               batch_size=4, seed=3, log=log)
+    log.flush()
+    stage = ApplierStage(str(tmp_path / "log"), str(tmp_path / "state"))
+    target = stage.applier.slot_of("bench", "doc1")
+    ingests = {"n": 0}
+
+    def plane(point, slot=None, **_ctx):
+        # escalate doc1 at its third batch: two batches already staged
+        # for the device are discarded by the flip
+        if point == "applier.ingest" and slot == target:
+            ingests["n"] += 1
+            if ingests["n"] == 3:
+                return "escalate_host"
+        return None
+
+    stage.applier.fault_plane = plane
+    while stage.run_once():
+        pass
+    assert stage.applier.host_escalations == 1
+    for doc in ("doc0", "doc1"):
+        oracle = MergeTreeClient("oracle")
+        for m in log_channel_stream(log, "bench", doc, DS_ID, CHANNEL_ID):
+            oracle.apply_msg(m, local=False)
+        assert oracle.get_text()
+        assert stage.applier.get_text("bench", doc) == oracle.get_text()
+
+
+def test_require_devices_never_swaps_a_chip_for_host_devices(monkeypatch):
+    """On a chip host (no JAX_PLATFORMS=cpu) the mesh helper uses the
+    chips present: too few raises, and neither jax_platforms nor
+    XLA_FLAGS is touched to make up the count with CPU devices."""
+    import types
+
+    import jax
+
+    from fluidframework_tpu.parallel import mesh
+
+    platforms = jax.config.jax_platforms
+    jax.config.update("jax_platforms", "")
+    try:
+        chips = [types.SimpleNamespace(platform="tpu",
+                                       device_kind="TPU v5 lite")] * 4
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: chips)
+        monkeypatch.delenv("XLA_FLAGS", raising=False)
+        mesh.require_devices(4)
+        with pytest.raises(RuntimeError, match="8 devices asked for, 4 tpu"):
+            mesh.require_devices(8)
+        assert "XLA_FLAGS" not in os.environ
+        assert jax.config.jax_platforms == ""
+    finally:
+        jax.config.update("jax_platforms", platforms)

@@ -1,7 +1,8 @@
 """Per-device scaling bench for the multi-chip doc mesh (ISSUE 9).
 
-Sweeps the 'docs' mesh axis 1 → 2 → 4 → 8 (forced host devices when the
-real platform has fewer) in WEAK-scaling geometry — 64 docs per shard,
+Sweeps the 'docs' mesh axis 1 → 2 → 4 → 8, as far as the devices go
+(the chips present; 8 forced host devices on a ``JAX_PLATFORMS=cpu``
+run), in WEAK-scaling geometry — 64 docs per shard,
 K=32 ops per doc per wave — and publishes per rung: ops/s, scaling
 efficiency vs the 1-shard rung, host staging cost per wave (the per-shard
 wave-build + pre-partitioned transfer path), and staged bytes per wave.
@@ -51,9 +52,8 @@ then carries ``host_limited: true`` with a note, and the overlap
 mechanism is evidenced by per-rung ``overlap_ratio`` plus the smoke
 gate's counter-asserts.
 
-``read_multichip`` folds all generations: v1 dryruns
-({n_devices, rc, ok, skipped, tail}) normalize to an empty rung list;
-v2 (r06) rungs gain null overlap fields.
+``read_multichip`` folds the committed generations: v2 (r06) rungs gain
+null overlap fields; v3 (r07) passes through.
 """
 
 from __future__ import annotations
@@ -71,42 +71,25 @@ _V3_RUNG_FIELDS = ("pipeline_ops_per_sec", "overlap_ratio",
 
 
 def read_multichip(path: str) -> dict:
-    """Load a MULTICHIP artifact of ANY generation as schema v3."""
+    """Load a schema v2 or v3 MULTICHIP artifact as schema v3."""
     with open(path) as f:
         raw = json.load(f)
-    schema = raw.get("schema", 1)
-    if schema >= 3:
+    schema = raw.get("schema")
+    if schema == 3:
         return raw
-    if schema == 2:
-        # r06: real rungs, pre-overlap — the v3 split fields are unknown
-        for r in raw.get("rungs", []):
-            for f2 in _V3_RUNG_FIELDS:
-                r.setdefault(f2, None)
-        raw.setdefault("overlap", False)
-        raw.setdefault("efficiency_basis", "wall")
-        raw.setdefault("host_limited", raw.get("forced_host"))
-        raw.setdefault("host_limited_note", None)
-        raw.setdefault("local_dense_ab", None)
-        raw["schema"] = 3
-        return raw
-    # r01..r05 dryrun schema: presence/absence of a multi-device compile,
-    # no throughput rungs
-    return {
-        "schema": 3,
-        "platform": None,
-        "n_devices": raw.get("n_devices"),
-        "forced_host": None,
-        "host_limited": None,
-        "host_limited_note": None,
-        "overlap": False,
-        "efficiency_basis": "wall",
-        "rungs": [],
-        "local_dense_ops_per_sec": None,
-        "mesh_vs_local_1shard": None,
-        "local_dense_ab": None,
-        "ok": bool(raw.get("ok")) and not raw.get("skipped"),
-        "rc": raw.get("rc"),
-    }
+    if schema != 2:
+        raise ValueError(f"{path}: MULTICHIP schema {schema!r} is not 2 or 3")
+    # r06: real rungs, pre-overlap — the v3 split fields are unknown
+    for r in raw.get("rungs", []):
+        for f2 in _V3_RUNG_FIELDS:
+            r.setdefault(f2, None)
+    raw.setdefault("overlap", False)
+    raw.setdefault("efficiency_basis", "wall")
+    raw.setdefault("host_limited", raw.get("forced_host"))
+    raw.setdefault("host_limited_note", None)
+    raw.setdefault("local_dense_ab", None)
+    raw["schema"] = 3
+    return raw
 
 
 def _msg(seq: int) -> types.SimpleNamespace:
@@ -197,13 +180,14 @@ DOCS_PER_SHARD = 64
 K = 32
 
 
-def run_sweep(axes=(1, 2, 4, 8)) -> dict:
+def run_sweep() -> dict:
     import jax
 
     from fluidframework_tpu.parallel.mesh import make_mesh
     from fluidframework_tpu.service.tpu_applier import TpuDocumentApplier
 
     forced_host = jax.devices()[0].platform == "cpu"
+    axes = [n for n in (1, 2, 4, 8) if n <= len(jax.devices())]
     rungs = []
     for n in axes:
         D = DOCS_PER_SHARD * n
@@ -285,7 +269,9 @@ def run_smoke() -> None:
     from fluidframework_tpu.parallel.mesh import make_mesh
     from fluidframework_tpu.service.tpu_applier import TpuDocumentApplier
 
-    D, n_shards, k = 64, 8, 8
+    import jax
+
+    D, n_shards, k = 64, min(8, len(jax.devices())), 8
     applier = TpuDocumentApplier(max_docs=D, max_slots=32,
                                  ops_per_dispatch=k,
                                  mesh=make_mesh(n_shards, seg_shards=1))
@@ -355,15 +341,23 @@ def run_smoke() -> None:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="devices to require (default: 8 forced host "
+                         "devices on a JAX_PLATFORMS=cpu run, else every "
+                         "device present)")
     ap.add_argument("--smoke", action="store_true",
                     help="structural counter-asserts only (ci.sh gate)")
     ap.add_argument("--out", default=None,
                     help="also write the artifact JSON to this path")
     args = ap.parse_args(argv)
-    from fluidframework_tpu.parallel.mesh import force_host_devices
+    import jax
 
-    force_host_devices(args.devices)
+    from fluidframework_tpu.parallel.mesh import require_devices
+
+    if args.devices is None and jax.config.jax_platforms == "cpu":
+        args.devices = 8
+    if args.devices is not None:
+        require_devices(args.devices)
     if args.smoke:
         run_smoke()
         return 0

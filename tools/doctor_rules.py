@@ -87,7 +87,7 @@ def scrape_anomalies(owner: str, scrape_text: str) -> list:
     if unknown:
         out.append(
             f"core {owner}: {int(unknown)} hop stamp(s) outside "
-            "this build's taxonomy (version-skewed client?) — "
+            "this build's catalog (version-skewed client?) — "
             "the breakdown is missing legs")
     rejected = scrape_counter(
         scrape_text, "fluid_placement_table_stale_rejections")

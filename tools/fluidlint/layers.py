@@ -75,9 +75,10 @@ ALLOWED = {
     # (`fault_plane = None` class attrs / module hooks), so disarmed code
     # has no chaos dependency at all; only tests and the soak import it.
     # loader/runtime: the soak's snapshot campaign boots full containers
-    # through the columnar fast-boot plane as its late joiners
+    # through the columnar fast-boot plane as its late joiners; parallel:
+    # the mesh soak asks for its devices (parallel.mesh.require_devices)
     "chaos": {"service", "driver", "mergetree", "protocol", "utils",
-              "obs", "loader", "runtime"},
+              "obs", "loader", "runtime", "parallel"},
 }
 
 #: One-line role per layer, used by the PACKAGES.md generator.

@@ -4,7 +4,7 @@ Each fluidlint pass used to hand-roll its own loader for the contract
 table it checks against — the journal pass parsed ``KINDS`` out of
 ``obs/journal.py``, the metric pass carried ``LOCKED_FAMILIES`` inline,
 the wire pass knew the codec files but not the frame-id inventory, and
-the hop taxonomy lived only in ``utils/telemetry.py``. This module is
+the hop catalog lived only in ``utils/telemetry.py``. This module is
 the single home: every registry that is a WIRE or ALERT contract (ids
 and names other builds/dashboards key on) loads or lives here, so a
 pass that needs one imports it instead of re-parsing, and a human
@@ -14,7 +14,7 @@ Registries:
 
 - :func:`load_journal_kinds` — the audit journal's closed kind
   vocabulary (``obs/journal.py`` ``KINDS``; must stay a pure literal).
-- :func:`load_hops` — the hop taxonomy (``utils/telemetry.py``
+- :func:`load_hops` — the hop catalog (``utils/telemetry.py``
   ``HOPS``): ids 0–8 are FROZEN wire values stamped into trace tails.
 - :data:`LOCKED_FAMILIES` — metric families whose exact member sets
   are alert-surface contracts (moved here from metrics_check).
@@ -78,10 +78,10 @@ def load_journal_kinds(repo_root: Optional[str] = None
     return None
 
 
-# ------------------------------------------------------------ hop taxonomy
+# ------------------------------------------------------------ hop catalog
 
 def load_hops(repo_root: Optional[str] = None) -> Optional[tuple]:
-    """The hop taxonomy as ((service, action, short), ...) — index IS
+    """The hop catalog as ((service, action, short), ...) — index IS
     the frozen wire id. None when HOPS is missing or not a literal."""
     repo_root = repo_root or _repo_root()
     hops = _module_literal(os.path.join(repo_root, HOPS_HOME), "HOPS")
