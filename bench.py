@@ -657,48 +657,6 @@ def bench_network() -> dict:
         # relay_to_relay from the stacked-leaf burst above
         hop_breakdown = _query_hop_breakdown(port)
 
-        # device-dispatch leg: a split-service core (subprocess applier
-        # stage tailing the log, backchannel consumed by the core) — the
-        # applier's stage/execute wall stamps thread back over the
-        # backchannel and fold into the core's registry as
-        # stage_to_execute. Short burst, then poll until the fold lands
-        # (the stage checkpoints once per second).
-        split_dir = tempfile.mkdtemp(prefix="bench-split-")
-        log_dir = os.path.join(split_dir, "log")
-        state_dir = os.path.join(split_dir, "applier-state")
-        applier = subprocess.Popen(
-            _lean_cmd("fluidframework_tpu.service.stage_runner",
-                      "--stage", "applier", "--log-dir", log_dir,
-                      "--state-dir", state_dir),
-            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-            text=True, cwd=REPO)
-        assert applier.stdout.readline().strip() == "READY"
-        sfe = None
-        try:
-            sfe, sfe_port = _spawn_listening(
-                "fluidframework_tpu.service.front_end", "--port", "0",
-                "--log-dir", log_dir,
-                "--consume-backchannel", state_dir)
-            run_workers([sfe_port], 2, 8, 2, knee_rate, 32,
-                        max(8, int(8 * knee_rate)), "stgexe")
-            deadline = _time.monotonic() + 20.0
-            split_hops = _query_hop_breakdown(sfe_port)
-            while ("stage_to_execute" not in split_hops
-                   and _time.monotonic() < deadline):
-                _time.sleep(0.25)
-                split_hops = _query_hop_breakdown(sfe_port)
-            for pair, n in split_hops.items():
-                hop_breakdown[pair] = hop_breakdown.get(pair, 0) + n
-        finally:
-            applier.terminate()
-            if sfe is not None:
-                sfe.terminate()
-                sfe.wait(timeout=10)
-            try:
-                applier.wait(timeout=10)
-            except subprocess.TimeoutExpired:
-                applier.kill()
-
         # armed/disarmed A/B at the knee rate: the sampling knob must
         # cost ~nothing when off AND ~nothing at 1-in-16 — two
         # back-to-back runs, same geometry, published side by side

@@ -39,6 +39,7 @@ import numpy as np
 from ..native.oplog import NativeOpLog
 from ..utils.affinity import blocking
 from ..obs.metrics import tier_counters
+from ..utils.telemetry import span
 from ..protocol import binwire
 from .local_log import OrderedLogBase
 from .log_compat import (  # noqa: F401  (re-exported legacy codec names)
@@ -462,7 +463,8 @@ class DurableLog(OrderedLogBase):
 
     @blocking("mmap page-cache flush (PR 6; PR 11 made it per-batch) — bounded but off the async fast path")
     def flush(self) -> None:
-        self._log.flush()
+        with span("log.flush"):
+            self._log.flush()
 
     # ------------------------------------------------- storage primitives
 

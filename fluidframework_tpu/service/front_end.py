@@ -66,6 +66,8 @@ from ..utils.affinity import loop_only, ticker_thread
 from ..protocol import binwire
 from ..protocol.messages import Nack, NackErrorType, Signal, TraceHop
 from ..protocol.serialization import message_from_dict, message_to_dict
+from ..utils.telemetry import (record_span, sample, span, tracing_armed,
+                               tracing_start, tracing_stop)
 from ..utils.telemetry import (HOP_ADMIT, HOP_SERVICE_ACTION,
                                count_unknown_hops, hop_pairs)
 from .admission import AdmissionController, retry_after_ms
@@ -170,6 +172,31 @@ async def _read_frame(reader: asyncio.StreamReader) -> Optional[dict]:
 #: Bulk backfill frame types deferred behind the interactive ops of
 #: the same ingress wave (see _handle_conn lane priority).
 _BULK_FRAMES = ("get_deltas_cols", "get_deltas", "get_snapshot_cols")
+
+#: the serving loop's lag gauge: tick period, and the lateness past
+#: which an armed trace records the wake-up as a stall
+LOOP_TICK_S = 0.010
+LOOP_STALL_MS = 20.0
+
+
+async def loop_lag_ticker() -> None:
+    """Sleep LOOP_TICK_S and observe how late the wake-up came, as
+    ``frontend.loop.lag_ms`` (a windowed series on the metrics scrape,
+    always on: ~100 wake-ups a second). With tracing armed the value is
+    also kept whole, and a wake-up later than LOOP_STALL_MS is recorded
+    as a ``frontend.loop_stall`` span over the time the loop was held."""
+    reg = get_registry()
+    while True:
+        t0 = time.monotonic()
+        await asyncio.sleep(LOOP_TICK_S)
+        late = time.monotonic() - t0 - LOOP_TICK_S
+        lag_ms = max(0.0, late * 1e3)
+        reg.observe_windowed("frontend.loop.lag_ms", lag_ms)
+        sample("frontend.loop.lag_ms", lag_ms)
+        if lag_ms > LOOP_STALL_MS:
+            held_ns = int(late * 1e9)
+            record_span("frontend.loop_stall", time.time_ns() - held_ns,
+                        held_ns)
 
 
 def _frame_buffered(reader: asyncio.StreamReader) -> bool:
@@ -1806,6 +1833,9 @@ class NetworkFrontEnd:
         self.logger = self.server.logger.child("front_end")
         self.host = host
         self.port = port
+        #: armed-tracing output directory (``--trace-dir``): SIGUSR1
+        #: starts the program's spans, SIGUSR2 writes them
+        self.trace_dir: Optional[str] = None
         # service limits come from the unified config registry unless a
         # caller overrides explicitly
         self.max_message_size = (
@@ -2260,28 +2290,30 @@ class NetworkFrontEnd:
                 # connection from starving its peers on the loop.
                 n = 0
                 deferred: list = []
-                while body is not None:
-                    n += 1
-                    recorder.frame(conn_id, "in", body)
-                    if binwire.is_binary(body):
-                        session.handle_binary(body)
-                    else:
-                        frame = json.loads(body.decode())
-                        if frame.get("t") in _BULK_FRAMES:
-                            # lane priority: bulk backfill yields to the
-                            # interactive ops of the same wave — a
-                            # catch-up client's multi-MB range read must
-                            # not sit between a submit and its ack
-                            deferred.append(frame)
+                with span("frontend.ingress_batch"):
+                    while body is not None:
+                        n += 1
+                        recorder.frame(conn_id, "in", body)
+                        if binwire.is_binary(body):
+                            session.handle_binary(body)
                         else:
-                            session.handle(frame)
-                    body = None
-                    if n < 64 and _frame_buffered(reader):
-                        # completes synchronously — the bytes are
-                        # already in the stream buffer
-                        body = await _read_body(reader)
-                for frame in deferred:
-                    session.handle(frame)
+                            frame = json.loads(body.decode())
+                            if frame.get("t") in _BULK_FRAMES:
+                                # lane priority: bulk backfill yields to
+                                # the interactive ops of the same wave —
+                                # a catch-up client's multi-MB range read
+                                # must not sit between a submit and its
+                                # ack
+                                deferred.append(frame)
+                            else:
+                                session.handle(frame)
+                        body = None
+                        if n < 64 and _frame_buffered(reader):
+                            # completes synchronously — the bytes are
+                            # already in the stream buffer
+                            body = await _read_body(reader)
+                    for frame in deferred:
+                        session.handle(frame)
                 if deferred:
                     counters.inc("net.ingress.deprioritized",
                                  len(deferred))
@@ -2348,16 +2380,6 @@ class NetworkFrontEnd:
             orderer.apply_retention(rec["capture_seq"])
         elif kind == "applied":
             self.applier_status[(tenant, doc)] = rec["applied_seq"]
-            # hoptail thread across the process boundary: the applier
-            # stage's stage/execute wall stamps fold into THIS core's
-            # registry so net_hop_breakdown attributes device dispatch
-            hops = rec.get("wave_hops")
-            if hops and len(hops) == 2:
-                ms = (hops[1] - hops[0]) * 1e3
-                reg = get_registry()
-                reg.observe("obs.hop.ms", ms, pair="stage_to_execute")
-                reg.observe_windowed("obs.hop.window_ms", ms,
-                                     pair="stage_to_execute")
 
     def enable_summarizer(self, every: int) -> "NetworkFrontEnd":
         """Arm the threshold-driven service-summarizer loop: every doc
@@ -2386,54 +2408,67 @@ class NetworkFrontEnd:
         while True:
             await asyncio.sleep(lane.flush_interval)
             try:
-                lane.flush()
+                with span("frontend.presence_flush"):
+                    lane.flush()
             except Exception as e:  # noqa: BLE001
                 self.logger.error("presence_flush_error", message=str(e))
 
     async def _summarize_loop(self, interval: float = 0.05) -> None:
         while True:
             try:
-                for server in self._all_servers():
-                    by_tenant: dict = {}
-                    for key in list(server._orderers):
-                        tenant, _, doc = key.partition("/")
-                        by_tenant.setdefault(tenant, []).append(doc)
-                    summ = self._summarizer_for(server)
-                    wrote = 0
-                    for tenant, docs in by_tenant.items():
-                        wrote += summ.run_pass(tenant, docs)
-                    if wrote and self._log_flush and \
-                            hasattr(server.log, "flush"):
-                        server.log.flush()
-                    if wrote:
-                        get_journal().emit(
-                            "summary.commit", docs=wrote,
-                            part=getattr(server, "part_k", None))
+                with span("frontend.summarize_pass"):
+                    self._summarize_pass()
             except Exception as e:  # noqa: BLE001 — the loop must outlive
                 # one doc's refusal/IO error
                 self.logger.error("summarize_loop_error", message=str(e))
             await asyncio.sleep(interval)
 
+    def _summarize_pass(self) -> None:
+        for server in self._all_servers():
+            by_tenant: dict = {}
+            for key in list(server._orderers):
+                tenant, _, doc = key.partition("/")
+                by_tenant.setdefault(tenant, []).append(doc)
+            summ = self._summarizer_for(server)
+            wrote = 0
+            for tenant, docs in by_tenant.items():
+                wrote += summ.run_pass(tenant, docs)
+            if wrote and self._log_flush and hasattr(server.log, "flush"):
+                server.log.flush()
+            if wrote:
+                get_journal().emit("summary.commit", docs=wrote,
+                                   part=getattr(server, "part_k", None))
+
     async def _poll_backchannels(self) -> None:
         while True:
-            moved = False
+            grown = []
             for bc in self._backchannels:
                 try:
                     if bc.poll():
-                        bc.drain()
-                        moved = True
+                        grown.append(bc)
                 except Exception as e:  # noqa: BLE001
-                    # drain advances the cursor BEFORE the handler runs,
-                    # so continuing resumes at the NEXT record — one
-                    # poisoned record must not kill every stage's
-                    # pipeline (it used to: the task died silently)
                     self.logger.error("backchannel_record_error",
                                       message=str(e))
-                    moved = True
-            if moved and self._log_flush:
-                # acks ordered above must become visible to the stages
-                self.server.log.flush()
+            if grown:
+                with span("frontend.backchannel"):
+                    self._drain_backchannels(grown)
             await asyncio.sleep(0.002)
+
+    def _drain_backchannels(self, grown) -> None:
+        """Deliver the polled backchannels' new records, then flush."""
+        for bc in grown:
+            try:
+                bc.drain()
+            except Exception as e:  # noqa: BLE001
+                # drain advances the cursor BEFORE the handler runs, so
+                # continuing resumes at the NEXT record — one poisoned
+                # record must not kill every stage's pipeline (it used
+                # to: the task died silently)
+                self.logger.error("backchannel_record_error",
+                                  message=str(e))
+        if self._log_flush:
+            # acks ordered above must become visible to the stages
+            self.server.log.flush()
 
     async def _start(self) -> None:
         # deep backlog: load tests open hundreds of connections at once,
@@ -2454,6 +2489,8 @@ class NetworkFrontEnd:
                 self._summarize_loop()))
         self._bg_tasks.append(asyncio.get_running_loop().create_task(
             self._presence_loop()))
+        self._bg_tasks.append(asyncio.get_running_loop().create_task(
+            loop_lag_ticker()))
         if self.shard_host is not None:
             loop = asyncio.get_running_loop()
 
@@ -2564,10 +2601,11 @@ class NetworkFrontEnd:
 
             def _sweep():
                 sweep_n[0] += 1
-                if sweep_n[0] % 300 == 0:
-                    gc.unfreeze()
-                gc.collect()
-                gc.freeze()
+                with span("frontend.gc_sweep"):
+                    if sweep_n[0] % 300 == 0:
+                        gc.unfreeze()
+                    gc.collect()
+                    gc.freeze()
                 loop.call_later(2.0, _sweep)
             loop.call_later(2.0, _sweep)
         if self._log_flush:
@@ -2575,14 +2613,30 @@ class NetworkFrontEnd:
             # killed core resumes from them (deli/scribe offsets +
             # scriptorium retention base ride the checkpoint topic)
             def _checkpoint():
-                for server in self._all_servers():
-                    server.checkpoint_all()
-                self._flush_logs()
+                with span("frontend.pipeline_checkpoint"):
+                    for server in self._all_servers():
+                        server.checkpoint_all()
+                    self._flush_logs()
                 loop.call_later(2.0, _checkpoint)
             loop.call_later(2.0, _checkpoint)
+        if self.trace_dir:
+            import signal
+
+            loop.add_signal_handler(signal.SIGUSR1, self._trace_start)
+            loop.add_signal_handler(signal.SIGUSR2, self._trace_stop)
         # readiness marker for process supervisors / tests
         print(f"LISTENING {self.host}:{self.port}", flush=True)
         loop.run_forever()
+
+    def _trace_start(self) -> None:
+        if not tracing_armed():  # a repeated signal is a no-op
+            tracing_start(self.trace_dir, "core",
+                          counters=(self.counters,))
+
+    def _trace_stop(self) -> None:
+        path = tracing_stop()
+        if path is not None:
+            print(f"SPANS {path}", flush=True)
 
 
 def _apply_overload_flags(front: "NetworkFrontEnd", args, parser) -> None:
@@ -2736,6 +2790,11 @@ def main() -> None:
                              "per second this core will run; excess "
                              "first-routes park on the retry lane "
                              "(0 disarms)")
+    parser.add_argument("--trace-dir", default=None, metavar="DIR",
+                        help="armed tracing: SIGUSR1 starts recording the "
+                             "program's spans, SIGUSR2 stops, writes "
+                             "DIR/spans-core-<pid>.json and prints "
+                             "SPANS <path>")
     parser.add_argument("--boot-burst", type=int, default=32,
                         metavar="N",
                         help="boot-storm admission burst size")
@@ -2791,6 +2850,7 @@ def main() -> None:
         if args.max_message_size is not None:
             front.max_message_size = args.max_message_size
         _apply_overload_flags(front, args, parser)
+        front.trace_dir = args.trace_dir
         if args.probe and front._health_cfg is None:
             # flag-armed on top of a spec without a health stanza
             # (spec.health goes through build_core)
@@ -2856,6 +2916,7 @@ def main() -> None:
         front.enable_summarizer(args.summarize_every)
     for state_dir in args.consume_backchannel:
         front.attach_backchannel(state_dir)
+    front.trace_dir = args.trace_dir
     front.serve_forever()
 
 

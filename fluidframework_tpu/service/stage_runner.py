@@ -47,6 +47,9 @@ import sys
 import time
 from typing import Optional
 
+from ..obs.metrics import tier_counters
+from ..utils.telemetry import (sample, span, tracing_armed, tracing_start,
+                               tracing_stop)
 from .core import InMemoryDb, summary_versions_collection
 from .durable_log import DurableLog, log_channel_stream
 from .synthetic import chanop_of
@@ -87,6 +90,10 @@ class _StageHostBase:
     #: set to drain-stop (the applier's SIGINT): run_forever finishes
     #: its pass, checkpoints and returns
     stopping = False
+    #: armed tracing: "start" or "stop", set by a signal handler and
+    #: acted on by run_forever between loop turns — never inside the
+    #: handler, which can land while a span holds the window's lock
+    trace_request: Optional[str] = None
 
     def _fault(self, point: str, **ctx) -> None:
         if self.fault_plane is not None:
@@ -105,6 +112,7 @@ class _StageHostBase:
         self._known: set[str] = set()
         self._last_checkpoint = time.monotonic()
         self.checkpoint_every_s = 1.0
+        self.counters = tier_counters("stage")
 
     def _owns(self, topic: str) -> bool:
         if self.partition is None:
@@ -176,12 +184,15 @@ class _StageHostBase:
         self._ctl_mtime = None
         self._steps_done = 0
         while not self.stopping:
+            if self.trace_request is not None:
+                self._serve_trace_request()
             now = time.monotonic()
             if now - last_discover >= 0.25:  # listdir is not free at 2ms
                 last_discover = now
                 self.discover()
                 self._read_ctl()
-            moved = self.shared.poll()
+            with span("stage.poll"):
+                moved = self.shared.poll()
             if self._ctl.get("mode") != "pause":
                 # leaving (or never entering) a pause episode resets the
                 # step ledger: each pause session's budget counts from 0,
@@ -200,12 +211,15 @@ class _StageHostBase:
                 time.sleep(0.01)
                 continue
             if moved:
-                self.shared.drain()
+                with span("stage.drain"):
+                    self.counters.inc("stage.records.drained",
+                                      self.shared.drain())
             now = time.monotonic()
             if now - self._last_checkpoint >= self.checkpoint_every_s:
                 self._last_checkpoint = now
                 self.checkpoint()
-            self.state.flush()
+            with span("stage.state_flush"):
+                self.state.flush()
             if not moved:
                 time.sleep(POLL_INTERVAL_S)
         self.checkpoint()
@@ -226,6 +240,11 @@ class _StageHostBase:
         return moved
 
     # ------------------------------------------------------------ per-stage
+
+    def _serve_trace_request(self) -> None:
+        """Act on :attr:`trace_request`; a stage that traces overrides
+        this."""
+        self.trace_request = None
 
     def attach(self, topic: str) -> None:
         raise NotImplementedError
@@ -327,6 +346,11 @@ class ApplierStage(_StageHostBase):
     launcher's environment); a doc past ``applier_max_docs`` ends the
     process (:class:`..parallel.placement.PlacementFull`)."""
 
+    #: armed tracing: where the span file goes, and whether the stage
+    #: also runs the JAX profiler there over the window
+    trace_dir: Optional[str] = None
+    trace_profile = False
+
     def __init__(self, log_dir: str, state_dir: str,
                  ds_id: str = "default", channel_id: str = "text",
                  partition=None):
@@ -354,6 +378,11 @@ class ApplierStage(_StageHostBase):
         # last-applicable-op-applied — otherwise a stream ending in a
         # summary ack reads as forever lagging
         self._watermarks: dict[str, int] = {}
+        # per topic: the deli wall stamp of the newest message consumed,
+        # and the applied seq last published — "applied" records whose
+        # seq advanced give the deli-to-applied lag
+        self._stamps: dict[str, float] = {}
+        self._published: dict[str, int] = {}
 
     def attach(self, topic: str) -> None:
         tenant, doc = _doc_of(topic)
@@ -367,6 +396,7 @@ class ApplierStage(_StageHostBase):
             if abatch is not None:
                 self._watermarks[topic] = max(
                     self._watermarks.get(topic, 0), abatch.last_seq)
+                self._stamps[topic] = abatch.timestamp
                 if abatch.last_seq > self.applier.applied_seq(tenant, doc):
                     self.applier.ingest_array_batch(tenant, doc, abatch)
                 return
@@ -375,6 +405,7 @@ class ApplierStage(_StageHostBase):
             self._watermarks[topic] = max(
                 self._watermarks.get(topic, 0),
                 msgs[-1].sequence_number)
+            self._stamps[topic] = msgs[-1].timestamp
             # replay idempotency: the farm checkpoint is saved BEFORE
             # the offset checkpoints, so a crash in between replays a
             # window of already-applied ops — skip by sequence number
@@ -392,12 +423,37 @@ class ApplierStage(_StageHostBase):
 
         self.shared.subscribe(topic, on_deltas, from_offset=start)
 
+    def _serve_trace_request(self) -> None:
+        """Start or stop the program's spans (utils/telemetry.py) over
+        :attr:`trace_dir`, with the stage's counters; with
+        :attr:`trace_profile` the JAX profiler runs into the same
+        directory over the window. A stop prints ``SPANS <path>``. A
+        repeated request is a no-op."""
+        import jax
+
+        req, self.trace_request = self.trace_request, None
+        if req == "start" and not tracing_armed():
+            if self.trace_profile:
+                jax.profiler.start_trace(self.trace_dir)
+            tracing_start(self.trace_dir, "stage", counters=(self.counters,))
+        elif req == "stop" and tracing_armed():
+            path = tracing_stop()
+            if self.trace_profile:
+                jax.profiler.stop_trace()
+            print(f"SPANS {path}", flush=True)
+
     @classmethod
-    def serve(cls, log_dir: str, state_dir: str, partition=None) -> None:
+    def serve(cls, log_dir: str, state_dir: str, partition=None,
+              trace_dir: Optional[str] = None) -> None:
         """The applier process: hold the chip, print ``READY`` and a
         ``STAGE {...}`` line (device, kernel lane, geometry), run until
         SIGINT drain-stops it, then print ``STOPPED {...}``. A doc past
-        ``applier_max_docs`` exits 1 naming the setting."""
+        ``applier_max_docs`` exits 1 naming the setting.
+
+        With ``trace_dir``, SIGUSR1 starts the profiler and the spans
+        and SIGUSR2 stops both, writes the spans' file beside the
+        profile and prints ``SPANS <path>``, each at the loop's next
+        turn."""
         from ..parallel.placement import PlacementFull
         from ..utils.chip import (CompileClock, init_compile_cache,
                                   peak_bytes_in_use, require_tpu)
@@ -409,6 +465,12 @@ class ApplierStage(_StageHostBase):
         a = stage.applier
         signal.signal(signal.SIGINT,
                       lambda *_: setattr(stage, "stopping", True))
+        if trace_dir:
+            stage.trace_dir, stage.trace_profile = trace_dir, True
+            signal.signal(signal.SIGUSR1,
+                          lambda *_: setattr(stage, "trace_request", "start"))
+            signal.signal(signal.SIGUSR2,
+                          lambda *_: setattr(stage, "trace_request", "stop"))
         print("READY", flush=True)
         print("STAGE " + json.dumps({
             "platform": dev.platform, "device_kind": dev.device_kind,
@@ -434,30 +496,39 @@ class ApplierStage(_StageHostBase):
         # a restart resumes from the OLD offsets and replays the window
         # (ingest skips by sequence number)
         self._fault("stage.pre_checkpoint")
-        self.applier.flush()
-        self.applier.finalize()
-        save_applier_checkpoint(self.applier, self._ckpt_path)
-        # crash window 2: the farm is saved but the offset checkpoints /
-        # "applied" emits are not — the restart replays against a NEWER
-        # farm, the skip-by-seq path's hardest case
-        self._fault("stage.post_checkpoint")
-        # thread the hoptail across the process boundary: the applier's
-        # last stage/execute wall stamps ride the "applied" record so
-        # the core can fold stage_to_execute into its own registry
-        wave_hops = getattr(self.applier, "last_wave_hops", None)
-        if wave_hops is not None:  # consume: one fold per wave
-            self.applier.last_wave_hops = None
+        with span("stage.checkpoint"):
+            with span("applier.flush"):
+                self.applier.flush()
+            self.applier.finalize()
+            written = save_applier_checkpoint(self.applier,
+                                              self._ckpt_path)
+            self.counters.inc("stage.checkpoint.bytes", written)
+            # crash window 2: the farm is saved but the offset
+            # checkpoints / "applied" emits are not — the restart
+            # replays against a NEWER farm, the skip-by-seq path's
+            # hardest case
+            self._fault("stage.post_checkpoint")
+            with span("stage.publish"):
+                self._publish()
+
+    def _publish(self) -> None:
+        """Offset checkpoints and one "applied" record per doc; with
+        tracing armed, each doc whose applied seq advanced samples its
+        deli-to-applied lag."""
+        now = time.time()
         for topic, offset in self._offsets.items():
             tenant, doc = _doc_of(topic)
             self.save_checkpoint(tenant, doc, {"offset": offset})
-            rec = {"kind": "applied", "tenant": tenant, "doc": doc,
-                   "applied_seq": max(
-                       self._watermarks.get(topic, 0),
-                       self.applier.applied_seq(tenant, doc))}
-            if wave_hops is not None:
-                rec["wave_hops"] = list(wave_hops)
-                wave_hops = None  # one observation per wave, not per doc
-            self.emit(rec)
+            seq = max(self._watermarks.get(topic, 0),
+                      self.applier.applied_seq(tenant, doc))
+            self.emit({"kind": "applied", "tenant": tenant, "doc": doc,
+                       "applied_seq": seq})
+            if seq > self._published.get(topic, 0):
+                self._published[topic] = seq
+                stamp = self._stamps.get(topic)
+                if stamp:
+                    sample("stage.deli_to_applied.ms", (now - stamp) * 1e3)
+        self.counters.inc("stage.publish.records", 2 * len(self._offsets))
 
 
 STAGES = {"scribe": ScribeStage, "applier": ApplierStage}
@@ -473,6 +544,10 @@ def main() -> None:
     parser.add_argument("--partition", default=None, metavar="K/N",
                         help="own only docs with doc_partition == K of N "
                              "(N stage processes split the doc space)")
+    parser.add_argument("--trace-dir", default=None, metavar="DIR",
+                        help="applier: SIGUSR1 starts the profiler and "
+                             "the program's spans, SIGUSR2 writes both "
+                             "under DIR and prints SPANS <path>")
     args = parser.parse_args()
     signal.signal(signal.SIGTERM, lambda *a: os._exit(0))
     partition = None
@@ -480,7 +555,8 @@ def main() -> None:
         k, _, n = args.partition.partition("/")
         partition = (int(k), int(n))
     if args.stage == "applier":
-        ApplierStage.serve(args.log_dir, args.state_dir, partition)
+        ApplierStage.serve(args.log_dir, args.state_dir, partition,
+                           trace_dir=args.trace_dir)
         return
     stage = STAGES[args.stage](args.log_dir, args.state_dir,
                                partition=partition)

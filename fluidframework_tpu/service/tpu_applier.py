@@ -67,6 +67,7 @@ from ..protocol.messages import MessageType, SequencedDocumentMessage
 from ..parallel.placement import DocPlacement
 from ..utils.contracts import register_kernel_contract
 from ..utils.affinity import blocking
+from ..utils.telemetry import span
 
 MARKER_GLYPH = "￼"  # arena placeholder byte for markers (flags classify)
 
@@ -419,7 +420,9 @@ class TpuDocumentApplier:
         self.stage_seconds = 0.0
         self.stage_overlap_seconds = 0.0
         self.stage_bytes = 0
-        self.exec_seconds = 0.0
+        # the execute half's wall time: an asynchronous enqueue of the
+        # step, not its device time (that is in the profiler's trace)
+        self.dispatch_seconds = 0.0
         self.waves_staged = 0
         self._registry = None
         # contract-kernel selection: auto = Pallas on real TPU, XLA scan
@@ -844,10 +847,12 @@ class TpuDocumentApplier:
         same pair; overlap comes from the execute half being an async
         dispatch, so the NEXT iteration's stage half runs on the host
         while this wave executes on device."""
-        staged = self._stage_wave(parts)
+        with span("applier.stage_wave"):
+            staged = self._stage_wave(parts)
         if staged is None:
             return 0
-        return self._execute_wave(staged)
+        with span("applier.dispatch"):
+            return self._execute_wave(staged)
 
     # --------------------------------------------- stage / execute halves
 
@@ -990,9 +995,6 @@ class TpuDocumentApplier:
         reg.set_gauge("applier.stage.overlap_ratio",
                       self.stage_overlap_seconds / self.stage_seconds,
                       lane=staged.lane)
-        # applier/stage hop: wall-clock stamp at stage completion, the
-        # hoptail's clock — _execute_wave closes the stage→execute leg
-        self._last_stage_wall = time.time()
         if self.fault_plane is not None:
             # chaos seam: wave N+1 staged (popped from the staging dict,
             # device buffers resident) but NOT yet executed — a crash
@@ -1020,22 +1022,9 @@ class TpuDocumentApplier:
         if not self._overlap:
             jax.block_until_ready(self._exec_marker)
         dt = time.perf_counter() - t0
-        self.exec_seconds += dt
-        reg = self._metrics()
-        reg.inc("applier.exec.seconds", dt, lane=staged.lane)
-        # applier/execute hop: the dispatch-split leg of the hop
-        # breakdown. Observed directly into the hop family (this wave
-        # never rides a wire hoptail), and retained as last_wave_hops so
-        # a subprocess ApplierStage can thread the stamps over its
-        # backchannel for the parent core's registry.
-        stage_wall = getattr(self, "_last_stage_wall", None)
-        exec_wall = time.time()
-        if stage_wall is not None:
-            ms = (exec_wall - stage_wall) * 1e3
-            reg.observe("obs.hop.ms", ms, pair="stage_to_execute")
-            reg.observe_windowed("obs.hop.window_ms", ms,
-                                 pair="stage_to_execute")
-            self.last_wave_hops = ((stage_wall, exec_wall))
+        self.dispatch_seconds += dt
+        self._metrics().inc("applier.dispatch.seconds", dt,
+                            lane=staged.lane)
         self.dispatches += 1
         self._dispatches_since_check += 1
         if self.fault_plane is not None:
@@ -1180,6 +1169,10 @@ class TpuDocumentApplier:
     def finalize(self) -> None:
         """Flush staged ops and fence the device: after this, every doc's
         state (or its host escalation) reflects everything ingested."""
+        with span("applier.fence"):
+            self._finalize()
+
+    def _finalize(self) -> None:
         if self._async:
             import time as _time
 
@@ -1388,16 +1381,53 @@ def save_applier_checkpoint(applier: "TpuDocumentApplier",
     of deli's Mongo checkpoint, SURVEY §5.4).
 
     Call after ``finalize()`` (the state must be fenced); host-mode docs
-    are serialized as their oracle snapshots.
+    are serialized as their oracle snapshots. Returns the bytes written
+    (the arrays' file and the JSON sidecar).
     """
+    with span("checkpoint.save"):
+        return _save_applier_checkpoint(applier, path)
+
+
+def _save_applier_checkpoint(applier: "TpuDocumentApplier",
+                             path: str) -> int:
     import json as _json
+    import os as _os
 
     applier.finalize()
-    arrays = {f: np.asarray(getattr(applier.state, f))
-              for f in ("length", "text_start", "flags", "ins_seq",
-                        "ins_client", "rem_seq", "rem_client_a",
-                        "rem_client_b", "prop_key", "prop_val", "count",
-                        "overflow")}
+    with span("checkpoint.device_get"):
+        arrays = {f: np.asarray(getattr(applier.state, f))
+                  for f in ("length", "text_start", "flags", "ins_seq",
+                            "ins_client", "rem_seq", "rem_client_a",
+                            "rem_client_b", "prop_key", "prop_val",
+                            "count", "overflow")}
+    # crash-atomic commit: a periodic saver can be SIGKILLed mid-write,
+    # and a torn .npz must never be what a restart loads. The arrays go
+    # to an alternating generation file; the .json (which NAMES the
+    # generation) is renamed into place last — the rename is the commit
+    # point, and the previous consistent pair survives until then.
+    gen = 0
+    try:
+        with open(path + ".json") as f:
+            gen = 1 - int(_json.load(f).get("gen", 0))
+    except (OSError, ValueError):
+        pass
+    npz_path = f"{path}.g{gen}.npz"
+    with span("checkpoint.write_arrays"):
+        with open(npz_path + ".tmp", "wb") as f:
+            np.savez_compressed(f, **arrays)
+        _os.replace(npz_path + ".tmp", npz_path)
+    with span("checkpoint.write_meta"):
+        written = _os.path.getsize(npz_path) + _write_meta(applier, path,
+                                                           gen)
+    return written
+
+
+def _write_meta(applier: "TpuDocumentApplier", path: str, gen: int) -> int:
+    """The JSON sidecar, renamed into place (the commit point); returns
+    its size in bytes."""
+    import json as _json
+    import os as _os
+
     meta = {
         "max_docs": applier.max_docs,
         "max_slots": applier.max_slots,
@@ -1420,28 +1450,13 @@ def save_applier_checkpoint(applier: "TpuDocumentApplier",
         # any downtime ops below it from the summarizer's gate)
         "restore_applied": {str(k): v
                             for k, v in applier._restore_applied.items()},
+        "gen": gen,
     }
-    # crash-atomic commit: a periodic saver can be SIGKILLed mid-write,
-    # and a torn .npz must never be what a restart loads. The arrays go
-    # to an alternating generation file; the .json (which NAMES the
-    # generation) is renamed into place last — the rename is the commit
-    # point, and the previous consistent pair survives until then.
-    import os as _os
-
-    gen = int(meta.get("gen", 0))
-    try:
-        with open(path + ".json") as f:
-            gen = 1 - int(_json.load(f).get("gen", 0))
-    except (OSError, ValueError):
-        pass
-    meta["gen"] = gen
-    npz_path = f"{path}.g{gen}.npz"
-    with open(npz_path + ".tmp", "wb") as f:
-        np.savez_compressed(f, **arrays)
-    _os.replace(npz_path + ".tmp", npz_path)
     with open(path + ".json.tmp", "w") as f:
         _json.dump(meta, f)
+    size = _os.path.getsize(path + ".json.tmp")
     _os.replace(path + ".json.tmp", path + ".json")
+    return size
 
 
 def load_applier_checkpoint(path: str, **applier_kwargs

@@ -1,4 +1,4 @@
-"""Base utils (SURVEY §1.1): telemetry logger, perf events, metrics,
+"""Base utils (SURVEY §1.1): telemetry logger, spans, metrics,
 wire-trace consumption, kernel-contract registry.
 """
 
@@ -23,7 +23,6 @@ from .telemetry import (  # noqa: F401
     HOPS,
     BufferSink,
     Counters,
-    PerformanceEvent,
     TelemetryLogger,
     TraceAggregator,
     count_unknown_hops,

@@ -1,4 +1,4 @@
-"""Telemetry: namespaced logger + perf events + metrics + trace hops.
+"""Telemetry: namespaced logger + metrics + trace hops + spans.
 
 Ref: packages/utils/telemetry-utils/src/logger.ts — ChildLogger
 namespacing (:239), MultiSinkLogger (:283), PerformanceEvent scoped
@@ -10,12 +10,19 @@ Differences by design: sinks are plain callables (no transport baked
 in), and the trace consumer turns the hops deli already stamps into the
 per-hop latency breakdown the load benches report — the reference
 stamps traces but ships them to an external telegraf; here the
-aggregation is in-process and queryable.
+aggregation is in-process and queryable. The reference's scoped
+``PerformanceEvent`` is :func:`span` here: named from the closed
+:data:`SPANS` catalog, free when tracing is off, and written out once
+per traced window (see "spans" below).
 """
 
 from __future__ import annotations
 
+import json
+import os
 import random
+import sys
+import threading
 import time
 from collections import defaultdict
 from typing import Any, Callable, Optional
@@ -105,6 +112,247 @@ def count_unknown_hops(hops) -> int:
     return sum(1 for i, _ in hops if not 0 <= i < len(HOPS))
 
 
+# -------------------------------------------------------------------- spans
+#
+# One span primitive for the whole program. ``with span("stage.poll"):``
+# times a piece of work in the process that does it. Names form the
+# closed catalog below (the fluidlint ``metric-name`` pass rejects a
+# literal ``span("...")`` name that is not in it); SAMPLES names the
+# observations an armed trace keeps every value of.
+#
+# Disarmed (the default) ``span`` is one module-global test returning a
+# shared no-op context manager: no allocation, no clock read, no lock.
+# ``tracing_start(dir, role)`` arms it: each span then records (name,
+# start wall ns, duration ns, parent index, thread id) into a bounded
+# in-memory list — past DEFAULT_SPAN_CAPACITY entries new spans are
+# counted as overflow, never stored — and, in a process that has
+# imported JAX, also opens a ``jax.profiler.TraceAnnotation`` of the
+# same name, so the span lands on the profiler's trace beside the
+# device ops. Arming there also marks the window's start with a
+# ``trace.window`` annotation, at the wall ns the output keeps as its
+# anchor: whoever reads the profile finds that annotation, and the
+# difference of the two starts maps wall ns onto the profile's clock.
+# ``tracing_stop()`` writes everything once, to
+# ``dir/spans-<role>-<pid>.json``: the spans (those still open cut at the
+# stop and listed under ``open``), the samples, the window's
+# deltas of the counters handed to ``tracing_start``, and the anchor.
+
+#: span name -> what it times. A pure literal (fluidlint reads it).
+SPANS = {
+    # applier stage (service/stage_runner.py)
+    "stage.poll": "refresh of the shared log's subscribed topics",
+    "stage.drain": "delivery of the new records into the applier",
+    "stage.checkpoint": "ApplierStage.checkpoint, the whole call",
+    "stage.publish": "offset checkpoints and 'applied' records",
+    "stage.state_flush": "flush of the stage's own log",
+    # device applier (service/tpu_applier.py)
+    "applier.flush": "dispatch of every staged op (stage checkpoint)",
+    "applier.fence": "finalize: wait for the device, read overflow",
+    "applier.stage_wave": "host half of a wave: pack, scatter, put",
+    "applier.dispatch": "enqueue of one jitted step (asynchronous)",
+    "checkpoint.save": "save_applier_checkpoint, the whole call",
+    "checkpoint.device_get": "copy of the state arrays to the host",
+    "checkpoint.write_arrays": "savez_compressed of the arrays + rename",
+    "checkpoint.write_meta": "JSON sidecar: build, dump, rename",
+    # durable log (service/durable_log.py)
+    "log.flush": "DurableLog.flush",
+    # socket doors (service/front_end.py)
+    "frontend.ingress_batch": "one drain-batched wave of frames",
+    "frontend.backchannel": "one backchannel pass that moved records",
+    "frontend.summarize_pass": "one pass of the summarizer loop",
+    "frontend.presence_flush": "one presence-lane flush tick",
+    "frontend.gc_sweep": "the timed cycle-collector sweep",
+    "frontend.pipeline_checkpoint": "the periodic pipeline checkpoint",
+    "frontend.loop_stall": "a serving-loop wake-up late past 20 ms",
+}
+
+#: sample name -> what one value is; an armed trace keeps every value.
+SAMPLES = {
+    "frontend.loop.lag_ms": "how late the 10 ms loop ticker woke, ms",
+    "stage.deli_to_applied.ms": "deli stamp to 'applied' record, ms",
+}
+
+#: the profiler annotation that marks the traced window's start (the
+#: anchor)
+WINDOW_SPAN = "trace.window"
+
+#: spans an armed window stores; later ones count as overflow
+DEFAULT_SPAN_CAPACITY = 1 << 20
+
+
+class _NoSpan:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Trace:
+    """The armed recording of one window (see the section comment)."""
+
+    def __init__(self, directory: str, role: str, counters: tuple):
+        self.directory, self.role = directory, role
+        self.capacity = DEFAULT_SPAN_CAPACITY
+        self.spans: list = []  # [name, start_ns, dur_ns, parent, tid]
+        self.samples: dict = {}
+        self.overflow = 0
+        self.lock = threading.Lock()
+        self.local = threading.local()
+        self.counters = counters
+        self.counts0 = [dict(c._counts) for c in counters]
+        jax = sys.modules.get("jax")
+        self.annotate = jax.profiler.TraceAnnotation if jax else None
+        self.anchor_wall_ns = time.time_ns()
+        if self.annotate is not None:
+            with self.annotate(WINDOW_SPAN):
+                pass
+
+    def stack(self) -> list:
+        s = getattr(self.local, "stack", None)
+        if s is None:
+            s = self.local.stack = []
+        return s
+
+
+class _Span:
+    __slots__ = ("trace", "name", "idx", "ann")
+
+    def __init__(self, trace: _Trace, name: str):
+        if name not in SPANS:
+            raise ValueError(f"span {name!r} is not in telemetry.SPANS")
+        self.trace, self.name, self.ann = trace, name, None
+
+    def __enter__(self):
+        tr = self.trace
+        stack = tr.stack()
+        rec = [self.name, 0, -1, stack[-1] if stack else -1,
+               threading.get_ident()]
+        with tr.lock:
+            idx = len(tr.spans)
+            if idx < tr.capacity:
+                tr.spans.append(rec)
+            else:
+                tr.overflow += 1
+                idx = -1
+        self.idx = idx
+        if idx >= 0:
+            stack.append(idx)
+        if tr.annotate is not None:
+            self.ann = tr.annotate(self.name)
+            self.ann.__enter__()
+        rec[1] = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t = time.time_ns()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        if self.idx >= 0:
+            rec = self.trace.spans[self.idx]
+            rec[2] = t - rec[1]
+            stack = self.trace.stack()
+            if stack and stack[-1] == self.idx:
+                stack.pop()
+            elif self.idx in stack:
+                stack.remove(self.idx)
+        return False
+
+
+_ARMED: Optional[_Trace] = None
+
+
+def span(name: str):
+    """Time the ``with`` block as span ``name`` (a :data:`SPANS` key)
+    when tracing is armed; a shared no-op otherwise."""
+    t = _ARMED
+    if t is None:
+        return _NO_SPAN
+    return _Span(t, name)
+
+
+def record_span(name: str, start_ns: int, dur_ns: int) -> None:
+    """Record a span measured after the fact (a late loop wake-up), with
+    no parent; nothing when disarmed."""
+    t = _ARMED
+    if t is None:
+        return
+    if name not in SPANS:
+        raise ValueError(f"span {name!r} is not in telemetry.SPANS")
+    with t.lock:
+        if len(t.spans) < t.capacity:
+            t.spans.append([name, int(start_ns), int(dur_ns), -1,
+                            threading.get_ident()])
+        else:
+            t.overflow += 1
+
+
+def sample(name: str, value: float) -> None:
+    """Keep one value of observation ``name`` (a :data:`SAMPLES` key)
+    in the armed trace; nothing when disarmed."""
+    t = _ARMED
+    if t is None:
+        return
+    if name not in SAMPLES:
+        raise ValueError(f"sample {name!r} is not in telemetry.SAMPLES")
+    with t.lock:
+        t.samples.setdefault(name, []).append([time.time_ns(), value])
+
+
+def tracing_armed() -> bool:
+    return _ARMED is not None
+
+
+def tracing_start(directory: str, role: str, counters=()) -> None:
+    """Arm span recording for this process until :func:`tracing_stop`.
+    ``counters`` are :class:`Counters` whose deltas over the window the
+    output keeps. Where the JAX profiler is to hold the spans, start it
+    first."""
+    global _ARMED
+    if _ARMED is not None:
+        raise RuntimeError("tracing is already armed")
+    _ARMED = _Trace(directory, role, tuple(counters))
+
+
+def tracing_stop() -> Optional[str]:
+    """Disarm and write the window out; returns the file's path (None
+    when tracing was not armed). Spans still open are cut at the stop
+    and listed by index under ``open``: whole-span statistics leave them
+    out, a timeline keeps them. Call it from the thread that runs the
+    spans' work (not from a signal handler, which can land while a span
+    holds the window's lock)."""
+    global _ARMED
+    t = _ARMED
+    if t is None:
+        return None
+    _ARMED = None
+    stop_ns = time.time_ns()
+    with t.lock:
+        spans = [list(s) for s in t.spans]
+    still_open = [i for i, s in enumerate(spans) if s[2] < 0]
+    for i in still_open:
+        spans[i][2] = max(0, stop_ns - spans[i][1])
+    counts = {}
+    for c, before in zip(t.counters, t.counts0):
+        for name, v in list(c._counts.items()):
+            counts[name] = counts.get(name, 0) + v - before.get(name, 0)
+    anchor = {"wall_ns": t.anchor_wall_ns, "stop_wall_ns": stop_ns}
+    doc = {"role": t.role, "pid": os.getpid(), "capacity": t.capacity,
+           "overflow": t.overflow, "anchor": anchor, "spans": spans,
+           "open": still_open, "samples": t.samples, "counters": counts}
+    os.makedirs(t.directory, exist_ok=True)
+    path = os.path.join(t.directory, f"spans-{t.role}-{os.getpid()}.json")
+    with open(path + ".tmp", "w") as f:
+        json.dump(doc, f)
+    os.replace(path + ".tmp", path)
+    return path
+
+
 def percentile(sorted_vals: list[float], p: float) -> float:
     if not sorted_vals:
         return 0.0
@@ -148,9 +396,6 @@ class TelemetryLogger:
     def error(self, event: str, **fields: Any) -> None:
         self.send("error", event, **fields)
 
-    def perf(self, event: str, **fields: Any) -> "PerformanceEvent":
-        return PerformanceEvent(self, event, fields)
-
 
 class BufferSink:
     """Ring-buffer sink for tests and the /repl-style debug surface."""
@@ -166,31 +411,6 @@ class BufferSink:
 
     def of(self, event: str) -> list[dict]:
         return [r for r in self.records if r["event"] == event]
-
-
-class PerformanceEvent:
-    """Scoped timing (ref: PerformanceEvent logger.ts:434): emits
-    ``<event>_end`` with duration_ms on success, ``<event>_cancel`` with
-    the error on exception."""
-
-    def __init__(self, logger: TelemetryLogger, event: str, fields: dict):
-        self._logger = logger
-        self._event = event
-        self._fields = fields
-        self._t0 = 0.0
-
-    def __enter__(self) -> "PerformanceEvent":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        ms = (time.perf_counter() - self._t0) * 1e3
-        if exc_type is None:
-            self._logger.send("performance", f"{self._event}_end",
-                              duration_ms=ms, **self._fields)
-        else:
-            self._logger.send("performance", f"{self._event}_cancel",
-                              duration_ms=ms, error=str(exc), **self._fields)
 
 
 class Counters:

@@ -475,6 +475,47 @@ def test_boot_family_members_pass(tmp_path):
     assert metrics_check.check_file(path, repo_root=str(tmp_path)) == []
 
 
+def _span_tree(tmp_path, mod_src, catalog_src=None):
+    """A fake repo with a utils/telemetry.py span catalog and one
+    module."""
+    pkg = tmp_path / "fluidframework_tpu"
+    (pkg / "utils").mkdir(parents=True)
+    (pkg / "utils" / "telemetry.py").write_text(
+        catalog_src if catalog_src is not None else
+        'SPANS = {"stage.poll": "x", "log.flush": "x"}\n'
+        'SAMPLES = {"frontend.loop.lag_ms": "x"}\n')
+    path = pkg / "mod.py"
+    path.write_text(mod_src)
+    return str(path)
+
+
+def test_uncatalogued_span_name_caught(tmp_path):
+    path = _span_tree(
+        tmp_path,
+        "from .utils import telemetry\n"
+        "from .utils.telemetry import record_span, sample, span\n"
+        "def f(n):\n"
+        "    with span('stage.pol'):\n"          # typo'd span
+        "        telemetry.span('log.flush')\n"  # catalogued: fine
+        "    record_span('frontend.stall', 0, 1)\n"
+        "    sample('frontend.loop.lag', 1.0)\n"
+        "    span(n)\n")  # computed: out of scope
+    vs = metrics_check.check_file(path, repo_root=str(tmp_path))
+    msgs = [v.message for v in vs]
+    assert len(vs) == 3, msgs
+    assert any("stage.pol" in m and "SPANS" in m for m in msgs)
+    assert any("frontend.stall" in m for m in msgs)
+    assert any("frontend.loop.lag" in m and "SAMPLES" in m for m in msgs)
+
+
+def test_nonliteral_span_catalog_caught(tmp_path):
+    _span_tree(tmp_path, "x = 1\n",
+               catalog_src="SPANS = dict(make())\nSAMPLES = {}\n")
+    vs = metrics_check.check_metrics(repo_root=str(tmp_path))
+    assert len(vs) == 1 and "pure dict" in vs[0].message, \
+        [str(v) for v in vs]
+
+
 def test_metrics_real_tree_clean():
     assert metrics_check.check_metrics(repo_root=REPO) == []
 

@@ -13,9 +13,8 @@ Five planes under test:
 - the metrics history ring — retired buckets survive past the live
   window, the horizon prunes, and ``window_history`` label-filters;
 - the appended hop catalog e2e — ``relay_to_relay`` from a real
-  2-level relay tree (core ← mid ← leaf, separate processes) and
-  ``stage_to_execute`` from the applier backchannel fold, plus the
-  full forced-migration journal chain on in-proc shard hosts.
+  2-level relay tree (core ← mid ← leaf, separate processes), plus
+  the full forced-migration journal chain on in-proc shard hosts.
 """
 
 from __future__ import annotations
@@ -28,11 +27,9 @@ import string
 import subprocess
 import sys
 import time
-import types
 
 import pytest
 
-from fluidframework_tpu.obs import get_registry, parse_prometheus
 from fluidframework_tpu.obs.journal import (
     KINDS,
     Journal,
@@ -46,7 +43,6 @@ from fluidframework_tpu.obs.journal import (
 )
 from fluidframework_tpu.obs.metrics import MetricsRegistry, WindowedSeries
 from fluidframework_tpu.protocol import binwire
-from fluidframework_tpu.service import LocalServer, NetworkFrontEnd
 from fluidframework_tpu.service.front_end import ShardHost
 from fluidframework_tpu.service.placement_plane import MigrationEngine
 from fluidframework_tpu.utils.telemetry import (
@@ -289,21 +285,6 @@ def test_hop_pairs_full_pipeline_with_new_ids():
     assert hop_pairs(skewed) == pairs
     assert count_unknown_hops(skewed) == 2
     assert count_unknown_hops(hops) == 0
-
-
-def test_stage_to_execute_folds_from_applier_backchannel():
-    """An applier stage's wave stamps ride the 'applied' backchannel
-    record and land in THIS core's registry as stage_to_execute."""
-    fe = NetworkFrontEnd(LocalServer())
-    t0 = time.time()
-    rec = {"kind": "applied", "tenant": "t", "doc": "bdoc",
-           "applied_seq": 4, "wave_hops": [t0, t0 + 0.012]}
-    fe._on_backchannel_record(types.SimpleNamespace(value=rec))
-    assert fe.applier_status[("t", "bdoc")] == 4
-    series = parse_prometheus(get_registry().scrape())
-    pairs = {dict(k).get("pair")
-             for k in series.get("fluid_obs_hop_ms_count", {})}
-    assert "stage_to_execute" in pairs
 
 
 # ------------------------------------------------- relay-tree hoptail e2e

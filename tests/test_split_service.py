@@ -16,6 +16,7 @@ replays idempotently, and the pipeline completes.
 from __future__ import annotations
 
 import contextlib
+import json
 import os
 import signal
 import subprocess
@@ -500,6 +501,79 @@ def test_applier_stage_takes_max_docs_from_env(tmp_path, monkeypatch):
         assert stage.wait(timeout=30) == 0
 
 
+def _line_with(proc, prefix: str) -> str:
+    while True:
+        line = proc.stdout.readline()
+        assert line, f"no {prefix!r} line"
+        if line.startswith(prefix):
+            return line[len(prefix):].strip()
+
+
+def test_armed_tracing_writes_both_span_files(tmp_path):
+    """``--trace-dir`` on the core and the applier stage: SIGUSR1 arms
+    the program's spans in both, SIGUSR2 writes each process's file and
+    prints its path; together they hold every span on the served path,
+    the stage's under a profile that marks where their window starts."""
+    from benchmark.program_trace import window_start_ns
+
+    log_dir, state_dir = tmp_path / "log", tmp_path / "applier-state"
+    trace_dir = tmp_path / "trace"
+    procs = {}
+    try:
+        procs["applier"], _ = _spawn(
+            ["fluidframework_tpu.service.stage_runner", "--stage",
+             "applier", "--log-dir", str(log_dir), "--state-dir",
+             str(state_dir), "--trace-dir", str(trace_dir)], "READY")
+        _line_with(procs["applier"], "STAGE")
+        procs["core"], line = _spawn(
+            ["fluidframework_tpu.service.front_end", "--port", "0",
+             "--log-dir", str(log_dir), "--consume-backchannel",
+             str(state_dir), "--trace-dir", str(trace_dir)], "LISTENING")
+        port = int(line.rsplit(":", 1)[1])
+        for p in procs.values():
+            p.send_signal(signal.SIGUSR1)
+        time.sleep(0.5)
+        _drive_docs(port, 4, "traced")
+        assert wait_for(lambda: all(
+            _applied_seq(state_dir, "t", f"traced{i}") > 0
+            for i in range(4)), timeout=120)
+        time.sleep(0.3)
+        docs = {}
+        for role, p in procs.items():
+            p.send_signal(signal.SIGUSR2)
+            with open(_line_with(p, "SPANS")) as f:
+                docs[role] = json.load(f)
+    except BaseException:
+        _dump_stderr(procs)
+        raise
+    finally:
+        _cleanup(procs)
+    stage, core = docs["applier"], docs["core"]
+    assert {s[0] for s in stage["spans"]} >= {
+        "stage.poll", "stage.drain", "stage.state_flush", "stage.checkpoint",
+        "applier.flush", "applier.fence", "applier.stage_wave",
+        "applier.dispatch", "checkpoint.save", "checkpoint.device_get",
+        "checkpoint.write_arrays", "checkpoint.write_meta", "stage.publish",
+        "log.flush"}
+    assert {s[0] for s in core["spans"]} >= {
+        "frontend.ingress_batch", "frontend.backchannel", "log.flush",
+        "frontend.presence_flush"}
+    # the checkpoint's children: the fence, the save and the publish
+    by_idx = {i: s for i, s in enumerate(stage["spans"])}
+    kids = {by_idx[s[3]][0]: [] for s in stage["spans"] if s[3] >= 0}
+    for s in stage["spans"]:
+        if s[3] >= 0:
+            kids[by_idx[s[3]][0]].append(s[0])
+    assert {"applier.fence", "checkpoint.save", "stage.publish"} <= set(
+        kids["stage.checkpoint"])
+    assert stage["samples"]["stage.deli_to_applied.ms"]
+    assert len(core["samples"]["frontend.loop.lag_ms"]) >= 50
+    assert stage["counters"]["stage.checkpoint.bytes"] > 0
+    assert stage["counters"]["stage.records.drained"] > 0
+    assert window_start_ns(str(trace_dir)) is not None
+    assert stage["overflow"] == core["overflow"] == 0
+
+
 def test_applier_stage_exits_when_slots_run_out(tmp_path, monkeypatch):
     """More docs than applier_max_docs ends the stage with a non-zero
     exit naming the setting — never a stage dead but still running."""
@@ -552,6 +626,65 @@ def test_escalated_doc_in_stage_replays_from_the_log(tmp_path):
             oracle.apply_msg(m, local=False)
         assert oracle.get_text()
         assert stage.applier.get_text("bench", doc) == oracle.get_text()
+
+
+def test_stage_stops_tracing_between_turns_not_in_the_handler(tmp_path):
+    """SIGUSR2 landing in the middle of a checkpoint only asks for the
+    stop: tracing stays armed until the checkpoint is over, and the
+    loop's next turn writes the file, with no span cut. A handler that
+    stopped tracing itself could take the window's lock while a span on
+    the same thread held it."""
+    from fluidframework_tpu.service.durable_log import DurableLog
+    from fluidframework_tpu.service.load_gen import run_inproc
+    from fluidframework_tpu.service.stage_runner import ApplierStage
+    from fluidframework_tpu.utils import telemetry
+
+    log = DurableLog(str(tmp_path / "log"))
+    run_inproc(n_docs=2, clients_per_doc=2, ops_per_client=16,
+               batch_size=4, seed=5, log=log)
+    log.flush()
+    stage = ApplierStage(str(tmp_path / "log"), str(tmp_path / "state"))
+    stage.trace_dir = str(tmp_path / "trace")
+    stage.checkpoint_every_s = 0.0
+    seen: dict = {}
+    publish, serve = stage._publish, stage._serve_trace_request
+
+    def publish_after_signal():
+        if not seen:
+            os.kill(os.getpid(), signal.SIGUSR2)
+            assert wait_for(lambda: stage.trace_request == "stop",
+                            timeout=10)
+            seen["armed"] = telemetry.tracing_armed()
+        seen["publishes"] = seen.get("publishes", 0) + 1
+        if seen["publishes"] > 20:  # the stop was never served
+            stage.stopping = True
+        publish()
+
+    def serve_then_end():
+        serve()
+        if seen and not telemetry.tracing_armed():
+            stage.stopping = True
+
+    stage._publish = publish_after_signal
+    stage._serve_trace_request = serve_then_end
+    before = signal.signal(signal.SIGUSR2, lambda *_: setattr(
+        stage, "trace_request", "stop"))
+    stage.trace_request = "start"
+    try:
+        stage.run_forever()
+    finally:
+        signal.signal(signal.SIGUSR2, before)
+        telemetry.tracing_stop()
+    # the signal's turn; the next, which stops tracing first; the
+    # drain-stop's checkpoint
+    assert seen == {"armed": True, "publishes": 3}
+    (name,) = os.listdir(tmp_path / "trace")
+    with open(tmp_path / "trace" / name) as f:
+        doc = json.load(f)
+    assert doc["open"] == []
+    names = [s[0] for s in doc["spans"]]
+    assert "stage.publish" in names and "stage.checkpoint" in names
+    assert all(s[2] >= 0 for s in doc["spans"])
 
 
 def test_require_devices_never_swaps_a_chip_for_host_devices(monkeypatch):

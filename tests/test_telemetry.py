@@ -2,10 +2,14 @@
 wire-trace consumer producing per-hop latency (SURVEY §5.1/§5.5).
 
 Ref: telemetry-utils/src/logger.ts (ChildLogger :239, PerformanceEvent
-:434), services/src/metricClient.ts, protocol ITrace hops.
+:434, here ``span``), services/src/metricClient.ts, protocol ITrace
+hops.
 """
 
+import json
 import time
+
+import pytest
 
 from fluidframework_tpu.protocol.messages import (
     MessageType,
@@ -15,10 +19,10 @@ from fluidframework_tpu.protocol.messages import (
 from fluidframework_tpu.utils import (
     BufferSink,
     Counters,
-    PerformanceEvent,
     TelemetryLogger,
     TraceAggregator,
 )
+from fluidframework_tpu.utils.telemetry import span, tracing_start, tracing_stop
 
 
 def test_child_logger_namespacing_and_shared_sinks():
@@ -42,20 +46,22 @@ def test_sinkless_logger_is_free_and_silent():
     log.info("anything", heavy=object())  # must not raise or format
 
 
-def test_performance_event_duration_and_cancel():
-    sink = BufferSink()
-    log = TelemetryLogger("perf", sinks=[sink])
-    with log.perf("step"):
-        time.sleep(0.01)
-    (end,) = sink.of("step_end")
-    assert end["duration_ms"] >= 8
+@pytest.mark.parametrize("raises", [False, True])
+def test_span_duration_and_exception(tmp_path, raises):
+    """The scoped timer (PerformanceEvent's role): an armed span records
+    its duration whether the block returns or raises, and the exception
+    still propagates."""
+    tracing_start(str(tmp_path), "t")
     try:
-        with log.perf("bad"):
-            raise ValueError("nope")
-    except ValueError:
-        pass
-    (cancel,) = sink.of("bad_cancel")
-    assert "nope" in cancel["error"]
+        with span("stage.poll"):
+            time.sleep(0.01)
+            if raises:
+                raise ValueError("nope")
+    except ValueError as e:
+        assert raises and "nope" in str(e)
+    with open(tracing_stop()) as f:
+        (rec,) = json.load(f)["spans"]
+    assert rec[0] == "stage.poll" and rec[2] >= 8e6
 
 
 def test_counters_snapshot_percentiles():

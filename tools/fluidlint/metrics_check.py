@@ -18,6 +18,12 @@ Two checks over the library package:
   ``tools/fluidlint/registries.py`` in the same change, or
   the lint refuses it (spelling drift like ``net.admission.dropped`` vs
   the canonical ``net.admission.shed`` is exactly the bug this catches).
+- **span names**: every string-literal first argument to ``span(...)``
+  or ``record_span(...)`` must be a key of ``SPANS`` in
+  ``utils/telemetry.py``, and of ``sample(...)`` a key of ``SAMPLES``
+  — the closed catalogs the trace reductions key on. An uncatalogued
+  name would pass silently while tracing is off and raise only in the
+  traced run that was meant to explain a stall.
 - **Counters construction**: ``Counters(...)`` may only be constructed
   in ``utils/telemetry.py`` (its home) and ``obs/metrics.py`` (the
   registry factory). Everywhere else must go through
@@ -33,7 +39,9 @@ import os
 import re
 from typing import Iterable, Optional
 
+from .registries import HOPS_HOME as SPANS_HOME
 from .registries import LOCKED_FAMILIES  # noqa: F401 — re-exported
+from .registries import load_span_catalogs
 from .report import Violation
 
 #: Swept directories (repo-relative). Tests and tools construct Counters
@@ -51,6 +59,17 @@ NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+){2,3}$")
 
 _METHODS = ("inc", "observe", "set_gauge", "observe_windowed")
 
+#: callee name -> the telemetry catalog its literal name must be in
+_CATALOGED = {"span": "SPANS", "record_span": "SPANS", "sample": "SAMPLES"}
+
+
+def _callee(func) -> Optional[str]:
+    if isinstance(func, ast.Name):
+        return func.id
+    if isinstance(func, ast.Attribute):
+        return func.attr
+    return None
+
 
 def _py_files(root: str) -> Iterable[str]:
     for dirpath, dirnames, files in os.walk(root):
@@ -61,9 +80,11 @@ def _py_files(root: str) -> Iterable[str]:
                 yield os.path.join(dirpath, fn)
 
 
-def check_file(path: str, repo_root: Optional[str] = None
-               ) -> list[Violation]:
+def check_file(path: str, repo_root: Optional[str] = None,
+               catalogs: Optional[dict] = None) -> list[Violation]:
     repo_root = repo_root or _repo_root()
+    if catalogs is None:
+        catalogs = load_span_catalogs(repo_root)
     rel = os.path.relpath(path, repo_root)
     with open(path) as f:
         try:
@@ -107,6 +128,17 @@ def check_file(path: str, repo_root: Optional[str] = None
                                            "registries.py if the "
                                            "contract change is "
                                            "intentional"))
+        table = _CATALOGED.get(_callee(func))
+        if (table is not None and catalogs is not None and node.args
+                and isinstance(node.args[0], ast.Constant)
+                and isinstance(node.args[0].value, str)
+                and node.args[0].value not in catalogs[table]):
+            out.append(Violation(
+                pass_name="metric-name", path=rel, line=node.lineno,
+                message=f'"{node.args[0].value}" is not in the telemetry '
+                        f"{table} catalog (utils/telemetry.py)",
+                suggestion=f"fix the name, or add it to {table} with what "
+                           "it times in the same change"))
         if (isinstance(func, ast.Name) and func.id == "Counters"
                 and rel not in COUNTERS_HOMES):
             out.append(Violation(
@@ -122,12 +154,20 @@ def check_metrics(repo_root: Optional[str] = None,
                   roots: tuple = METRIC_ROOTS) -> list[Violation]:
     repo_root = repo_root or _repo_root()
     out: list[Violation] = []
+    catalogs = load_span_catalogs(repo_root)
+    if catalogs is None and os.path.exists(
+            os.path.join(repo_root, SPANS_HOME)):
+        out.append(Violation(
+            pass_name="metric-name", path=SPANS_HOME, line=1,
+            message="SPANS or SAMPLES is missing or not a pure dict "
+                    "literal — span names cannot be checked",
+            suggestion="keep both literal dicts of str -> str"))
     for r in roots:
         root = os.path.join(repo_root, r)
         if not os.path.isdir(root):
             continue
         for path in _py_files(root):
-            out.extend(check_file(path, repo_root))
+            out.extend(check_file(path, repo_root, catalogs))
     return out
 
 

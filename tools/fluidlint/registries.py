@@ -16,6 +16,9 @@ Registries:
   vocabulary (``obs/journal.py`` ``KINDS``; must stay a pure literal).
 - :func:`load_hops` — the hop catalog (``utils/telemetry.py``
   ``HOPS``): ids 0–8 are FROZEN wire values stamped into trace tails.
+- :func:`load_span_catalogs` — the span and sample catalogs
+  (``utils/telemetry.py`` ``SPANS`` / ``SAMPLES``): the closed sets of
+  names ``span(...)`` may time and ``sample(...)`` may keep.
 - :data:`LOCKED_FAMILIES` — metric families whose exact member sets
   are alert-surface contracts (moved here from metrics_check).
 - :func:`load_frame_types` — the binary codec's ``FT_*`` frame ids
@@ -91,6 +94,23 @@ def load_hops(repo_root: Optional[str] = None) -> Optional[tuple]:
     return None
 
 
+# ----------------------------------------------------------- span catalog
+
+def load_span_catalogs(repo_root: Optional[str] = None
+                       ) -> Optional[dict]:
+    """{"SPANS": names, "SAMPLES": names}, or None when either table is
+    missing or not a pure dict literal (the metric-name pass reports
+    that)."""
+    path = os.path.join(repo_root or _repo_root(), HOPS_HOME)
+    out = {}
+    for name in ("SPANS", "SAMPLES"):
+        table = _module_literal(path, name)
+        if not isinstance(table, dict):
+            return None
+        out[name] = frozenset(table)
+    return out
+
+
 # -------------------------------------------------------- metric families
 
 #: prefix -> exact member set. These families are overload-control
@@ -148,7 +168,7 @@ LOCKED_FAMILIES = {
                            "applier.stage.seconds",
                            "applier.stage.bytes",
                            "applier.stage.overlap_ratio",
-                           "applier.exec.seconds"}),
+                           "applier.dispatch.seconds"}),
     # the placement control plane: the net-smoke migration gate, the
     # admin CLI, and the chaos migration campaign key on these exact
     # names (service/placement_plane.py); placement.heat.* are the

@@ -14,10 +14,10 @@ rig. Usage:  python tools/profile_applier.py [--docs D] [--k K]
 
 The applier also accounts its own halves per lane —
 ``applier.stage.seconds`` (host wave assembly + transfer, with the
-hidden-behind-execute fraction) vs ``applier.exec.seconds`` (the step
-dispatch) — so a throughput swing is attributable from its counters: a
-stage swing is host/link time, an exec swing is device time or a kernel
-change. This profile prints that split below (``stage/execute split``)
+hidden-behind-execute fraction) vs ``applier.dispatch.seconds`` (the
+step's enqueue, not its device time) — so a throughput swing is attributable from its counters: a
+stage swing is host/link time, a dispatch swing is the enqueue (or,
+with overlap off, device time) or a kernel change. This profile prints that split below (``stage/execute split``)
 for the dense lane and, when the machine has several devices, the mesh
 lane; the manual pack/h2d/step rows remain the finer microscope.
 """
@@ -37,7 +37,7 @@ import numpy as np  # noqa: E402
 
 def _reset_split(applier) -> None:
     applier.stage_seconds = applier.stage_overlap_seconds = 0.0
-    applier.exec_seconds = 0.0
+    applier.dispatch_seconds = 0.0
     applier.stage_bytes = 0
     applier.waves_staged = 0
 
@@ -160,7 +160,7 @@ def main() -> None:
         if not waves:
             return
         stage_ms = a.stage_seconds / waves * 1e3
-        exec_ms = a.exec_seconds / waves * 1e3
+        exec_ms = a.dispatch_seconds / waves * 1e3
         print(f"  {lane:5s}: stage {stage_ms:7.2f} ms/wave "
               f"({a.stage_overlap_ratio()*100:5.1f}% hidden behind "
               f"execute), exec-call {exec_ms:7.2f} ms/wave, "
