@@ -365,9 +365,11 @@ class MergeTree:
                 raise RuntimeError("cannot snapshot with pending local ops")
             if seg.rem_seq is not None and seg.rem_seq <= self.min_seq:
                 continue  # invisible forever
-            d: dict = {"props": seg.props} if seg.props else {}
+            # copies: annotate changes a segment's props in place, and a
+            # snapshot may be serialized later, on another thread
+            d: dict = {"props": dict(seg.props)} if seg.props else {}
             if seg.is_marker:
-                d["marker"] = seg.marker
+                d["marker"] = dict(seg.marker)
             else:
                 d["text"] = seg.text
             if seg.ins_seq > self.min_seq:

@@ -154,10 +154,25 @@ class TextArena:
         self._len += len(text)
         return start
 
+    def __len__(self) -> int:
+        return self._len
+
     def text(self) -> str:
         if len(self._chunks) > 1:
             self._chunks = ["".join(self._chunks)]
         return self._chunks[0] if self._chunks else ""
+
+    def tail(self, start: int) -> str:
+        """The text from ``start`` on, joined from the newest chunks only
+        (its cost follows the tail, not the arena)."""
+        need, parts = self._len - start, []
+        for chunk in reversed(self._chunks):
+            if need <= 0:
+                break
+            parts.append(chunk)
+            need -= len(chunk)
+        joined = "".join(reversed(parts))
+        return joined[len(joined) - (self._len - start):]
 
     def slice(self, start: int, length: int) -> str:
         return self.text()[start : start + length]

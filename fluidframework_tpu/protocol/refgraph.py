@@ -133,8 +133,10 @@ def frame_record(payload: bytes) -> bytes:
     return _FRAME.pack(len(payload), zlib.crc32(payload)) + payload
 
 
-def scan_records(buf: bytes) -> tuple[list[dict], int]:
-    """Decode the clean prefix of a history file.
+def scan_records(buf: bytes, decode=decode_record
+                 ) -> tuple[list, int]:
+    """Decode the clean prefix of a file of framed records (a history
+    file; ``decode`` turns another file's payloads into its records).
 
     Returns ``(records, clean_end)``: every record that framed and
     CRC-checked, and the byte offset the clean prefix ends at. A torn
@@ -156,7 +158,7 @@ def scan_records(buf: bytes) -> tuple[list[dict], int]:
         if zlib.crc32(payload) != crc:   # torn or corrupt: drop the tail
             break
         try:
-            records.append(decode_record(payload))
+            records.append(decode(payload))
         except Exception:
             break
         off = end

@@ -25,9 +25,10 @@ Stages:
   idempotent by sequence number).
 - ``applier`` — the TPU device farm (TpuDocumentApplier) out of
   process: the deli/broadcast hot path never shares a GIL with device
-  work. Consumes deltas chanops, checkpoints the device farm
-  (save_applier_checkpoint) periodically, and reports per-doc applied
-  seqs on its backchannel as status records.
+  work. Consumes deltas chanops; after every turn that consumed records
+  it checkpoints the docs that moved (a delta on the farm's base,
+  tpu_applier.ApplierCheckpointer) and reports their applied seqs on
+  its backchannel as status records.
 
 Deployment:
 
@@ -110,8 +111,6 @@ class _StageHostBase:
         # never owned)
         self.partition = partition
         self._known: set[str] = set()
-        self._last_checkpoint = time.monotonic()
-        self.checkpoint_every_s = 1.0
         self.counters = tier_counters("stage")
 
     def _owns(self, topic: str) -> bool:
@@ -214,15 +213,13 @@ class _StageHostBase:
                 with span("stage.drain"):
                     self.counters.inc("stage.records.drained",
                                       self.shared.drain())
-            now = time.monotonic()
-            if now - self._last_checkpoint >= self.checkpoint_every_s:
-                self._last_checkpoint = now
+            if self._checkpoint_due():
                 self.checkpoint()
             with span("stage.state_flush"):
                 self.state.flush()
             if not moved:
                 time.sleep(POLL_INTERVAL_S)
-        self.checkpoint()
+        self.checkpoint(final=True)
         self.state.flush()
 
     def run_once(self) -> bool:
@@ -249,8 +246,13 @@ class _StageHostBase:
     def attach(self, topic: str) -> None:
         raise NotImplementedError
 
-    def checkpoint(self) -> None:
-        pass
+    def _checkpoint_due(self) -> bool:
+        """Whether this loop turn ends with a checkpoint: each stage
+        keeps its own cadence."""
+        raise NotImplementedError
+
+    def checkpoint(self, final: bool = False) -> None:
+        """Save what was consumed; ``final`` is the drain-stop's."""
 
 
 class ScribeStage(_StageHostBase):
@@ -269,11 +271,15 @@ class ScribeStage(_StageHostBase):
     # in the same poll window as its upload: round-5 flake fix)
     topic_prefixes = ("uploads/", "deltas/")
 
+    #: its checkpoint saves every scribe, so it runs on a timer
+    checkpoint_every_s = 1.0
+
     def __init__(self, log_dir: str, state_dir: str,
                  partition=None):
         super().__init__(log_dir, state_dir, partition=partition)
         self.db = InMemoryDb()
         self.scribes: dict[str, object] = {}  # "tenant/doc" → ScribeLambda
+        self._last_checkpoint = time.monotonic()
 
     def _scribe_for(self, tenant: str, doc: str):
         from .scribe import ScribeLambda
@@ -326,7 +332,14 @@ class ScribeStage(_StageHostBase):
 
             self.shared.subscribe(topic, on_upload, from_offset=0)
 
-    def checkpoint(self) -> None:
+    def _checkpoint_due(self) -> bool:
+        now = time.monotonic()
+        if now - self._last_checkpoint < self.checkpoint_every_s:
+            return False
+        self._last_checkpoint = now
+        return True
+
+    def checkpoint(self, final: bool = False) -> None:
         # crash window: records consumed, checkpoint not yet written —
         # a restart replays the window (scribe replay is seq-idempotent)
         self._fault("stage.pre_checkpoint")
@@ -355,7 +368,8 @@ class ApplierStage(_StageHostBase):
                  ds_id: str = "default", channel_id: str = "text",
                  partition=None):
         super().__init__(log_dir, state_dir, partition=partition)
-        from .tpu_applier import TpuDocumentApplier, load_applier_checkpoint
+        from .tpu_applier import (ApplierCheckpointer, TpuDocumentApplier,
+                                  load_applier_checkpoint)
 
         self.ds_id, self.channel_id = ds_id, channel_id
         ckpt = os.path.join(state_dir, "applier")
@@ -369,8 +383,14 @@ class ApplierStage(_StageHostBase):
         self.applier.set_replay_source(
             lambda t, d: log_channel_stream(self.shared, t, d,
                                             self.ds_id, self.channel_id))
-        self._ckpt_path = ckpt
+        # a base at start: the deltas that follow need one, and it drops
+        # whatever delta files a crash left
+        self._ckpt = ApplierCheckpointer(self.applier, ckpt)
+        self._count(self._ckpt.base())
         self._offsets: dict[str, int] = {}
+        # topics whose consumed offset moved since the last publish: the
+        # only ones it writes offsets and "applied" records for
+        self._moved: dict[str, None] = {}
         # highest sequence number CONSUMED per topic (the consumer-group
         # offset semantic): the stream tail includes messages the applier
         # skips (joins, summarize/ack, other channels), and "caught up"
@@ -388,9 +408,16 @@ class ApplierStage(_StageHostBase):
         tenant, doc = _doc_of(topic)
         cp = self.load_checkpoint(tenant, doc)
         start = cp["offset"] + 1 if cp else 0
+        if cp:
+            # a restart publishes each doc it has a checkpoint for once: a
+            # kill between a doc's offset checkpoint and its "applied"
+            # record must not leave the record missing until the doc moves
+            self._offsets[topic] = cp["offset"]
+            self._moved[topic] = None
 
         def on_deltas(message, tenant=tenant, doc=doc, topic=topic):
             self._offsets[topic] = message.offset
+            self._moved[topic] = None
             value = message.value
             abatch = value.get("abatch")
             if abatch is not None:
@@ -489,9 +516,14 @@ class ApplierStage(_StageHostBase):
             "peak_bytes_in_use": peak_bytes_in_use(dev)}),
             flush=True)
 
-    def checkpoint(self) -> None:
-        from .tpu_applier import save_applier_checkpoint
+    def _checkpoint_due(self) -> bool:
+        # every turn that consumed records (or, after a restart, has
+        # docs to publish), so "applied" follows the drain; never idle
+        return bool(self._moved)
 
+    def checkpoint(self, final: bool = False) -> None:
+        """Save the farm (a delta of the docs that moved; the drain-stop's
+        is a base), then publish offsets and "applied" records."""
         # crash window 1: deltas consumed into the farm, nothing saved —
         # a restart resumes from the OLD offsets and replays the window
         # (ingest skips by sequence number)
@@ -499,10 +531,8 @@ class ApplierStage(_StageHostBase):
         with span("stage.checkpoint"):
             with span("applier.flush"):
                 self.applier.flush()
-            self.applier.finalize()
-            written = save_applier_checkpoint(self.applier,
-                                              self._ckpt_path)
-            self.counters.inc("stage.checkpoint.bytes", written)
+            # each fences the farm first (applier.finalize)
+            self._count(self._ckpt.base() if final else self._ckpt.delta())
             # crash window 2: the farm is saved but the offset
             # checkpoints / "applied" emits are not — the restart
             # replays against a NEWER farm, the skip-by-seq path's
@@ -511,12 +541,19 @@ class ApplierStage(_StageHostBase):
             with span("stage.publish"):
                 self._publish()
 
+    def _count(self, written) -> None:
+        self.counters.inc("stage.checkpoint.bytes", written.nbytes)
+        self.counters.inc("stage.checkpoint.delta_docs", written.docs)
+        self.counters.inc("stage.checkpoint.bases", written.bases)
+
     def _publish(self) -> None:
-        """Offset checkpoints and one "applied" record per doc; with
-        tracing armed, each doc whose applied seq advanced samples its
-        deli-to-applied lag."""
+        """Offset checkpoints and one "applied" record per doc whose
+        offset moved since the last publish; with tracing armed, each doc
+        whose applied seq advanced samples its deli-to-applied lag."""
         now = time.time()
-        for topic, offset in self._offsets.items():
+        moved, self._moved = self._moved, {}
+        for topic in moved:
+            offset = self._offsets[topic]
             tenant, doc = _doc_of(topic)
             self.save_checkpoint(tenant, doc, {"offset": offset})
             seq = max(self._watermarks.get(topic, 0),
@@ -528,7 +565,7 @@ class ApplierStage(_StageHostBase):
                 stamp = self._stamps.get(topic)
                 if stamp:
                     sample("stage.deli_to_applied.ms", (now - stamp) * 1e3)
-        self.counters.inc("stage.publish.records", 2 * len(self._offsets))
+        self.counters.inc("stage.publish.records", 2 * len(moved))
 
 
 STAGES = {"scribe": ScribeStage, "applier": ApplierStage}

@@ -150,10 +150,14 @@ SPANS = {
     "applier.fence": "finalize: wait for the device, read overflow",
     "applier.stage_wave": "host half of a wave: pack, scatter, put",
     "applier.dispatch": "enqueue of one jitted step (asynchronous)",
-    "checkpoint.save": "save_applier_checkpoint, the whole call",
+    "checkpoint.save": "a base: save_applier_checkpoint, or its write "
+                       "on the applier-base thread",
     "checkpoint.device_get": "copy of the state arrays to the host",
     "checkpoint.write_arrays": "savez_compressed of the arrays + rename",
-    "checkpoint.write_meta": "JSON sidecar: build, dump, rename",
+    "checkpoint.write_meta": "JSON sidecar: dump, rename",
+    "checkpoint.delta": "a delta: the moved slots' rows and sidecar",
+    "checkpoint.delta.device_get": "copy of the state arrays to the host",
+    "checkpoint.delta.write": "frame and append the delta record",
     # durable log (service/durable_log.py)
     "log.flush": "DurableLog.flush",
     # socket doors (service/front_end.py)

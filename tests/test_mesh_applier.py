@@ -22,6 +22,7 @@ from fluidframework_tpu.parallel.mesh import make_mesh
 from fluidframework_tpu.parallel.sharded_apply import doc_sharding
 from fluidframework_tpu.service import LocalServer
 from fluidframework_tpu.service.tpu_applier import (
+    ApplierCheckpointer,
     TpuDocumentApplier,
     channel_stream,
     load_applier_checkpoint,
@@ -228,3 +229,37 @@ def test_mesh_checkpoint_restore_resharded(tmp_path, soup):
     with pytest.raises(ValueError):
         load_applier_checkpoint(str(tmp_path / "ck"),
                                 mesh=make_mesh(4, seg_shards=1))
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_mesh_loads_base_and_deltas_resharded(tmp_path, soup, seed):
+    """A mesh applier's base + deltas (each half of every doc's stream a
+    delta) replay on the host and re-shard after: the state comes back
+    committed per P('docs'), row for row what a full save reloads, and
+    every doc's text is the oracle's."""
+    server, texts = soup[seed]
+    mesh = make_mesh(2, seg_shards=1)
+    a = TpuDocumentApplier(max_docs=8, max_slots=128, ops_per_dispatch=8,
+                           mesh=mesh)
+    ck = ApplierCheckpointer(a, str(tmp_path / "ck"))
+    ck.limit = 1 << 60  # every delta stays on disk
+    ck.base()
+    msgs = {d: list(channel_stream(server, "t", d, "default", "text"))
+            for d in DOCS}
+    for half in (0, 1):
+        for d in DOCS:
+            n = len(msgs[d])
+            for m in msgs[d][half * n // 2:(half + 1) * n // 2]:
+                a.ingest("t", d, m, m.contents)
+        assert ck.delta().docs == len(DOCS)
+    save_applier_checkpoint(a, str(tmp_path / "full"))
+
+    b = load_applier_checkpoint(str(tmp_path / "ck"), mesh=mesh)
+    full = load_applier_checkpoint(str(tmp_path / "full"), mesh=mesh)
+    assert b._marks.seq == 2
+    assert b.state.length.sharding == doc_sharding(mesh)
+    for f in ("length", "text_start", "rem_seq", "prop_key", "count"):
+        np.testing.assert_array_equal(np.asarray(getattr(b.state, f)),
+                                      np.asarray(getattr(full.state, f)))
+    for d in DOCS:
+        assert b.get_text("t", d) == texts[d], d

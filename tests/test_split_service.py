@@ -50,7 +50,7 @@ def _spawn(args, ready_line):
         mode="w+", prefix=f"{args[0].rsplit('.', 1)[-1]}-", suffix=".err",
         delete=False)
     proc = subprocess.Popen(
-        [sys.executable, "-m"] + args,
+        [sys.executable] + (args if args[0] == "-c" else ["-m"] + args),
         stdout=subprocess.PIPE, stderr=errf, text=True,
         cwd=REPO)
     proc._stderr_path = errf.name
@@ -80,16 +80,38 @@ def _cleanup(procs) -> None:
             os.unlink(path)
 
 
-def _spawn_stage(stage, log_dir, state_dir):
-    proc, _ = _spawn(
-        ["fluidframework_tpu.service.stage_runner", "--stage", stage,
-         "--log-dir", str(log_dir), "--state-dir", str(state_dir)],
-        "READY")
+# a stage process that kill -9s itself the k-th time its checkpoint
+# reaches a crash window: argv is the window, k, then stage_runner's own
+_KILL_AT_WINDOW = """
+import os, signal, sys
+from fluidframework_tpu.service import stage_runner
+window, k = sys.argv[1], int(sys.argv[2])
+hits = [0]
+def plane(point, **_ctx):
+    if point == window:
+        hits[0] += 1
+        if hits[0] == k:
+            os.kill(os.getpid(), signal.SIGKILL)
+stage_runner.ApplierStage.fault_plane = staticmethod(plane)
+sys.argv = sys.argv[:1] + sys.argv[3:]
+stage_runner.main()
+"""
+
+
+def _spawn_stage(stage, log_dir, state_dir, kill_at=None):
+    args = ["--stage", stage, "--log-dir", str(log_dir), "--state-dir",
+            str(state_dir)]
+    if kill_at is None:
+        args = ["fluidframework_tpu.service.stage_runner"] + args
+    else:
+        args = ["-c", _KILL_AT_WINDOW, kill_at[0], str(kill_at[1])] + args
+    proc, _ = _spawn(args, "READY")
     return proc
 
 
 @contextlib.contextmanager
-def split_deployment(tmp_path, stages=("scribe", "applier")):
+def split_deployment(tmp_path, stages=("scribe", "applier"),
+                     kill_applier_at=None):
     log_dir = tmp_path / "log"
     storage_dir = tmp_path / "blobs"
     state_dirs = {s: tmp_path / f"{s}-state" for s in stages}
@@ -98,7 +120,9 @@ def split_deployment(tmp_path, stages=("scribe", "applier")):
     # still dump stderr and not leak the already-started processes
     try:
         for s in stages:
-            procs[s] = _spawn_stage(s, log_dir, state_dirs[s])
+            procs[s] = _spawn_stage(
+                s, log_dir, state_dirs[s],
+                kill_applier_at if s == "applier" else None)
         core_args = ["fluidframework_tpu.service.front_end", "--port", "0",
                      "--log-dir", str(log_dir),
                      "--storage-dir", str(storage_dir)]
@@ -191,35 +215,60 @@ def test_scribe_stage_killed_and_restarted_mid_stream(tmp_path):
         assert wait_for(lambda: sm.summaries_acked >= 2)
 
 
-def test_applier_stage_catches_up_and_survives_kill(tmp_path):
+@pytest.mark.parametrize("window", [None, "stage.pre_checkpoint",
+                                    "stage.post_checkpoint"])
+def test_applier_stage_catches_up_and_survives_kill(tmp_path, window):
     """The TPU applier as its own process: consumes the deltas log,
     reports applied seqs on its backchannel, and after kill -9 +
     restart resumes from its device-farm checkpoint (warm restart, no
-    full replay) to catch back up to the stream tail."""
-    with split_deployment(tmp_path, stages=("scribe", "applier")) as (
+    full replay) to catch back up to the stream tail. The kill lands
+    anywhere, or at the third delta checkpoint: before its delta (the
+    restart replays from older offsets) or after it and before the
+    offsets (it replays against newer state). The drain-stop then leaves
+    a base and no delta, holding the doc's text."""
+    from fluidframework_tpu.service.tpu_applier import (
+        _delta_files, load_applier_checkpoint)
+
+    kill_at = (window, 3) if window else None
+    with split_deployment(tmp_path, stages=("scribe", "applier"),
+                          kill_applier_at=kill_at) as (
             port, procs, state_dirs, log_dir):
         loader = Loader(NetworkDocumentServiceFactory("127.0.0.1", port))
         c1 = loader.resolve("t", "doc")
         s = c1.runtime.create_data_store("default").create_channel(
             "text", "shared-string")
-        for i in range(20):
-            s.insert_text(0, "ab")
-        tail = c1.delta_manager.last_processed_seq
 
         def caught_up(target):
             return _applied_seq(state_dirs["applier"], "t", "doc") >= target
-        assert wait_for(lambda: caught_up(tail), timeout=120)
+        # in batches, each sequenced and then waited for: every batch is
+        # a turn that drains records and ends with a delta checkpoint
+        for batch in range(5):
+            for i in range(4):
+                s.insert_text(0, "ab")
+            assert wait_for(lambda: c1.runtime.pending.count == 0,
+                            timeout=60)
+            tail = c1.delta_manager.last_processed_seq
+            assert wait_for(lambda: caught_up(tail)
+                            or procs["applier"].poll() is not None,
+                            timeout=120)
 
-        os.kill(procs["applier"].pid, signal.SIGKILL)
-        procs["applier"].wait(timeout=10)
+        if window is None:
+            os.kill(procs["applier"].pid, signal.SIGKILL)
+        assert procs["applier"].wait(timeout=30) == -signal.SIGKILL
         for i in range(10):
             s.insert_text(0, "cd")
+        assert wait_for(lambda: c1.runtime.pending.count == 0, timeout=60)
         tail2 = c1.delta_manager.last_processed_seq
         assert tail2 > tail
 
         procs["applier"] = _spawn_stage("applier", log_dir,
                                         state_dirs["applier"])
         assert wait_for(lambda: caught_up(tail2), timeout=120)
+        procs["applier"].send_signal(signal.SIGINT)
+        assert procs["applier"].wait(timeout=60) == 0
+    path = str(state_dirs["applier"] / "applier")
+    assert _delta_files(path) == []
+    assert load_applier_checkpoint(path).get_text("t", "doc") == s.get_text()
 
 
 def test_doc_partitioned_appliers_and_rebalance(tmp_path):
@@ -552,23 +601,24 @@ def test_armed_tracing_writes_both_span_files(tmp_path):
     assert {s[0] for s in stage["spans"]} >= {
         "stage.poll", "stage.drain", "stage.state_flush", "stage.checkpoint",
         "applier.flush", "applier.fence", "applier.stage_wave",
-        "applier.dispatch", "checkpoint.save", "checkpoint.device_get",
-        "checkpoint.write_arrays", "checkpoint.write_meta", "stage.publish",
-        "log.flush"}
+        "applier.dispatch", "checkpoint.delta",
+        "checkpoint.delta.device_get", "checkpoint.delta.write",
+        "stage.publish", "log.flush"}
     assert {s[0] for s in core["spans"]} >= {
         "frontend.ingress_batch", "frontend.backchannel", "log.flush",
         "frontend.presence_flush"}
-    # the checkpoint's children: the fence, the save and the publish
+    # the checkpoint's children: the fence, the delta and the publish
     by_idx = {i: s for i, s in enumerate(stage["spans"])}
     kids = {by_idx[s[3]][0]: [] for s in stage["spans"] if s[3] >= 0}
     for s in stage["spans"]:
         if s[3] >= 0:
             kids[by_idx[s[3]][0]].append(s[0])
-    assert {"applier.fence", "checkpoint.save", "stage.publish"} <= set(
+    assert {"applier.fence", "checkpoint.delta", "stage.publish"} <= set(
         kids["stage.checkpoint"])
     assert stage["samples"]["stage.deli_to_applied.ms"]
     assert len(core["samples"]["frontend.loop.lag_ms"]) >= 50
     assert stage["counters"]["stage.checkpoint.bytes"] > 0
+    assert stage["counters"]["stage.checkpoint.delta_docs"] >= 4
     assert stage["counters"]["stage.records.drained"] > 0
     assert window_start_ns(str(trace_dir)) is not None
     assert stage["overflow"] == core["overflow"] == 0
@@ -628,6 +678,79 @@ def test_escalated_doc_in_stage_replays_from_the_log(tmp_path):
         assert stage.applier.get_text("bench", doc) == oracle.get_text()
 
 
+def test_applier_stage_publishes_in_the_turn_that_drained(tmp_path):
+    """A turn that drains a doc's records ends with a delta of the docs
+    that moved, then an offset checkpoint and an "applied" record for
+    each, at its last consumed seq; a doc with nothing new gets neither,
+    and a turn that drained nothing writes nothing. A restart publishes
+    every doc once."""
+    from fluidframework_tpu.service.durable_log import (DurableLog,
+                                                        record_messages)
+    from fluidframework_tpu.service.load_gen import run_inproc
+    from fluidframework_tpu.service.stage_runner import (BACKCHANNEL_TOPIC,
+                                                         ApplierStage)
+
+    src = DurableLog(str(tmp_path / "src"))
+    run_inproc(n_docs=3, clients_per_doc=2, ops_per_client=8,
+               batch_size=4, seed=4, log=src)
+    src.flush()
+    log = DurableLog(str(tmp_path / "log"))
+    topics = src.list_topics("deltas/")
+    held = topics[-1]
+
+    def copy(topic, lo, hi):
+        for i in range(lo, hi):
+            log.append(topic, src.read(topic, i))
+        log.flush()
+        return record_messages(src.read(topic, hi - 1))[-1].sequence_number
+
+    tails = {t: copy(t, 0, src.refresh_topic(t) - (2 if t == held else 0))
+             for t in topics}
+    stage = ApplierStage(str(tmp_path / "log"), str(tmp_path / "state"))
+    seen = [0]
+
+    def published():
+        n = stage.state.refresh_topic(BACKCHANNEL_TOPIC)
+        out = [stage.state.read(BACKCHANNEL_TOPIC, i)
+               for i in range(seen[0], n)]
+        seen[0] = n
+        return {f"deltas/{r['tenant']}/{r['doc']}": r["applied_seq"]
+                for r in out if r["kind"] == "applied"}
+
+    def snap():
+        return stage.counters.snapshot().get(
+            "stage.checkpoint.delta_docs", 0)
+
+    assert stage.run_once()
+    assert published() == tails
+    assert snap() == 3
+    assert not stage.run_once()  # nothing new: no delta, no record
+    assert published() == {} and snap() == 3
+    n = src.refresh_topic(held)
+    tail = copy(held, n - 2, n)
+    cps = {t: stage.state.refresh_topic("cp/" + t.split("/", 1)[1])
+           for t in topics}
+    assert stage.run_once()
+    assert published() == {held: tail}
+    assert snap() == 4
+    for t in topics:
+        grew = stage.state.refresh_topic("cp/" + t.split("/", 1)[1]) \
+            - cps[t]
+        assert grew == (1 if t == held else 0), t
+    # a restart publishes every doc it has a checkpoint for, once, with
+    # nothing new to drain (a kill may have fallen between a doc's
+    # offset checkpoint and its "applied" record)
+    stage.checkpoint(final=True)
+    stage.state.flush()
+    stage = ApplierStage(str(tmp_path / "log"), str(tmp_path / "state"))
+    seen[0] = stage.state.refresh_topic(BACKCHANNEL_TOPIC)
+    stage.discover()
+    assert stage._checkpoint_due()
+    assert not stage.run_once()
+    assert set(published()) == set(topics)
+    assert not stage._checkpoint_due()
+
+
 def test_stage_stops_tracing_between_turns_not_in_the_handler(tmp_path):
     """SIGUSR2 landing in the middle of a checkpoint only asks for the
     stop: tracing stays armed until the checkpoint is over, and the
@@ -645,7 +768,6 @@ def test_stage_stops_tracing_between_turns_not_in_the_handler(tmp_path):
     log.flush()
     stage = ApplierStage(str(tmp_path / "log"), str(tmp_path / "state"))
     stage.trace_dir = str(tmp_path / "trace")
-    stage.checkpoint_every_s = 0.0
     seen: dict = {}
     publish, serve = stage._publish, stage._serve_trace_request
 
@@ -675,9 +797,10 @@ def test_stage_stops_tracing_between_turns_not_in_the_handler(tmp_path):
     finally:
         signal.signal(signal.SIGUSR2, before)
         telemetry.tracing_stop()
-    # the signal's turn; the next, which stops tracing first; the
-    # drain-stop's checkpoint
-    assert seen == {"armed": True, "publishes": 3}
+    # the signal's turn, which drained the log; the drain-stop's
+    # checkpoint, after the next turn stopped tracing first (that turn
+    # drained nothing, so it checkpoints nothing)
+    assert seen == {"armed": True, "publishes": 2}
     (name,) = os.listdir(tmp_path / "trace")
     with open(tmp_path / "trace" / name) as f:
         doc = json.load(f)
