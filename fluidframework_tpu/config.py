@@ -33,6 +33,12 @@ class Config:
     applier_ops_per_dispatch: int = 32   # wave depth [K]
     applier_min_wave_ops: int = 0        # async worker dispatch threshold
     applier_overflow_check_every: int = 64  # dispatches between fences
+    # the long tier: [L, S_long] device rows beside the [D, S] farm for
+    # documents that outgrow S (promoted on the device, not replayed on
+    # the host). L is a multiple of 8 (the Pallas tile), S_long a power
+    # of two; L = 0 leaves the tier out.
+    applier_long_docs: int = 0           # long rows [L]
+    applier_long_slots: int = 4096       # segment slots per long row
     # use the Pallas VMEM-resident apply (ops/pallas_apply.py) in the
     # applier's dense step (requires max_docs % 8 == 0; which lane is
     # faster on the chip is not measured yet). Deprecated in favor of

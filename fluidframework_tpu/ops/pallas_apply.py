@@ -330,6 +330,47 @@ def _kernel(ops_ref, length, tstart, flags, iseq, icl, rseq, rca, rcb,
     o_ovf[...] = out[11]
 
 
+def _kernel_long(ops_ref, length, tstart, flags, iseq, icl, rseq, rca, rcb,
+                 pk, pv, count, ovf,
+                 o_length, o_tstart, o_flags, o_iseq, o_icl, o_rseq, o_rca,
+                 o_rcb, o_pk, o_pv, o_count, o_ovf, *, S, K):
+    """The long tier's layout: prop planes slot-last ([P, R, S] blocks,
+    each plane a whole [R, S] tile with the slots in the lane dimension;
+    the farm's [R, S, P] puts P = 8 in the lanes, which Mosaic pads to
+    128), and the state carried through the ``fori_loop`` in the OUTPUT
+    refs, read and written back once an op: at S = 4096 one plane is 32
+    vector registers, so a value carry of all 24 planes could never stay
+    in registers."""
+    P = pk.shape[0]
+    planes = (o_length, o_tstart, o_flags, o_iseq, o_icl, o_rseq, o_rca,
+              o_rcb)
+    for src, dst in zip((length, tstart, flags, iseq, icl, rseq, rca, rcb),
+                        planes):
+        dst[...] = src[...]
+    o_pk[...] = pk[...]
+    o_pv[...] = pv[...]
+    o_count[...] = count[...]
+    o_ovf[...] = ovf[...]
+
+    def body(k, _):
+        op_row = ops_ref[:, pl.ds(k, 1), :][:, 0, :]  # [R, F]
+        carry = (*(r[:, :] for r in planes),
+                 tuple(o_pk[p] for p in range(P)),
+                 tuple(o_pv[p] for p in range(P)),
+                 o_count[:, :], o_ovf[:, :])
+        out = _apply_one(carry, op_row, S)
+        for r, arr in zip(planes, out[:8]):
+            r[...] = arr
+        for p in range(P):
+            o_pk[p] = out[8][p]
+            o_pv[p] = out[9][p]
+        o_count[...] = out[10]
+        o_ovf[...] = out[11]
+        return 0
+
+    lax.fori_loop(0, K, body, 0)
+
+
 def _contract_example():
     """One R-tile wave in interpret mode (the checker runs on CPU)."""
     D, S, K = R, 16, 4
@@ -389,6 +430,79 @@ def pallas_apply_ops_batch(state: DocState, ops: jax.Array,
     return DocState(
         **dict(zip(_FIELDS_1D, outs[:8])),
         prop_key=outs[8], prop_val=outs[9],
+        count=outs[10].reshape(D),
+        overflow=outs[11].reshape(D).astype(bool),
+    )
+
+
+#: the long tier's kernel, by this name in the device trace
+LONG_KERNEL_NAME = "long_apply"
+#: the long kernel's scoped VMEM limit
+LONG_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _contract_example_long():
+    D, S, K = R, 256, 4
+    state = jax.vmap(lambda _: DocState.empty(S))(jnp.arange(D))
+    ops = jnp.zeros((D, K, OP_FIELDS), jnp.int32)
+    return (state, ops), {"interpret": True}
+
+
+@kernel_contract(
+    "ops.pallas_apply_ops_batch_long",
+    example=_contract_example_long,
+    no_gather=True,
+    no_scatter=True,
+    no_int16_arithmetic=True,
+    single_jit=True,
+    notes="Pallas apply at the long tier's layout (prop planes slot-last, "
+          "state carried in VMEM refs)",
+)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pallas_apply_ops_batch_long(state: DocState, ops: jax.Array,
+                                interpret: bool = False) -> DocState:
+    """``pallas_apply_ops_batch`` for the long tier's [L, S_long] rows:
+    the prop tables go to the kernel as [P, L, S] planes (a transpose
+    either side of the call, in XLA) so that a block stays within VMEM
+    at S = 8192, and the kernel is named ``LONG_KERNEL_NAME``."""
+    D = state.length.shape[0]
+    S = state.length.shape[1]
+    P = state.prop_key.shape[-1]
+    K = ops.shape[1]
+    assert D % R == 0, f"doc count {D} must be a multiple of {R}"
+    count2 = state.count.astype(jnp.int32).reshape(D, 1)
+    ovf2 = state.overflow.astype(jnp.int32).reshape(D, 1)
+
+    row = pl.BlockSpec((R, S), lambda i: (i, 0))
+    planes = pl.BlockSpec((P, R, S), lambda i: (0, i, 0))
+    row1 = pl.BlockSpec((R, 1), lambda i: (i, 0))
+    opspec = pl.BlockSpec((R, K, OP_FIELDS), lambda i: (i, 0, 0))
+    shapes = (
+        [jax.ShapeDtypeStruct((D, S), jnp.int32)] * 8
+        + [jax.ShapeDtypeStruct((P, D, S), jnp.int32)] * 2
+        + [jax.ShapeDtypeStruct((D, 1), jnp.int32)] * 2
+    )
+    outs = pl.pallas_call(
+        functools.partial(_kernel_long, S=S, K=K),
+        grid=(D // R,),
+        in_specs=[opspec] + [row] * 8 + [planes] * 2 + [row1, row1],
+        out_specs=[row] * 8 + [planes] * 2 + [row1, row1],
+        out_shape=shapes,
+        interpret=interpret,
+        name=LONG_KERNEL_NAME,
+        # double-buffered in and out blocks of 24 [8, S] planes plus the
+        # body's temporaries pass the 16 MB default scope (17.1 MB at
+        # S = 4096); 64 MB holds S = 8192, of the v5e core's 128 MiB
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=LONG_VMEM_LIMIT),
+    )(ops, *(getattr(state, f) for f in _FIELDS_1D),
+      jnp.transpose(state.prop_key, (2, 0, 1)),
+      jnp.transpose(state.prop_val, (2, 0, 1)), count2, ovf2)
+
+    return DocState(
+        **dict(zip(_FIELDS_1D, outs[:8])),
+        prop_key=jnp.transpose(outs[8], (1, 2, 0)),
+        prop_val=jnp.transpose(outs[9], (1, 2, 0)),
         count=outs[10].reshape(D),
         overflow=outs[11].reshape(D).astype(bool),
     )

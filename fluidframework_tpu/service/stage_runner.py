@@ -386,6 +386,7 @@ class ApplierStage(_StageHostBase):
         # a base at start: the deltas that follow need one, and it drops
         # whatever delta files a crash left
         self._ckpt = ApplierCheckpointer(self.applier, ckpt)
+        self._tallied: dict[str, int] = {}
         self._count(self._ckpt.base())
         self._offsets: dict[str, int] = {}
         # topics whose consumed offset moved since the last publish: the
@@ -490,6 +491,8 @@ class ApplierStage(_StageHostBase):
         clock = CompileClock()
         stage = cls(log_dir, state_dir, partition=partition)
         a = stage.applier
+        # the long tier's step and promotion compile here, before READY
+        a.warm_long_tier()
         signal.signal(signal.SIGINT,
                       lambda *_: setattr(stage, "stopping", True))
         if trace_dir:
@@ -502,7 +505,9 @@ class ApplierStage(_StageHostBase):
         print("STAGE " + json.dumps({
             "platform": dev.platform, "device_kind": dev.device_kind,
             "kernel_lane": a.kernel_lane, "max_docs": a.max_docs,
-            "max_slots": a.max_slots, "ops_per_dispatch": a.K}), flush=True)
+            "max_slots": a.max_slots, "ops_per_dispatch": a.K,
+            "long_docs": a.long_docs, "long_slots": a.long_slots,
+            "long_kernel_lane": a.long_kernel_lane}), flush=True)
         try:
             stage.run_forever()
         except PlacementFull as e:
@@ -512,6 +517,8 @@ class ApplierStage(_StageHostBase):
             "docs": len(a.placement.snapshot()["map"]),
             "ops_applied": a.ops_applied, "dispatches": a.dispatches,
             "host_escalations": a.host_escalations,
+            "promotions": a.promotions, "long_dispatches": a.long_dispatches,
+            "long_slots_peak": a.long_slots_peak,
             "compile_s": clock.seconds, "compiles": clock.compiles,
             "peak_bytes_in_use": peak_bytes_in_use(dev)}),
             flush=True)
@@ -545,6 +552,17 @@ class ApplierStage(_StageHostBase):
         self.counters.inc("stage.checkpoint.bytes", written.nbytes)
         self.counters.inc("stage.checkpoint.delta_docs", written.docs)
         self.counters.inc("stage.checkpoint.bases", written.bases)
+        a = self.applier
+        if a.long_state is None:
+            return
+        self.counters.inc("stage.checkpoint.long_bytes", written.long_bytes)
+        # the applier's own tallies, as deltas since the last checkpoint
+        # (the peak rises by what it rose: the counter reads the peak)
+        for name, now in (("applier.promotions", a.promotions),
+                          ("applier.long.dispatches", a.long_dispatches),
+                          ("applier.long.slots_peak", a.long_slots_peak)):
+            self.counters.inc(name, now - self._tallied.get(name, 0))
+            self._tallied[name] = now
 
     def _publish(self) -> None:
         """Offset checkpoints and one "applied" record per doc whose

@@ -55,6 +55,7 @@ from ..ops.apply import (
     F_TSTART,
     F_TYPE,
     F_VAL,
+    NO_CLIENT,
     NO_VAL,
     OP_ANNOTATE,
     OP_FIELDS,
@@ -67,7 +68,8 @@ from ..ops.apply import (
     unpack_wave16,
     wave_min_seq,
 )
-from ..ops.doc_state import FLAG_MARKER, DocState, PropTable, TextArena, decode_state
+from ..ops.doc_state import (FLAG_MARKER, NO_KEY, NO_SEQ, DocState, PropTable,
+                             TextArena, decode_state)
 from ..protocol.messages import MessageType, SequencedDocumentMessage
 from ..protocol.refgraph import frame_record, scan_records
 from ..parallel.placement import DocPlacement
@@ -76,6 +78,7 @@ from ..utils.affinity import blocking
 from ..utils.telemetry import span
 
 MARKER_GLYPH = "￼"  # arena placeholder byte for markers (flags classify)
+_INT16_MAX = 32767
 
 # SYSTEM_CLIENT / PACK_SYSTEM and the int16-delta wire format now live in
 # ops/apply.py (shared with the mesh lane's packed sharded step)
@@ -214,6 +217,72 @@ def _dense_step_for(D: int, K: int, use_pallas: bool = False,
     return fn
 
 
+def _long_step_for(L: int, K: int, use_pallas: bool = False,
+                   pallas_interpret: bool = False):
+    """The long tier's step: an int32 [L, K, F] wave (positions in a
+    book-length doc pass int16 at character 32,768, and the tier's wave
+    is small enough that packing would buy nothing), the apply at the
+    tier's kernel layout, and the fused zamboni."""
+    key = ("long", L, K, use_pallas, pallas_interpret)
+    fn = _DENSE_STEP_CACHE.get(key)
+    if fn is None:
+        if use_pallas:
+            from ..ops.pallas_apply import pallas_apply_ops_batch_long
+
+            def apply_fn(state, wave):
+                return pallas_apply_ops_batch_long(
+                    state, wave, interpret=pallas_interpret)
+        else:
+            apply_fn = apply_ops_batch
+
+        def long_step(state, wave):
+            _count_trace("long_step", f"{L}x{K}")
+            state = apply_fn(state, wave)
+            return compact_batch(state, wave_min_seq(wave))
+
+        from ..parallel.sharded_apply import donation_supported
+
+        fn = jax.jit(long_step,
+                     donate_argnums=(0,) if donation_supported() else ())
+        _DENSE_STEP_CACHE[key] = fn
+    return fn
+
+
+def _promote_rows(farm: DocState, long: DocState, slot, row):
+    """Move farm row ``slot`` into long row ``row``, padded with empty
+    slots to the tier's width, and leave the farm row empty. Both
+    indices are traced: one compile per pair of geometries."""
+    S, SL = farm.max_slots, long.max_slots
+    empty, empty_long = DocState.empty(S), DocState.empty(SL)
+    moved = {}
+    cleared = {}
+    for f in _STATE_FIELDS[:-2]:  # all but count and overflow
+        src = getattr(farm, f)[slot]
+        pad = getattr(empty_long, f)[S:]
+        moved[f] = getattr(long, f).at[row].set(
+            jnp.concatenate([src, pad], axis=0))
+        cleared[f] = getattr(farm, f).at[slot].set(getattr(empty, f))
+    return (DocState(**cleared, count=farm.count.at[slot].set(0),
+                     overflow=farm.overflow.at[slot].set(False)),
+            DocState(**moved, count=long.count.at[row].set(farm.count[slot]),
+                     overflow=long.overflow.at[row].set(
+                         farm.overflow[slot])))
+
+
+_PROMOTE_FN = None
+
+
+def _promote_fn():
+    global _PROMOTE_FN
+    if _PROMOTE_FN is None:
+        from ..parallel.sharded_apply import donation_supported
+
+        _PROMOTE_FN = jax.jit(
+            _promote_rows,
+            donate_argnums=(0, 1) if donation_supported() else ())
+    return _PROMOTE_FN
+
+
 def _contract_build():
     """The int16 packed wave applier at a small fixed geometry."""
     D, K = 8, 4
@@ -330,6 +399,8 @@ class TpuDocumentApplier:
         pallas_interpret: bool = False,
         kernel: Optional[str] = None,
         overlap: Optional[bool] = None,
+        long_docs: Optional[int] = None,
+        long_slots: Optional[int] = None,
     ):
         from ..config import DEFAULT as _CFG
 
@@ -337,6 +408,10 @@ class TpuDocumentApplier:
         max_docs = max_docs if max_docs is not None else _CFG.applier_max_docs
         max_slots = (max_slots if max_slots is not None
                      else _CFG.applier_max_slots)
+        long_docs = (long_docs if long_docs is not None
+                     else _CFG.applier_long_docs)
+        long_slots = (long_slots if long_slots is not None
+                      else _CFG.applier_long_slots)
         ops_per_dispatch = (ops_per_dispatch if ops_per_dispatch is not None
                             else _CFG.applier_ops_per_dispatch)
         overflow_check_every = (
@@ -481,6 +556,8 @@ class TpuDocumentApplier:
             self._dense_step = _dense_step_for(
                 max_docs, self.K, use_pallas=use_pallas,
                 pallas_interpret=pallas_interpret)
+        self._init_long_tier(long_docs, long_slots, use_pallas,
+                             pallas_interpret)
         self.dispatches = 0
         self.ops_applied = 0
         self.host_escalations = 0
@@ -532,6 +609,157 @@ class TpuDocumentApplier:
         state = apply_ops_batch(state, ops)
         state = compact_batch(state, wave_min_seq(ops))
         return state, {}
+
+    # ---------------------------------------------------------- long tier
+    #
+    # A document that outgrows the farm's S slots moves, on the device, to
+    # one of L rows of S_long slots beside the farm (``_promote``); it
+    # keeps its farm slot as its key (arena, client table, applied seq,
+    # placement) and ``_long_rows`` maps that slot to its long row. The
+    # tier has its own int32 wave and step in the same turn, so a book's
+    # positions never widen the farm's int16 wave. Host escalation stays
+    # for a long row past S_long and for the causes other than slots.
+
+    def _init_long_tier(self, L: int, SL: int, use_pallas: bool,
+                        pallas_interpret: bool) -> None:
+        self.long_docs, self.long_slots = L, SL
+        self.long_state: Optional[DocState] = None
+        self.long_kernel_lane: Optional[str] = None
+        self._long_rows: dict[int, int] = {}  # farm slot -> long row
+        self._long_free: list[int] = []
+        self.promotions = 0
+        self.long_dispatches = 0
+        self.long_slots_peak = 0
+        self._long_marker = None
+        self._long_since_check = 0
+        if not L:
+            return
+        if self._mesh is not None:
+            raise ValueError("the long tier runs beside a local farm, "
+                             "not a doc mesh")
+        if L % 8 or SL & (SL - 1) or SL <= self.max_slots \
+                or 2 * self.K >= self.max_slots:
+            raise ValueError(
+                f"long tier {L} x {SL}: L must be a multiple of 8, S_long "
+                f"a power of two above max_slots={self.max_slots}, and "
+                f"2 x ops_per_dispatch={self.K} below max_slots (a doc "
+                "moves at a count above max_slots - 2K)")
+        self.long_state = jax.vmap(lambda _: DocState.empty(SL))(
+            jnp.arange(L))
+        self._long_free = list(range(L - 1, -1, -1))
+        # host upper bound on each farm row's count: set at every fence,
+        # and raised by 2 per op dispatched since (no op adds more slots)
+        self._count_hi = np.zeros(self.max_docs, np.int64)
+        self.long_kernel_lane = "pallas" if use_pallas else "xla"
+        self._long_step = _long_step_for(L, self.K, use_pallas,
+                                         pallas_interpret)
+
+    def warm_long_tier(self) -> None:
+        """Compile the tier's step, the promotion and the farm's packed
+        step on throwaway state of the same shapes, so that nothing
+        compiles when a doc first grows past the farm."""
+        if self.long_state is None:
+            return
+        L, D, K = self.long_docs, self.max_docs, self.K
+        farm = jax.vmap(lambda _: DocState.empty(self.max_slots))(
+            jnp.arange(D))
+        long = jax.vmap(lambda _: DocState.empty(self.long_slots))(
+            jnp.arange(L))
+        farm, long = _promote_fn()(farm, long, 0, 0)
+        long = self._long_step(
+            long, jax.device_put(np.zeros((L, K, OP_FIELDS), np.int32)))
+        farm, _ = self._dense_step[0](
+            farm, jax.device_put(np.zeros((D, K, OP_FIELDS), np.int16)),
+            jax.device_put(np.zeros((D, 2), np.int32)))
+        jax.block_until_ready((farm, long))
+
+    def _route_parts(self, parts):
+        """Split one wave's parts into (farm, long). A farm doc moves to
+        the long tier first when this wave could pass its S slots (by the
+        host's bound; a fence confirms the count, and it moves above
+        S - 2K), or when one of its ops names a position past int16,
+        which would widen the farm's whole wave."""
+        rows, S = self._long_rows, self.max_slots
+        due, check = [], []
+        for slot, chunks, count in parts:
+            if not count or slot in rows:
+                continue
+            if any(int(c[:, F_POS].max()) > _INT16_MAX
+                   or int(c[:, F_END].max()) > _INT16_MAX for c in chunks):
+                due.append(slot)
+            elif self._count_hi[slot] + 2 * count > S:
+                check.append(slot)
+        if check:
+            counts = np.asarray(self.state.count)  # a fence of the farm
+            self._count_hi[:] = counts
+            due += [s for s in check if counts[s] > S - 2 * self.K]
+        for slot in due[:len(self._long_free)]:
+            self._promote(slot)
+        farm, long = [], []
+        for p in parts:
+            (long if p[0] in rows else farm).append(p)
+        return farm, long
+
+    def _promote(self, slot: int) -> None:
+        """Move farm doc ``slot`` to a free long row, on the device."""
+        with span("applier.promote"):
+            # the move donates both states: fence the waves in flight, so
+            # that no staging set's marker names a buffer it frees (a
+            # rotation would block on a donated buffer and fail)
+            self._drain_device()
+            self._stage_inflight = [None, None]
+            row = self._long_free.pop()
+            self.state, self.long_state = _promote_fn()(
+                self.state, self.long_state, slot, row)
+            self._exec_marker = self.state.count
+            self._long_marker = self.long_state.count
+            self._long_rows[slot] = row
+            self._count_hi[slot] = 0
+            self._marks.dirty.add(slot)
+            self._marks.promoted.add(slot)
+        self.promotions += 1
+
+    def _dispatch_long(self, parts) -> int:
+        """Stage and execute one long-tier wave: the tier's docs' op rows
+        at int32 in a fresh [L, K, F] buffer, then its step."""
+        with span("applier.long_stage_wave"):
+            parts = [p for p in parts if p[2]]
+            if not parts:
+                return 0
+            chunks = [c for _, cs, _ in parts for c in cs]
+            flat = chunks[0] if len(chunks) == 1 else np.concatenate(chunks)
+            rows_a = np.array([self._long_rows[p[0]] for p in parts],
+                              np.int64)
+            lens_a = np.array([p[2] for p in parts])
+            starts = np.cumsum(lens_a) - lens_a
+            wave = np.zeros((self.long_docs, self.K, OP_FIELDS), np.int32)
+            wave[np.repeat(rows_a, lens_a),
+                 np.arange(len(flat)) - np.repeat(starts, lens_a)] = flat
+            wave = jax.device_put(wave)
+        with span("applier.long_dispatch"):
+            self.long_state = self._long_step(self.long_state, wave)
+            self._long_marker = self.long_state.count
+            if not self._overlap:
+                jax.block_until_ready(self._long_marker)
+        self.long_dispatches += 1
+        self._long_since_check += 1
+        self._dispatches_since_check += 1
+        return len(flat)
+
+    def _check_long(self) -> None:
+        """The long tier's half of the fence: counts (and the peak) and
+        overflow; a long row past S_long escalates to the host."""
+        self._long_since_check = 0
+        flags, counts = jax.device_get((self.long_state.overflow,
+                                        self.long_state.count))
+        used = {row: slot for slot, row in self._long_rows.items()}
+        if used:
+            self.long_slots_peak = max(self.long_slots_peak,
+                                       int(counts[list(used)].max()))
+        for row in np.nonzero(flags)[0]:
+            slot = used.get(int(row))
+            if slot is not None and slot not in self._host_docs:
+                self._escalate(slot, None, None)
 
     # ------------------------------------------------------------- ingest
 
@@ -859,13 +1087,21 @@ class TpuDocumentApplier:
         The pipelining callers (_flush_sync, _worker_loop) go through the
         same pair; overlap comes from the execute half being an async
         dispatch, so the NEXT iteration's stage half runs on the host
-        while this wave executes on device."""
-        with span("applier.stage_wave"):
-            staged = self._stage_wave(parts)
-        if staged is None:
-            return 0
-        with span("applier.dispatch"):
-            return self._execute_wave(staged)
+        while this wave executes on device. With the long tier, the
+        wave's long docs go to the tier's own wave after the farm's."""
+        long_parts = None
+        if self.long_state is not None and parts:
+            parts, long_parts = self._route_parts(parts)
+        n = 0
+        if parts or long_parts is None:
+            with span("applier.stage_wave"):
+                staged = self._stage_wave(parts)
+            if staged is not None:
+                with span("applier.dispatch"):
+                    n = self._execute_wave(staged)
+        if long_parts:
+            n += self._dispatch_long(long_parts)
+        return n
 
     # --------------------------------------------- stage / execute halves
 
@@ -911,6 +1147,8 @@ class TpuDocumentApplier:
         wave still executing — strict wave order at every seam."""
         if self._exec_marker is not None:
             jax.block_until_ready(self._exec_marker)
+        if self._long_marker is not None:
+            jax.block_until_ready(self._long_marker)
 
     def _stage_wave(self, parts) -> Optional[_StagedWave]:
         """The HOST half of a dispatch: concat chunks → pack_wave_rows →
@@ -951,6 +1189,8 @@ class TpuDocumentApplier:
         pos_idx = np.arange(n, dtype=np.int64) - np.repeat(starts, lens_a)
 
         packed, seq_base, text_base = pack_wave_rows(flat, starts, lens_a)
+        if self.long_state is not None:
+            self._count_hi[slots_a] += 2 * lens_a
 
         force_wide = (
             self.fault_plane is not None
@@ -1216,19 +1456,45 @@ class TpuDocumentApplier:
 
     def _check_overflow(self) -> None:
         self._dispatches_since_check = 0
-        flags = np.asarray(self.state.overflow)  # host sync point
+        if self.long_state is None:
+            flags = np.asarray(self.state.overflow)  # host sync point
+            for slot in np.nonzero(flags)[0]:
+                if int(slot) not in self._host_docs:
+                    self._escalate(int(slot), None, None)
+            return
+        flags, counts = jax.device_get((self.state.overflow,
+                                        self.state.count))
+        self._count_hi[:] = counts
         for slot in np.nonzero(flags)[0]:
-            if int(slot) not in self._host_docs:
+            if int(slot) not in self._host_docs \
+                    and int(slot) not in self._long_rows:
                 self._escalate(int(slot), None, None)
+        if self._long_since_check:
+            self._check_long()
+        # the fence's promotions: farm docs the next wave could overflow
+        for slot in np.nonzero(counts > self.max_slots - 2 * self.K)[0]:
+            if not self._long_free:
+                break
+            if int(slot) not in self._host_docs \
+                    and int(slot) not in self._long_rows:
+                self._promote(int(slot))
 
     # ------------------------------------------------------------- queries
 
     def slot_count(self, tenant_id: str, document_id: str) -> int:
         """Live device slots for a doc (bounded under churn by zamboni)."""
         slot = self.slot_of(tenant_id, document_id)
+        row = self._long_rows.get(slot)
+        if row is not None:
+            return int(np.asarray(self.long_state.count)[row])
         return int(np.asarray(self.state.count)[slot])
 
     def _device_slot(self, slot: int) -> DocState:
+        """The doc's row of whichever tier holds it, on the host."""
+        row = self._long_rows.get(slot)
+        if row is not None:
+            return jax.tree.map(lambda a: np.asarray(a)[row],
+                                self.long_state)
         return jax.tree.map(lambda a: np.asarray(a)[slot], self.state)
 
     def _sync(self, slot: int) -> None:
@@ -1354,6 +1620,9 @@ class TpuDocumentApplier:
                 "replay source is configured (set_replay_source)")
         self.host_escalations += 1
         self._marks.dirty.add(slot)
+        row = self._long_rows.pop(slot, None)
+        if row is not None:
+            self._long_free.append(row)
         replica = MergeTreeClient(f"tpu-applier/{tenant_id}/{document_id}")
         self._host_docs[slot] = replica
         self._drop_staged(slot)
@@ -1401,6 +1670,13 @@ class TpuDocumentApplier:
 #   the sidecar holds for each of them, and what the property table grew
 #   by: its cost follows the docs that moved, not the farm.
 #
+# The long tier rides beside the farm: its [L, S_long] arrays as
+# ``long_<field>`` in the base's ``.npz``, its rows in a delta after the
+# farm's, and ``long`` in the sidecar ({docs, slots, rows: {row: [slot,
+# tenant, doc]}}). A promoted doc leaves the sidecar's ``doc_keys``; the
+# farm's arrays and ``doc_keys`` keep the layout a farm-only reader
+# expects.
+#
 # ``load_applier_checkpoint`` loads the base, then replays the deltas
 # numbered past its ``delta_seq``, in order, up to the first one missing
 # or torn. A torn tail is a delta that never committed; the stage writes
@@ -1415,6 +1691,13 @@ _STATE_FIELDS = ("length", "text_start", "flags", "ins_seq", "ins_client",
                  "rem_seq", "rem_client_a", "rem_client_b", "prop_key",
                  "prop_val", "count", "overflow")
 
+#: per-doc fields; the others are per slot, and a slot not in use holds
+#: ``_SLOT_FILL`` (0 where not named: DocState.empty's values)
+_DOC_FIELDS = ("count", "overflow")
+_SLOT_FILL = {"ins_client": NO_CLIENT, "rem_seq": NO_SEQ,
+              "rem_client_a": NO_CLIENT, "rem_client_b": NO_CLIENT,
+              "prop_key": NO_KEY}
+
 #: the sidecar's per-slot tables a delta carries an entry of for each
 #: slot it holds (None: the slot has none)
 _SLOT_TABLES = ("client_ids", "applied_seq", "first_seq", "restore_applied")
@@ -1428,10 +1711,13 @@ class _CheckpointMarks:
     length and the property table's size when it was taken, and the
     number of the last delta its state reflects."""
 
-    __slots__ = ("dirty", "arena_len", "props", "seq")
+    __slots__ = ("dirty", "promoted", "arena_len", "props", "seq")
 
     def __init__(self, max_docs: int):
         self.dirty: set[int] = set()
+        # moved to the long tier since: the delta carries their emptied
+        # farm rows once
+        self.promoted: set[int] = set()
         self.arena_len = [0] * max_docs
         self.props = (0, 0)
         self.seq = 0
@@ -1439,15 +1725,29 @@ class _CheckpointMarks:
     def reset(self, applier: "TpuDocumentApplier") -> None:
         """Everything the applier holds is saved."""
         self.dirty.clear()
+        self.promoted.clear()
         self.arena_len = [len(a) for a in applier.arenas]
         snap = applier.prop_table.snapshot()
         self.props = (len(snap["keys"]), len(snap["vals"]))
 
 
-def _device_get(applier: "TpuDocumentApplier") -> dict:
-    """Every state array on the host, in one fixed-shape transfer."""
-    return jax.device_get({f: getattr(applier.state, f)
-                           for f in _STATE_FIELDS})
+#: key prefix of the long tier's arrays in a base's ``.npz``
+LONG_PREFIX = "long_"
+
+
+def _device_get(applier: "TpuDocumentApplier", long: bool = True) -> dict:
+    """Every state array on the host, in one fixed-shape transfer; with
+    ``long``, the long tier's too, under ``LONG_PREFIX``."""
+    tree = {f: getattr(applier.state, f) for f in _STATE_FIELDS}
+    if long and applier.long_state is not None:
+        tree.update({LONG_PREFIX + f: getattr(applier.long_state, f)
+                     for f in _STATE_FIELDS})
+    return jax.device_get(tree)
+
+
+def _long_nbytes(arrays: dict) -> int:
+    return sum(a.nbytes for k, a in arrays.items()
+               if k.startswith(LONG_PREFIX))
 
 
 def save_applier_checkpoint(applier: "TpuDocumentApplier",
@@ -1481,7 +1781,8 @@ def _base_meta(applier: "TpuDocumentApplier") -> dict:
         "prop_table": applier.prop_table.snapshot(),
         "client_ids": {str(k): dict(v)
                        for k, v in applier._client_ids.items()},
-        "doc_keys": {str(k): list(v) for k, v in applier._doc_keys.items()},
+        "doc_keys": {str(k): list(v) for k, v in applier._doc_keys.items()
+                     if k not in applier._long_rows},
         "placement": applier.placement.snapshot(),
         "host_docs": {str(k): replica.snapshot()
                       for k, replica in applier._host_docs.items()},
@@ -1499,6 +1800,11 @@ def _base_meta(applier: "TpuDocumentApplier") -> dict:
                             for k, v in applier._restore_applied.items()},
         "delta_seq": applier._marks.seq,
     }
+    if applier.long_state is not None:
+        meta["long"] = {
+            "docs": applier.long_docs, "slots": applier.long_slots,
+            "rows": {str(row): [slot, *applier._doc_keys[slot]]
+                     for slot, row in applier._long_rows.items()}}
     return meta
 
 
@@ -1529,12 +1835,18 @@ def _write_base(arrays: dict, meta: dict, path: str) -> int:
 
 
 def _delta_payload(applier: "TpuDocumentApplier", arrays: dict
-                   ) -> tuple[bytes, int]:
+                   ) -> tuple[bytes, int, int]:
     """One delta's payload: the rows of the slots marked since the last
     checkpoint, taken from the host copy ``arrays``, and their sidecar;
-    marks them saved. Returns it and the number of slots."""
+    marks them saved. A doc on the long tier ships its long row (after
+    the farm's rows), and its farm row only in the delta that follows
+    its promotion. Returns it, the number of slots and the long rows'
+    bytes."""
     m = applier._marks
     slots = sorted(m.dirty)
+    on_long = applier._long_rows
+    farm_slots = [s for s in slots if s not in on_long or s in m.promoted]
+    long_slots = [s for s in slots if s in on_long]
     snap = applier.prop_table.snapshot()
     keys0, vals0 = m.props
     sidecar = []
@@ -1544,7 +1856,9 @@ def _delta_payload(applier: "TpuDocumentApplier", arrays: dict
         key = applier._doc_keys.get(s)
         sidecar.append({
             "arena": [m.arena_len[s], arena.tail(m.arena_len[s])],
-            "doc_key": list(key) if key else None,
+            "doc_key": list(key) if key and s not in on_long else None,
+            "long_row": on_long.get(s),
+            "long_key": list(key) if s in on_long else None,
             "host_doc": host.snapshot() if host is not None else None,
             "anchored": s in applier._anchored,
             "client_ids": applier._client_ids.get(s),
@@ -1552,17 +1866,35 @@ def _delta_payload(applier: "TpuDocumentApplier", arrays: dict
             "first_seq": applier._first_seq.get(s),
             "restore_applied": applier._restore_applied.get(s)})
         m.arena_len[s] = len(arena)
-    idx = np.asarray(slots, np.int64)
+    idx = np.asarray(farm_slots, np.int64)
     rows = [np.ascontiguousarray(arrays[f][idx]) for f in _STATE_FIELDS]
+    long_bytes = width = 0
+    if long_slots:
+        lidx = np.asarray([on_long[s] for s in long_slots], np.int64)
+        # a row's slots from its count on hold the empty fill (every step
+        # ends in zamboni's repack): ship the used prefix of the rows
+        width = int(arrays[LONG_PREFIX + "count"][lidx].max())
+        long_rows = [np.ascontiguousarray(
+            arrays[LONG_PREFIX + f][lidx] if f in _DOC_FIELDS
+            else arrays[LONG_PREFIX + f][lidx, :width])
+            for f in _STATE_FIELDS]
+        long_bytes = sum(r.nbytes for r in long_rows)
+        rows += long_rows
     m.seq += 1
     m.dirty.clear()
+    m.promoted.clear()
     m.props = (len(snap["keys"]), len(snap["vals"]))
-    head = json.dumps({
+    head = {
         "seq": m.seq, "slots": slots, "sidecar": sidecar,
         "props": [keys0, vals0, snap["keys"][keys0:], snap["vals"][vals0:]],
-        "rows": [[r.dtype.str, list(r.shape)] for r in rows]}).encode()
-    return b"".join([_DELTA_HEAD.pack(len(head)), head]
-                    + [r.tobytes() for r in rows]), len(slots)
+        "rows": [[r.dtype.str, list(r.shape)] for r in rows]}
+    if farm_slots != slots:
+        head["farm_slots"] = farm_slots
+    if long_slots:
+        head["long_slots"], head["long_width"] = long_slots, width
+    head = json.dumps(head).encode()
+    return (b"".join([_DELTA_HEAD.pack(len(head)), head]
+                     + [r.tobytes() for r in rows]), len(slots), long_bytes)
 
 
 def _decode_delta(payload: bytes) -> tuple[dict, list]:
@@ -1576,7 +1908,8 @@ def _decode_delta(payload: bytes) -> tuple[dict, list]:
         n = int(np.prod(shape))
         rows.append(np.frombuffer(payload, dtype, n, off).reshape(shape))
         off += n * dtype.itemsize
-    if off != len(payload) or len(rows) != len(_STATE_FIELDS):
+    tiers = 2 if head.get("long_slots") else 1
+    if off != len(payload) or len(rows) != tiers * len(_STATE_FIELDS):
         raise ValueError("delta rows do not fill the record")
     return head, rows
 
@@ -1627,12 +1960,25 @@ def _replay_delta(meta: dict, arrays: dict, head: dict, rows: list) -> None:
                          "checkpoint it is loaded onto")
     table["keys"] += keys
     table["vals"] += vals
-    idx = np.asarray(head["slots"], np.int64)
+    idx = np.asarray(head.get("farm_slots", head["slots"]), np.int64)
     for f, r in zip(_STATE_FIELDS, rows):
         arrays[f][idx] = r
+    side_of = dict(zip(head["slots"], head["sidecar"]))
+    if head.get("long_slots"):
+        lidx = np.asarray([side_of[s]["long_row"]
+                           for s in head["long_slots"]], np.int64)
+        width = head["long_width"]
+        for f, r in zip(_STATE_FIELDS, rows[len(_STATE_FIELDS):]):
+            a = arrays[LONG_PREFIX + f]
+            if f in _DOC_FIELDS:
+                a[lidx] = r
+            else:
+                a[lidx, :width] = r
+                a[lidx, width:] = _SLOT_FILL.get(f, 0)
+    long_rows = meta["long"]["rows"] if "long" in meta else {}
     sps = meta["placement"]["slots_per_shard"]
     anchored = set(meta.get("anchored", ()))
-    for slot, side in zip(head["slots"], head["sidecar"]):
+    for slot, side in side_of.items():
         k = str(slot)
         start, tail = side["arena"]
         if len(meta["arenas"][slot]) != start:
@@ -1640,11 +1986,17 @@ def _replay_delta(meta: dict, arrays: dict, head: dict, rows: list) -> None:
                              f"{len(meta['arenas'][slot])} chars, not "
                              f"{start}")
         meta["arenas"][slot] += tail
-        key = side["doc_key"]
+        # a doc on the long tier is named by its long row, not doc_keys
+        long_key = side.get("long_key")
+        key = side["doc_key"] or long_key
         if key is not None:
             meta["placement"]["map"][DocPlacement.key(*key)] = [
                 slot // sps, slot % sps]
-        _put(meta["doc_keys"], k, key)
+        _put(meta["doc_keys"], k, side["doc_key"])
+        for row in [r for r, v in long_rows.items() if v[0] == slot]:
+            del long_rows[row]
+        if long_key is not None:
+            long_rows[str(side["long_row"])] = [slot, *long_key]
         _put(meta["host_docs"], k, side["host_doc"])
         _put(meta["host_doc_names"], k,
              key if side["host_doc"] is not None else None)
@@ -1660,6 +2012,7 @@ class CheckpointWrite(NamedTuple):
     nbytes: int  # the delta's or base's, plus each base committed since
     docs: int    # slots the delta holds
     bases: int   # bases committed by the call, or since the last call
+    long_bytes: int = 0  # the long tier's rows in it (raw, in a base)
 
 
 class ApplierCheckpointer:
@@ -1674,6 +2027,10 @@ class ApplierCheckpointer:
         #: a base is due once the deltas written since the newest one
         #: hold more than the state itself: its raw bytes, a geometry
         self.limit = sum(a.nbytes for a in jax.tree.leaves(applier.state))
+        #: the long tier's raw bytes: its share of each base
+        self.long_raw = sum(a.nbytes for a in
+                            jax.tree.leaves(applier.long_state))
+        self.limit += self.long_raw
         self._since_base = 0
         self._file: Optional[str] = None  # the delta file appended to
         # the applier-base thread while a base is in flight, and where it
@@ -1691,7 +2048,8 @@ class ApplierCheckpointer:
         self.applier._marks.reset(self.applier)
         self._file, self._since_base = None, 0
         self._remove_deltas(None)
-        return CheckpointWrite(nbytes, 0, bases + 1)
+        return CheckpointWrite(nbytes, 0, bases + 1,
+                               (bases + 1) * self.long_raw)
 
     def delta(self) -> CheckpointWrite:
         """Append one delta of the slots changed since the last
@@ -1701,11 +2059,13 @@ class ApplierCheckpointer:
         a = self.applier
         a.finalize()
         if not a._marks.dirty:
-            return CheckpointWrite(nbytes, 0, bases)
+            return CheckpointWrite(nbytes, 0, bases, bases * self.long_raw)
         with span("checkpoint.delta"):
             with span("checkpoint.delta.device_get"):
-                arrays = _device_get(a)
-            payload, docs = _delta_payload(a, arrays)
+                # the long tier's arrays only when one of its docs moved
+                arrays = _device_get(a, long=not a._marks.dirty.isdisjoint(
+                    a._long_rows))
+            payload, docs, long_bytes = _delta_payload(a, arrays)
             with span("checkpoint.delta.write"):
                 frame = frame_record(payload)
                 mode = "ab"
@@ -1717,8 +2077,11 @@ class ApplierCheckpointer:
                     f.write(frame)
         self._since_base += len(frame)
         if self._since_base > self.limit and self._writer is None:
+            if a.long_state is not None and not _long_nbytes(arrays):
+                arrays.update(_device_get(a, long=True))
             self._start_base(arrays)
-        return CheckpointWrite(nbytes + len(frame), docs, bases)
+        return CheckpointWrite(nbytes + len(frame), docs, bases,
+                               long_bytes + bases * self.long_raw)
 
     def _start_base(self, arrays: dict) -> None:
         """Snapshot a base here (the host arrays the delta just took, and
@@ -1781,10 +2144,26 @@ def load_applier_checkpoint(path: str, **applier_kwargs
     for head, rows in _deltas_after(path, seq):
         _replay_delta(meta, arrays, head, rows)
         seq = head["seq"]
+    long = meta.get("long")
+    if long is not None:
+        applier_kwargs = {**applier_kwargs, "long_docs": long["docs"],
+                          "long_slots": long["slots"]}
     applier = TpuDocumentApplier(max_docs=meta["max_docs"],
                                  max_slots=meta["max_slots"],
                                  **applier_kwargs)
-    applier.state = _DS(**{k: jnp.asarray(v) for k, v in arrays.items()})
+    applier.state = _DS(**{k: jnp.asarray(v) for k, v in arrays.items()
+                           if not k.startswith(LONG_PREFIX)})
+    if long is not None:
+        applier.long_state = _DS(**{
+            k[len(LONG_PREFIX):]: jnp.asarray(v) for k, v in arrays.items()
+            if k.startswith(LONG_PREFIX)})
+        for row, (slot, *key) in long["rows"].items():
+            applier._long_rows[slot] = int(row)
+            applier._doc_keys[slot] = tuple(key)
+        applier._long_free = [r for r in range(long["docs"] - 1, -1, -1)
+                              if r not in applier._long_rows.values()]
+    if applier.long_state is not None:
+        applier._count_hi[:] = arrays["count"]
     if applier._mesh is not None:
         # a mesh applier's step requires state committed per P("docs");
         # without this re-shard the first dispatch would silently pay an
@@ -1800,8 +2179,8 @@ def load_applier_checkpoint(path: str, **applier_kwargs
     applier.prop_table = PropTable.load(meta["prop_table"])
     applier._client_ids = {int(k): dict(v)
                            for k, v in meta["client_ids"].items()}
-    applier._doc_keys = {int(k): tuple(v)
-                         for k, v in meta["doc_keys"].items()}
+    applier._doc_keys.update({int(k): tuple(v)
+                              for k, v in meta["doc_keys"].items()})
     placement = DocPlacement.load(meta["placement"])
     if applier._mesh is not None and \
             placement.n_shards != applier.placement.n_shards:

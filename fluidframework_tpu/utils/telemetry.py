@@ -150,6 +150,9 @@ SPANS = {
     "applier.fence": "finalize: wait for the device, read overflow",
     "applier.stage_wave": "host half of a wave: pack, scatter, put",
     "applier.dispatch": "enqueue of one jitted step (asynchronous)",
+    "applier.promote": "one farm doc moved to the long tier on the device",
+    "applier.long_stage_wave": "host half of a long-tier wave: rows, put",
+    "applier.long_dispatch": "enqueue of one long-tier step (asynchronous)",
     "checkpoint.save": "a base: save_applier_checkpoint, or its write "
                        "on the applier-base thread",
     "checkpoint.device_get": "copy of the state arrays to the host",

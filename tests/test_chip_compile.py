@@ -145,3 +145,49 @@ def test_mesh_packed_step_compiles(topo, no_compile_cache, lane):
     assert ("tpu_custom_call" in hlo) == (lane == "pallas")
     assert "all-reduce" in hlo
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+LONG_L, LONG_S = 16, 8192
+
+
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_long_tier_step_compiles(topo, no_compile_cache, lane):
+    """The long tier's step at 16 x 8192 slots x 32 ops (int32 wave,
+    apply at the tier's layout, zamboni): the Pallas kernel fits its
+    VMEM scope, and is the custom call."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from fluidframework_tpu.ops.apply import OP_FIELDS
+    from fluidframework_tpu.ops.doc_state import DocState
+    from fluidframework_tpu.service.tpu_applier import _long_step_for
+
+    one = SingleDeviceSharding(topo.devices[0])
+    step = _long_step_for(LONG_L, 32, use_pallas=lane == "pallas")
+    state = _shapes(jax.eval_shape(lambda: jax.vmap(
+        lambda _: DocState.empty(LONG_S))(jnp.arange(LONG_L))), one)
+    wave = jax.ShapeDtypeStruct((LONG_L, 32, OP_FIELDS), jnp.int32,
+                                sharding=one)
+    compiled = step.lower(state, wave).compile()
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (lane == "pallas")
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_promotion_compiles(topo, no_compile_cache):
+    """A farm row of the text cells' geometry moved into a long row."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from fluidframework_tpu.ops.doc_state import DocState
+    from fluidframework_tpu.service.tpu_applier import _promote_rows
+
+    one = SingleDeviceSharding(topo.devices[0])
+    long = _shapes(jax.eval_shape(lambda: jax.vmap(
+        lambda _: DocState.empty(LONG_S))(jnp.arange(LONG_L))), one)
+    idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    compiled = jax.jit(_promote_rows).lower(
+        _state_shapes(D, one), long, idx, idx).compile()
+    assert _device_bytes(compiled) < HBM_BYTES

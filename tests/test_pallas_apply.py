@@ -14,26 +14,35 @@ import pytest
 from fluidframework_tpu.ops.apply import apply_ops_batch
 from fluidframework_tpu.ops.doc_state import DocState
 from fluidframework_tpu.ops.opgen import generate_batch_ops
-from fluidframework_tpu.ops.pallas_apply import pallas_apply_ops_batch
+from fluidframework_tpu.ops.pallas_apply import (pallas_apply_ops_batch,
+                                                 pallas_apply_ops_batch_long)
 
 FIELDS = ("length", "text_start", "flags", "ins_seq", "ins_client",
           "rem_seq", "rem_client_a", "rem_client_b", "prop_key",
           "prop_val", "count", "overflow")
 
 
-def _run_pair(seed, D=16, S=64, K=24, **gen):
+def _run_pair(seed, D=16, S=64, K=24, kernel=pallas_apply_ops_batch,
+              **gen):
     rng = np.random.default_rng(seed)
     state = jax.vmap(lambda _: DocState.empty(S))(jnp.arange(D))
     ops = jnp.asarray(generate_batch_ops(rng, D, K, **gen))
     ref = apply_ops_batch(state, ops)
-    got = pallas_apply_ops_batch(state, ops, interpret=True)
+    got = kernel(state, ops, interpret=True)
     return ref, got
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_pallas_matches_xla_scan(seed):
-    ref, got = _run_pair(seed, remove_fraction=0.3, annotate_fraction=0.15,
-                         max_insert=6)
+# the long tier's layout (prop planes slot-last, state carried in refs)
+# at a wider row, beside the farm's
+@pytest.mark.parametrize("seed,kernel,S", [
+    (0, pallas_apply_ops_batch, 64), (1, pallas_apply_ops_batch, 64),
+    (2, pallas_apply_ops_batch, 64),
+    (0, pallas_apply_ops_batch_long, 256),
+    (7, pallas_apply_ops_batch_long, 256),
+], ids=["0", "1", "2", "long-0", "long-7"])
+def test_pallas_matches_xla_scan(seed, kernel, S):
+    ref, got = _run_pair(seed, S=S, kernel=kernel, remove_fraction=0.3,
+                         annotate_fraction=0.15, max_insert=6)
     for f in FIELDS:
         np.testing.assert_array_equal(
             np.asarray(getattr(got, f)), np.asarray(getattr(ref, f)), f)

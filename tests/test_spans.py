@@ -202,3 +202,53 @@ def test_loop_lag_ticker_reports_a_held_loop(tmp_path):
     stalls = [s for s in doc["spans"] if s[0] == "frontend.loop_stall"]
     assert len(stalls) == 1 and stalls[0][2] >= 90e6
     assert "fluid_frontend_loop_lag_ms_count" in get_registry().scrape()
+
+
+def test_the_long_tier_spans_nest_in_the_turn_and_counters_add_up(
+        tmp_path, monkeypatch):
+    """An applier stage with a long tier, under an armed trace: each
+    promotion's span lies inside the turn's ``stage.checkpoint``, the
+    tier's wave halves are there, and the window's tier counters are
+    what the applier counted (the values STOPPED prints)."""
+    from fluidframework_tpu import config
+    from fluidframework_tpu.service.durable_log import DurableLog
+    from fluidframework_tpu.service.load_gen import run_inproc
+    from fluidframework_tpu.service.stage_runner import ApplierStage
+
+    for name, value in (("applier_max_docs", 8), ("applier_max_slots", 16),
+                        ("applier_ops_per_dispatch", 4),
+                        ("applier_long_docs", 8),
+                        ("applier_long_slots", 256)):
+        monkeypatch.setattr(config.DEFAULT, name, value)
+    log = DurableLog(str(tmp_path / "log"))
+    run_inproc(n_docs=3, clients_per_doc=2, ops_per_client=24,
+               batch_size=4, seed=5, log=log)
+    log.flush()
+    stage = ApplierStage(str(tmp_path / "log"), str(tmp_path / "state"))
+    tracing_start(str(tmp_path / "trace"), "stage",
+                  counters=(stage.counters,))
+    while stage.run_once():
+        pass
+    doc = _load(tracing_stop())
+    a = stage.applier
+    assert a.promotions >= 1 and a.long_dispatches >= 1
+    assert a.host_escalations == 0
+    spans = doc["spans"]
+
+    def ancestors(i):
+        while spans[i][3] != -1:
+            i = spans[i][3]
+            yield spans[i][0]
+
+    names = [s[0] for s in spans]
+    promotes = [i for i, n in enumerate(names) if n == "applier.promote"]
+    assert len(promotes) == a.promotions
+    for i in promotes:
+        assert "stage.checkpoint" in set(ancestors(i))
+    assert names.count("applier.long_dispatch") == a.long_dispatches
+    assert names.count("applier.long_stage_wave") == a.long_dispatches
+    c = doc["counters"]
+    assert c["applier.promotions"] == a.promotions
+    assert c["applier.long.dispatches"] == a.long_dispatches
+    assert c["applier.long.slots_peak"] == a.long_slots_peak > 16
+    assert c["stage.checkpoint.long_bytes"] > 0

@@ -168,7 +168,12 @@ LOCKED_FAMILIES = {
                            "applier.stage.seconds",
                            "applier.stage.bytes",
                            "applier.stage.overlap_ratio",
-                           "applier.dispatch.seconds"}),
+                           "applier.dispatch.seconds",
+                           # the long tier's, on the stage's counters
+                           # (service/stage_runner.py ApplierStage._count)
+                           "applier.promotions",
+                           "applier.long.dispatches",
+                           "applier.long.slots_peak"}),
     # the placement control plane: the net-smoke migration gate, the
     # admin CLI, and the chaos migration campaign key on these exact
     # names (service/placement_plane.py); placement.heat.* are the
