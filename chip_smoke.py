@@ -13,9 +13,9 @@ escalation. After the stage has exited, each doc's text in its
 checkpoint must equal the scalar merge-tree oracle replayed from the
 durable log. This process stays off JAX until then.
 
-Phase ``inproc`` is bench_service's 8,192-doc scale row: ``run_inproc``
-over ``DurableLog`` with an async 8,192 x 256 applier; every op acked
-and applied, no escalation, every doc's device text equal to the oracle.
+Phase ``inproc`` runs 8,192 docs in one process: ``run_inproc`` over
+``DurableLog`` with an 8,192 x 256 applier; every op acked and applied,
+no escalation, every doc's device text equal to the oracle.
 
 Phase ``mesh`` runs ``TpuDocumentApplier(mesh=4)`` at 8,192 docs under
 ``run_inproc`` and a one-chip dense applier on the same logged op stream;
@@ -52,7 +52,7 @@ MESH_DOCS = 64 if _TINY else 8192
 CLIENTS = 2
 BOXCAR = 32
 ROUNDS = 2            # 64 ops per client in 32-op boxcars
-INPROC_OPS = 24       # bench_service's scale row: 24 ops per client
+INPROC_OPS = 24       # ops per client
 MAX_SLOTS = 256
 WAIT_S = 600.0
 
@@ -259,26 +259,20 @@ def phase_inproc(tmp: str, clock, device) -> dict:
     c0 = clock.seconds
     t0 = time.perf_counter()
     log = DurableLog(os.path.join(tmp, "inproc-log"))
-    applier = TpuDocumentApplier(
-        max_docs=INPROC_DOCS, max_slots=MAX_SLOTS, async_dispatch=True,
-        min_wave_ops=INPROC_DOCS * INPROC_OPS)
-    try:
-        stats = run_inproc(n_docs=INPROC_DOCS, clients_per_doc=CLIENTS,
-                           ops_per_client=INPROC_OPS, applier=applier,
-                           flush_every=4 * INPROC_DOCS, seed=5,
-                           batch_size=INPROC_OPS, array_lane=True, log=log)
-        check(stats.ops_acked == stats.ops_submitted == stats.applier_ops,
-              f"submitted {stats.ops_submitted} acked {stats.ops_acked} "
-              f"applied {stats.applier_ops}")
-        check(stats.applier_escalations == 0,
-              f"{stats.applier_escalations} escalations")
-        check(applier.kernel_lane == expected_lane(device.platform),
-              f"kernel lane {applier.kernel_lane}")
-        t_run = time.perf_counter() - t0
-        n = compare_texts(applier, log,
-                          [f"doc{i}" for i in range(INPROC_DOCS)])
-    finally:
-        applier.close()
+    applier = TpuDocumentApplier(max_docs=INPROC_DOCS, max_slots=MAX_SLOTS)
+    stats = run_inproc(n_docs=INPROC_DOCS, clients_per_doc=CLIENTS,
+                       ops_per_client=INPROC_OPS, applier=applier,
+                       flush_every=4 * INPROC_DOCS, seed=5,
+                       batch_size=INPROC_OPS, array_lane=True, log=log)
+    check(stats.ops_acked == stats.ops_submitted == stats.applier_ops,
+          f"submitted {stats.ops_submitted} acked {stats.ops_acked} "
+          f"applied {stats.applier_ops}")
+    check(stats.applier_escalations == 0,
+          f"{stats.applier_escalations} escalations")
+    check(applier.kernel_lane == expected_lane(device.platform),
+          f"kernel lane {applier.kernel_lane}")
+    t_run = time.perf_counter() - t0
+    n = compare_texts(applier, log, [f"doc{i}" for i in range(INPROC_DOCS)])
     return {"docs": INPROC_DOCS, "clients": CLIENTS,
             "ops": stats.ops_submitted, "acked": stats.ops_acked,
             "run_s": t_run, "wall_s": time.perf_counter() - t0,

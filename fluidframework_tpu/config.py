@@ -31,7 +31,6 @@ class Config:
     applier_max_docs: int = 256          # device doc slots [D]
     applier_max_slots: int = 256         # segment slots per doc [S]
     applier_ops_per_dispatch: int = 32   # wave depth [K]
-    applier_min_wave_ops: int = 0        # async worker dispatch threshold
     applier_overflow_check_every: int = 64  # dispatches between fences
     # the long tier: [L, S_long] device rows beside the [D, S] farm for
     # documents that outgrow S (promoted on the device, not replayed on
@@ -39,12 +38,6 @@ class Config:
     # of two; L = 0 leaves the tier out.
     applier_long_docs: int = 0           # long rows [L]
     applier_long_slots: int = 4096       # segment slots per long row
-    # use the Pallas VMEM-resident apply (ops/pallas_apply.py) in the
-    # applier's dense step (requires max_docs % 8 == 0; which lane is
-    # faster on the chip is not measured yet). Deprecated in favor of
-    # ``applier_kernel``; None defers to it, an explicit bool wins (the
-    # pre-kernel-selection API keeps working).
-    applier_use_pallas: Optional[bool] = None
     # contract-kernel selection for the apply step: "auto" picks the
     # Pallas VMEM-resident kernel on real TPU devices and the XLA scan
     # everywhere else (on a TPU it falls back to XLA, with a warning,
@@ -52,11 +45,6 @@ class Config:
     # "pallas"/"xla" force a lane (a forced "pallas" raises on
     # incompatible geometry).
     applier_kernel: str = "auto"
-    # overlap-staged dispatch: stage wave N+1 on the host (pack +
-    # per-shard scatter + device_put) while wave N executes
-    # asynchronously on device. Off = fence each wave before staging the
-    # next (the serialized pre-overlap behavior, kept for A/B).
-    applier_overlap: bool = True
     # ---- client: summarizer heuristics (ref: summarizer.ts:232)
     summary_max_ops: int = 100           # ops since last ack → attempt
     # ---- DDS: merge-tree snapshot chunking (ref: snapshotV1.ts:87)
@@ -89,23 +77,7 @@ class Config:
                 # set-but-empty (export FLUID_TPU_X=) means "unset" in
                 # shell convention: keep the layered default
                 continue
-            cur = getattr(base, f.name)
-            # Optional fields default to None: the only such tunables are
-            # bool-typed (applier_use_pallas), so parse them as booleans
-            typ = bool if cur is None else type(cur)
-            if typ is bool:
-                # bool("0") is True — parse the usual spellings instead
-                low = raw.strip().lower()
-                if low in ("1", "true", "yes", "on"):
-                    overrides[f.name] = True
-                elif low in ("0", "false", "no", "off"):
-                    overrides[f.name] = False
-                else:
-                    raise ValueError(
-                        f"{ENV_PREFIX}{f.name.upper()}={raw!r}: expected a "
-                        "boolean (1/0/true/false/yes/no/on/off)")
-            else:
-                overrides[f.name] = typ(raw)
+            overrides[f.name] = type(getattr(base, f.name))(raw)
         return base.with_overrides(**overrides)
 
 

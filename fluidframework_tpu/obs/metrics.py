@@ -21,7 +21,7 @@ module is the process-wide aggregation point:
 - ``scrape()`` renders Prometheus text exposition (counters as
   ``counter``, gauges as ``gauge``, observations as ``summary`` with
   p50/p99 quantile labels); :func:`parse_prometheus` is the matching
-  reader used by tools/net_smoke.py and bench.py.
+  reader used by tools/net_smoke.py.
 
 Dotted metric names (``tier.noun.verb`` — enforced by the fluidlint
 ``metric-name`` pass) map to Prometheus by ``.`` → ``_`` with a
@@ -554,7 +554,7 @@ def parse_prometheus(text: str) -> dict:
     """Parse text exposition → {name: {label-tuple: value}}.
 
     The reader half of :meth:`MetricsRegistry.scrape` (quantile labels
-    included verbatim), used by tools/net_smoke.py and bench.py to
+    included verbatim), used by tools/net_smoke.py to
     consume ``admin_metrics_scrape`` output without a client library.
     """
     out: dict[str, dict[tuple, float]] = {}

@@ -36,9 +36,10 @@ Deployment: ``python -m fluidframework_tpu.service.gateway
 ``--upstream-gateway H:P`` (aliases the core address and keeps the
 asyncio relay, which speaks the backbone protocol on both sides).
 
-When to use it (measured honestly): on a single host the extra hop LOSES
-— the core's one-encode batch cache makes direct fan-out writes cheap,
-so bench.py keeps the direct topology. Gateways are the cross-HOST
+When to use it: on a single host the extra hop buys nothing — the core's
+one-encode batch cache makes direct fan-out writes cheap, so the
+benchmark's cells use the direct topology (the hop's cost there has no
+chip measurement). Gateways are the cross-HOST
 scale-out story: socket termination under TLS/compression, thousands of
 clients per doc, or a core that is NIC-bound — the same conditions that
 motivate the reference's multi-Alfred deployment.

@@ -614,7 +614,7 @@ class MigrationEngine:
 def admin_rpc(host: str, port: int, frame: dict,
               timeout: float = 30.0) -> dict:
     """One rid-matched admin RPC round trip (length-prefixed JSON — the
-    same wire shape bench.py and the admin CLI use)."""
+    same wire shape the admin CLI uses)."""
     import socket
 
     with socket.create_connection((host, port), timeout=timeout) as s:

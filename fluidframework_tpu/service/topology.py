@@ -21,8 +21,7 @@ function that turns (spec, core_index) into a serving
 --core-index 2`` and an in-process test fleet are the same code.
 :class:`Fleet` drives the whole object: start every core (and gateway)
 from the spec, SIGKILL the lot mid-traffic, and restart from the same
-spec — the cold-start storm bench (``bench.py net_cold_storm``) and
-the cold-start chaos drill are its two callers.
+spec — as the cold-start chaos drill (chaos/coldstart.py) does.
 
 Counters (tier "frontend", locked in fluidlint's registry):
 

@@ -122,39 +122,31 @@ class _StagedWave:
         self.flip = 0           # which staging-buffer set holds the wave
 
 
-def _resolve_kernel(kernel, use_pallas, cfg, tile_docs: int) -> bool:
-    """Resolve the applier's contract kernel to use_pallas.
-
-    Precedence: an explicit ``use_pallas`` bool (the pre-selection API)
-    wins, then config ``applier_use_pallas`` when set, then
-    ``kernel``/``applier_kernel``. ``auto`` selects Pallas only on real
-    TPU devices AND when the doc geometry tiles (R=8 docs per grid
+def _resolve_kernel(kernel, cfg, tile_docs: int) -> bool:
+    """Resolve the applier's contract kernel (``kernel``, else config
+    ``applier_kernel``) to use_pallas. ``auto`` selects Pallas only on
+    real TPU devices AND when the doc geometry tiles (R=8 docs per grid
     instance); a forced ``pallas`` raises on bad geometry, while ``auto``
     falls back to the XLA scan with a warning."""
-    if use_pallas is None:
-        use_pallas = cfg.applier_use_pallas
-    if use_pallas is not None:
-        use, origin = bool(use_pallas), "applier_use_pallas"
-    else:
-        kernel = kernel if kernel is not None else cfg.applier_kernel
-        if kernel not in ("auto", "pallas", "xla"):
-            raise ValueError(
-                f"applier_kernel={kernel!r}: expected auto|pallas|xla")
-        if kernel == "auto":
-            if jax.default_backend() != "tpu":
-                return False
-            if tile_docs % 8:
-                warnings.warn(
-                    f"applier_kernel=auto on a TPU picks the XLA scan: "
-                    f"docs-per-shard {tile_docs} is not a multiple of 8, "
-                    "so the Pallas kernel cannot tile it", stacklevel=3)
-                return False
-            return True
-        use, origin = kernel == "pallas", "applier_kernel=pallas"
-    if use and tile_docs % 8:
+    kernel = kernel if kernel is not None else cfg.applier_kernel
+    if kernel not in ("auto", "pallas", "xla"):
         raise ValueError(
-            f"{origin} requires docs-per-shard % 8 == 0 (got {tile_docs})")
-    return use
+            f"applier_kernel={kernel!r}: expected auto|pallas|xla")
+    if kernel == "auto":
+        if jax.default_backend() != "tpu":
+            return False
+        if tile_docs % 8:
+            warnings.warn(
+                f"applier_kernel=auto on a TPU picks the XLA scan: "
+                f"docs-per-shard {tile_docs} is not a multiple of 8, "
+                "so the Pallas kernel cannot tile it", stacklevel=3)
+            return False
+        return True
+    if kernel == "pallas" and tile_docs % 8:
+        raise ValueError(
+            "applier_kernel=pallas requires docs-per-shard % 8 == 0 "
+            f"(got {tile_docs})")
+    return kernel == "pallas"
 
 
 def _count_trace(kernel: str, shape: str) -> None:
@@ -393,12 +385,8 @@ class TpuDocumentApplier:
         ops_per_dispatch: Optional[int] = None,
         mesh=None,
         overflow_check_every: Optional[int] = None,
-        async_dispatch: bool = False,
-        min_wave_ops: Optional[int] = None,
-        use_pallas: Optional[bool] = None,
         pallas_interpret: bool = False,
         kernel: Optional[str] = None,
-        overlap: Optional[bool] = None,
         long_docs: Optional[int] = None,
         long_slots: Optional[int] = None,
     ):
@@ -417,8 +405,6 @@ class TpuDocumentApplier:
         overflow_check_every = (
             overflow_check_every if overflow_check_every is not None
             else _CFG.applier_overflow_check_every)
-        min_wave_ops = (min_wave_ops if min_wave_ops is not None
-                        else _CFG.applier_min_wave_ops)
         self.max_docs = max_docs
         self.max_slots = max_slots
         self.K = ops_per_dispatch
@@ -489,8 +475,6 @@ class TpuDocumentApplier:
         # copying — _rotate_stage_buffers fences a set's previous
         # transfers before handing it out again, so async H2D and state
         # donation stay sound even when the backend copies lazily.
-        self._overlap = (overlap if overlap is not None
-                         else _CFG.applier_overlap)
         self._stage_pool: tuple = ({}, {})
         self._stage_inflight: list = [None, None]
         self._stage_flip = 0
@@ -512,7 +496,7 @@ class TpuDocumentApplier:
         # contract-kernel selection: auto = Pallas on real TPU, XLA scan
         # elsewhere; dense lane's tile is max_docs (= slots_per_shard of
         # the 1-shard placement), mesh lane's is slots-per-shard
-        use_pallas = _resolve_kernel(kernel, use_pallas, _CFG,
+        use_pallas = _resolve_kernel(kernel, _CFG,
                                      self.placement.slots_per_shard)
         self.kernel_lane = "pallas" if use_pallas else "xla"
         if mesh is not None:
@@ -523,7 +507,7 @@ class TpuDocumentApplier:
             # the mesh twin of _dense_step_for: same int16 packed wave,
             # unpacked per shard inside shard_map, state donated, stats
             # psum'd — the dispatch path below is otherwise identical to
-            # the local dense lane (async worker, min-wave, force_wide)
+            # the local dense lane (force_wide included)
             self._sharded_step = make_sharded_packed_step(
                 mesh, use_pallas=use_pallas,
                 pallas_interpret=pallas_interpret,
@@ -545,11 +529,6 @@ class TpuDocumentApplier:
             # INACTIVE shards (no host alloc, no transfer)
             self._zero_shards: dict = {}
         else:
-            from ..parallel.sharded_apply import donation_supported
-
-            self._step = jax.jit(
-                self._local_step,
-                donate_argnums=(0,) if donation_supported() else ())
             # dense dispatch: ship the padded [D, K, F] wave packed to
             # int16 deltas (see _dense_step_for for the wire format and
             # why device-side scatter lost)
@@ -576,39 +555,6 @@ class TpuDocumentApplier:
         # verify the feed resumed without skipping any (see restore_gap)
         self._restore_applied: dict[int, int] = {}
         self._post_restore_first: dict[int, int] = {}
-        # async mode: a worker thread owns wave building + host→device
-        # transfer + dispatch, so transfer latency never blocks the
-        # ordering pipeline — the applier becomes a real pipeline stage
-        # the way the reference's scribe/scriptorium are separate
-        # consumers of the sequenced topic. The worker is the ONLY state mutator; the
-        # main thread stages tuples under the lock and escalates at sync
-        # points (worker defers overflow escalation to `_overflow_slots`).
-        self._async = async_dispatch
-        # below this many staged ops the worker holds off dispatching
-        # (unless draining): the K-step scan costs the same whether waves
-        # are full or nearly empty, and each distinct dense-bucket shape
-        # costs a compile — steady waves at one size keep both amortized
-        self._min_wave = min_wave_ops
-        self._draining = False
-        if async_dispatch:
-            import threading
-
-            self._lock = threading.Lock()
-            self._wake = threading.Event()
-            self._idle = threading.Event()
-            self._idle.set()
-            self._stop = False
-            self._overflow_slots: set[int] = set()
-            self._worker = threading.Thread(
-                target=self._worker_loop, daemon=True, name="tpu-applier")
-            self._worker.start()
-
-    @staticmethod
-    def _local_step(state: DocState, ops: jax.Array):
-        _count_trace("local_step", "x".join(map(str, ops.shape[:2])))
-        state = apply_ops_batch(state, ops)
-        state = compact_batch(state, wave_min_seq(ops))
-        return state, {}
 
     # ---------------------------------------------------------- long tier
     #
@@ -739,8 +685,6 @@ class TpuDocumentApplier:
         with span("applier.long_dispatch"):
             self.long_state = self._long_step(self.long_state, wave)
             self._long_marker = self.long_state.count
-            if not self._overlap:
-                jax.block_until_ready(self._long_marker)
         self.long_dispatches += 1
         self._long_since_check += 1
         self._dispatches_since_check += 1
@@ -940,27 +884,16 @@ class TpuDocumentApplier:
 
     def _push_chunk(self, slot: int, chunk: np.ndarray) -> None:
         """Append a staged [n, OP_FIELDS] chunk (the ONLY staging-count
-        mutation point besides _take_wave_locked/_drop_staged)."""
-        if self._async:
-            with self._lock:
-                self._staged.setdefault(slot, []).append(chunk)
-                self._staged_ops += len(chunk)
-        else:
-            self._staged.setdefault(slot, []).append(chunk)
-            self._staged_ops += len(chunk)
+        mutation point besides _take_wave/_drop_staged)."""
+        self._staged.setdefault(slot, []).append(chunk)
+        self._staged_ops += len(chunk)
 
     def _drop_staged(self, slot: int) -> None:
         """Discard a slot's staged chunks (escalation path), keeping the
         staged-op count consistent."""
-        if self._async:
-            with self._lock:
-                dropped = self._staged.pop(slot, None)
-                if dropped:
-                    self._staged_ops -= sum(len(c) for c in dropped)
-        else:
-            dropped = self._staged.pop(slot, None)
-            if dropped:
-                self._staged_ops -= sum(len(c) for c in dropped)
+        dropped = self._staged.pop(slot, None)
+        if dropped:
+            self._staged_ops -= sum(len(c) for c in dropped)
 
     def _stage_op(self, staged, arena, w, seq, ref, client, msn) -> bool:
         """Append a wire op's device tuples (ops/apply field order).
@@ -1020,34 +953,23 @@ class TpuDocumentApplier:
     # -------------------------------------------------------------- flush
 
     def flush(self) -> int:
-        """Dispatch all staged ops to the device in [D, K] waves.
-
-        In async mode this just wakes the worker (non-blocking); in sync
-        mode it dispatches inline. Either way device execution is only
-        fenced by the periodic overflow poll (every
-        ``overflow_check_every`` dispatches) or by ``finalize()``/queries.
-        """
-        if self._async:
-            self._wake.set()
-            return 0
-        return self._flush_sync()
-
-    def _flush_sync(self) -> int:
+        """Dispatch all staged ops to the device in [D, K] waves, inline.
+        Device execution is only fenced by the periodic overflow poll
+        (every ``overflow_check_every`` dispatches) or by
+        ``finalize()``/queries."""
         total = 0
         while self._staged:
             # one dispatch path for both lanes: _dispatch_wave routes the
             # packed wave to the local dense step or the mesh's sharded
             # step (per-shard staging + pre-partitioned transfer)
-            total += self._dispatch_wave(self._take_wave_locked())
+            total += self._dispatch_wave(self._take_wave())
         self.ops_applied += total
         if self._dispatches_since_check >= self.overflow_check_every:
             self._check_overflow()
         return total
 
-    # ------------------------------------------------------ async worker
-
-    def _take_wave_locked(self):
-        """Pop up to K staged op ROWS per doc (caller holds the lock).
+    def _take_wave(self):
+        """Pop up to K staged op ROWS per doc.
 
         Returns [(slot, chunk_list, row_count)]; an overflowing chunk is
         split by an array view, so staging order is preserved."""
@@ -1083,12 +1005,11 @@ class TpuDocumentApplier:
         return parts
 
     def _dispatch_wave(self, parts) -> int:
-        """Stage then execute one wave — the serialized entry point.
-        The pipelining callers (_flush_sync, _worker_loop) go through the
-        same pair; overlap comes from the execute half being an async
-        dispatch, so the NEXT iteration's stage half runs on the host
-        while this wave executes on device. With the long tier, the
-        wave's long docs go to the tier's own wave after the farm's."""
+        """Stage then execute one wave. Overlap comes from the execute
+        half being an async dispatch, so flush()'s NEXT iteration's stage
+        half runs on the host while this wave executes on device. With
+        the long tier, the wave's long docs go to the tier's own wave
+        after the farm's."""
         long_parts = None
         if self.long_state is not None and parts:
             parts, long_parts = self._route_parts(parts)
@@ -1160,7 +1081,7 @@ class TpuDocumentApplier:
         One vectorized fancy-index write places every occupied row; the
         flat rows build as ONE ``np.array`` over the concatenated tuple
         list (per-doc conversions were the dominant host cost at high doc
-        counts). ``_take_wave_locked`` caps each doc at K ops, so a wave
+        counts). ``_take_wave`` caps each doc at K ops, so a wave
         always fits. In mesh mode the scatter targets compact per-shard
         buffers for ACTIVE shards only (_stage_wave_mesh) — never an
         O(max_docs) dense host array."""
@@ -1257,10 +1178,8 @@ class TpuDocumentApplier:
 
     def _execute_wave(self, staged: _StagedWave) -> int:
         """The DEVICE half: one jitted-step dispatch on already-resident
-        buffers. With overlap on the dispatch is asynchronous — the
-        caller's next stage half runs while the device executes; overlap
-        off blocks until the step completes (the serialized pre-overlap
-        behavior, kept for A/B)."""
+        buffers. The dispatch is asynchronous — the caller's next stage
+        half runs while the device executes."""
         t0 = time.perf_counter()
         packed_fn, wide_fn = (self._sharded_step if staged.lane == "mesh"
                               else self._dense_step)
@@ -1272,8 +1191,6 @@ class TpuDocumentApplier:
         # execution completes — record its marker against the buffer set
         # the wave staged from, for _rotate_stage_buffers to fence on
         self._stage_inflight[staged.flip] = self._exec_marker
-        if not self._overlap:
-            jax.block_until_ready(self._exec_marker)
         dt = time.perf_counter() - t0
         self.dispatch_seconds += dt
         self._metrics().inc("applier.dispatch.seconds", dt,
@@ -1381,44 +1298,6 @@ class TpuDocumentApplier:
         return jax.make_array_from_single_device_arrays(
             (self.max_docs,) + tail, self._mesh_sharding, arrays)
 
-    def _worker_loop(self) -> None:
-        import time as _time
-
-        while True:
-            self._wake.wait()
-            if self._stop:
-                return
-            with self._lock:
-                if not self._draining and self._min_wave \
-                        and self._staged_ops < self._min_wave:
-                    parts = None
-                else:
-                    parts = self._take_wave_locked()
-                if parts is None:
-                    self._wake.clear()
-                    self._idle.set()
-                    continue
-                self._idle.clear()
-            n = self._dispatch_wave(parts)
-            with self._lock:
-                self.ops_applied += n
-            if self._dispatches_since_check >= self.overflow_check_every:
-                # poll from the worker (it owns the device stream); defer
-                # the actual escalation replay to the main thread's sync
-                self._dispatches_since_check = 0
-                flags = np.asarray(self.state.overflow)
-                hit = set(int(s) for s in np.nonzero(flags)[0])
-                if hit:
-                    with self._lock:
-                        self._overflow_slots |= hit
-            _time.sleep(0)  # yield to the staging thread
-
-    def close(self) -> None:
-        if self._async:
-            self._stop = True
-            self._wake.set()
-            self._worker.join(timeout=5)
-
     def finalize(self) -> None:
         """Flush staged ops and fence the device: after this, every doc's
         state (or its host escalation) reflects everything ingested."""
@@ -1426,30 +1305,7 @@ class TpuDocumentApplier:
             self._finalize()
 
     def _finalize(self) -> None:
-        if self._async:
-            import time as _time
-
-            self._draining = True
-            try:
-                while True:
-                    self._wake.set()
-                    with self._lock:
-                        empty = not self._staged
-                    if empty and self._idle.is_set():
-                        break
-                    _time.sleep(0.0005)
-            finally:
-                self._draining = False
-            with self._lock:
-                pending = sorted(self._overflow_slots)
-                self._overflow_slots.clear()
-            for slot in pending:
-                if slot not in self._host_docs:
-                    self._escalate(slot, None, None)
-            self._drain_device()
-            self._check_overflow()
-            return
-        self._flush_sync()
+        self.flush()
         self._drain_device()
         if self._dispatches_since_check:
             self._check_overflow()
@@ -1499,9 +1355,6 @@ class TpuDocumentApplier:
 
     def _sync(self, slot: int) -> None:
         """Flush + overflow-check before exposing a doc's state."""
-        if self._async:
-            self.finalize()
-            return
         if self._staged.get(slot):
             self.flush()
         self._drain_device()

@@ -2,7 +2,7 @@
 
 The chip belongs to one process at a time, and JAX carries on on the CPU
 when no TPU backend comes up. So every entry point meant to hold the
-chip — the applier stage, ``chip_smoke.py``, ``bench.py`` and
+chip — the applier stage, ``chip_smoke.py`` and
 ``tools/profile_applier.py`` — calls :func:`require_tpu` and
 :func:`init_compile_cache` at the start of its ``main()``. Nothing calls
 them at import time, and tests never do: a CPU run asks for the CPU with

@@ -1,6 +1,6 @@
 """Seeded LOCK-ORDER inversions: a journal-holding path acquires the
-epoch-table flock (rank 0 after rank 3), and an applier-holding path
-calls into an epoch-table holder."""
+epoch-table flock (rank 0 after rank 2), and a partition-claim-holding
+path calls into an epoch-table holder."""
 
 from .aff import holds_lock
 
@@ -11,7 +11,7 @@ def _flock(path):
 
 @holds_lock("journal_lock")
 def flush_entry(path):
-    with _flock(path):  # SEEDED VIOLATION: rank-0 lock after rank-3
+    with _flock(path):  # SEEDED VIOLATION: rank-0 lock after rank-2
         return 1
 
 
@@ -20,6 +20,6 @@ def record_claim(rec):
     return rec
 
 
-@holds_lock("applier_lock")
+@holds_lock("partition_claim_flock")
 def drain_and_record():
     return record_claim({})  # SEEDED VIOLATION: callee takes rank 0

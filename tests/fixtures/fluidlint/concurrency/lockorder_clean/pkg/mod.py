@@ -12,6 +12,6 @@ def claim_then_drain(path):
         return drain()
 
 
-@holds_lock("applier_lock")
-def drain():  # ...then rank 2: ordered
+@holds_lock("partition_claim_flock")
+def drain():  # ...then rank 1: ordered
     return 1

@@ -108,12 +108,13 @@ def test_lock_order_inversions_detected():
     assert len(msgs) == 2, msgs
     joined = "\n".join(msgs)
     # lexical inversion: @holds_lock('journal_lock') body takes the
-    # epoch-table flock (rank 0 after rank 3) — the seeded flock case
+    # epoch-table flock (rank 0 after rank 2) — the seeded flock case
     assert ("mod.flush_entry acquires 'epoch_table_flock' while "
             "holding 'journal_lock'") in joined
-    # call-edge inversion: applier holder calls an epoch-table holder
+    # call-edge inversion: partition-claim holder calls an epoch-table
+    # holder
     assert ("mod.drain_and_record acquires 'epoch_table_flock' while "
-            "holding 'applier_lock'") in joined
+            "holding 'partition_claim_flock'") in joined
     # the message teaches the global order
     assert " -> ".join(LOCK_ORDER) in joined
 

@@ -8,7 +8,7 @@ ops run on the device path (one per-key LWW table write per op), not via
 host escalation.
 
 Runs on CPU (conftest pins JAX_PLATFORMS=cpu); the same jitted code runs on
-TPU in bench.py.
+TPU in the applier stage.
 """
 
 from __future__ import annotations

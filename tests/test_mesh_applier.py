@@ -130,24 +130,6 @@ def test_mesh_force_wide_lane_matches(soup):
     assert applier.mesh_waves == applier.dispatches > 0
 
 
-@pytest.mark.parametrize("seed", SEEDS)
-def test_mesh_async_min_wave_matches(soup, seed):
-    """Async + min-wave parity: the mesh path rides the same worker
-    thread and min_wave_ops hold-off as the local path."""
-    server, texts = soup[seed]
-    applier = TpuDocumentApplier(max_docs=16, max_slots=256,
-                                 ops_per_dispatch=8,
-                                 mesh=make_mesh(4, seg_shards=1),
-                                 async_dispatch=True, min_wave_ops=16)
-    try:
-        _feed_all(applier, server)
-        for d in DOCS:
-            assert applier.get_text("t", d) == texts[d], (seed, d)
-        assert applier.host_escalations == 0
-    finally:
-        applier.close()
-
-
 def test_mesh_staging_bytes_scale_with_active_shards(soup):
     """The tentpole's O(max_docs) → O(active shards) claim, counter-
     asserted: one active doc stages exactly one shard's compact buffers

@@ -3,8 +3,10 @@ parity on fuzzed op streams (and through the existing kernel-vs-oracle
 suite, parity with the scalar merge-tree).
 
 Runs in interpreter mode on the CPU test mesh; the TPU path compiles the
-real Mosaic kernel (exercised by bench/driver runs).
+real Mosaic kernel (exercised by the benchmark's cells on the chip).
 """
+
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +68,7 @@ def test_pallas_flags_overflow_identically():
 
 
 def test_applier_with_pallas_dense_step_matches_live_clients():
-    """The live TpuDocumentApplier with use_pallas rides the same
+    """The live TpuDocumentApplier with kernel=pallas rides the same
     sequenced stream as real clients and converges identically
     (interpret mode on the CPU test mesh)."""
     from fluidframework_tpu.driver import LocalDocumentServiceFactory
@@ -91,7 +93,7 @@ def test_applier_with_pallas_dense_step_matches_live_clients():
     assert s1.get_text() == s2.get_text()
 
     applier = TpuDocumentApplier(max_docs=8, max_slots=64,
-                                 ops_per_dispatch=8, use_pallas=True,
+                                 ops_per_dispatch=8, kernel="pallas",
                                  pallas_interpret=True)
     applier.set_replay_source(lambda t, d: [])
     for m in channel_stream(server, "t", "pdoc", "default", "text"):
@@ -101,7 +103,7 @@ def test_applier_with_pallas_dense_step_matches_live_clients():
     assert applier.get_text("t", "pdoc") == s1.get_text()
 
 
-# ------------------------------------------- kernel / overlap matrix
+# -------------------------------------------- kernel selection matrix
 
 SEEDS = (0, 7, 42)
 
@@ -184,11 +186,11 @@ def test_applier_kernel_matrix_matches_oracle(sessions, kernel, seed):
 
 @pytest.mark.parametrize("shards", [0, 2, 4, 8])
 @pytest.mark.parametrize("seed", SEEDS)
-def test_overlap_on_off_equivalence(sessions, shards, seed):
+def test_pipelined_applier_matches_oracle(sessions, shards, seed):
     """The overlap-staged pipeline (wave N+1 stages on the host while
-    wave N executes on device) must be a pure perf change: overlap on
-    and off converge identically, locally and across 2/4/8-shard
-    meshes, with strict wave order preserved through finalize."""
+    wave N executes on device) converges to the scalar oracle, locally
+    and across 2/4/8-shard meshes, with strict wave order preserved
+    through finalize."""
     server, want = sessions[seed]
     kw = {}
     if shards:
@@ -196,13 +198,43 @@ def test_overlap_on_off_equivalence(sessions, shards, seed):
 
         kw["mesh"] = make_mesh(shards, seg_shards=1)
     doc = f"mx{seed}"
-    on = _drive_applier(server, doc, overlap=True, **kw)
-    off = _drive_applier(server, doc, overlap=False, **kw)
-    assert on.get_text("t", doc) == off.get_text("t", doc) == want
-    # both lanes really dispatched through the stage/execute split and
-    # fed the per-lane stage accounting (the dense lane used to report
-    # zero staging cost — the asymmetry this PR fixes)
-    for applier in (on, off):
-        assert applier.waves_staged == applier.dispatches > 0
-        assert applier.stage_seconds > 0
-        assert applier.stage_bytes > 0
+    applier = _drive_applier(server, doc, **kw)
+    assert applier.get_text("t", doc) == want
+    # every wave went through the stage/execute split and fed the
+    # per-lane stage accounting, on the dense lane as on the mesh
+    assert applier.waves_staged == applier.dispatches > 0
+    assert applier.stage_seconds > 0
+    assert applier.stage_bytes > 0
+
+
+@pytest.mark.parametrize("kernel, backend, tile, want", [
+    ("auto", "cpu", 8, False),
+    ("auto", "tpu", 8, True),
+    ("auto", "tpu", 12, "warn"),
+    ("xla", "tpu", 8, False),
+    ("pallas", "cpu", 16, True),
+    ("pallas", "tpu", 12, ValueError),
+    ("bogus", "cpu", 8, ValueError),
+    (None, "tpu", 8, True),
+])
+def test_resolve_kernel(monkeypatch, kernel, backend, tile, want):
+    """``kernel`` (else config ``applier_kernel``, "auto" by default)
+    resolves to the Pallas lane: auto takes it on a TPU when the
+    docs-per-shard tile is a multiple of 8 and otherwise warns and takes
+    the XLA scan; a forced pallas on a bad tile and an unknown name
+    raise."""
+    from fluidframework_tpu.config import Config
+    from fluidframework_tpu.service.tpu_applier import _resolve_kernel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = Config()
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            _resolve_kernel(kernel, cfg, tile)
+    elif want == "warn":
+        with pytest.warns(UserWarning, match="picks the XLA scan"):
+            assert _resolve_kernel(kernel, cfg, tile) is False
+    else:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert _resolve_kernel(kernel, cfg, tile) is want

@@ -185,22 +185,9 @@ def test_storage_and_backfill_rpcs_require_tokens_too():
         proc.wait(timeout=10)
 
 
-def test_config_bool_env_parses_spellings(monkeypatch):
-    """ADVICE r3: FLUID_TPU_APPLIER_USE_PALLAS=0 must DISABLE, not
-    silently enable via bool('0') is True."""
-    for raw, want in [("0", False), ("false", False), ("no", False),
-                      ("off", False), ("1", True), ("true", True),
-                      ("YES", True), ("On", True)]:
-        monkeypatch.setenv("FLUID_TPU_APPLIER_USE_PALLAS", raw)
-        assert Config.from_env().applier_use_pallas is want, raw
-    monkeypatch.setenv("FLUID_TPU_APPLIER_USE_PALLAS", "maybe")
-    with pytest.raises(ValueError):
-        Config.from_env()
-
-
 def test_config_empty_env_value_keeps_default(monkeypatch):
-    monkeypatch.setenv("FLUID_TPU_APPLIER_USE_PALLAS", "")
-    # the default is None (defer to applier_kernel); empty env keeps it
-    assert Config.from_env().applier_use_pallas is None
+    monkeypatch.setenv("FLUID_TPU_APPLIER_KERNEL", "")
+    # set-but-empty means unset: the layered default stays
+    assert Config.from_env().applier_kernel == "auto"
     monkeypatch.setenv("FLUID_TPU_CLIENT_TIMEOUT_S", "")
     assert Config.from_env().client_timeout_s == Config().client_timeout_s

@@ -37,9 +37,11 @@ split::
                 "staging_ms_per_wave": ..., "staged_bytes_per_wave": ...},
                ...],
      "local_dense_ops_per_sec": ..., "mesh_vs_local_1shard": ...,
-     "local_dense_ab": {"n_docs": D, "on": {...}, "off": {...},
-                        "improvement": ..., "improvement_basis": ...},
+     "local_dense_ab": null,
      "ok": true, "rc": 0}
+
+``local_dense_ab`` was the dense lane's overlap on/off A/B; the off
+switch is gone, so new runs emit null and r07 keeps its measured arms.
 
 ``ops_per_sec`` stays wall-clock and ``scaling_efficiency`` is computed
 on it (``efficiency_basis: "wall"`` — the number that cannot lie).
@@ -214,24 +216,6 @@ def run_sweep() -> dict:
     docs1 = [f"d{i}" for i in range(DOCS_PER_SHARD)]
     local_opsps = _time_applier(local, docs1, K)["ops_per_sec"]
 
-    # dense-lane A/B at the 4-doc-axis rung's doc count: overlap on vs
-    # off over the identical pipelined workload. The design's effect
-    # lives on the host critical path (improvement_basis), wall is
-    # reported alongside — on a single-core host the two arms do the
-    # same total work, so wall improvement there is bounded by the
-    # sync-call overhead the off arm pays.
-    ab_docs = DOCS_PER_SHARD * 4
-    ab = {}
-    for arm, overlap in (("on", True), ("off", False)):
-        applier = TpuDocumentApplier(max_docs=ab_docs, max_slots=64,
-                                     ops_per_dispatch=K, overlap=overlap)
-        ab[arm] = _time_applier(applier,
-                                [f"d{i}" for i in range(ab_docs)], K)
-
-    def _ratio(key):
-        on, off = ab["on"][key], ab["off"][key]
-        return round(on / off, 3) if on and off else None
-
     host_limited_note = (
         "forced host-platform devices time-slice one core: wall "
         "throughput cannot rise with the docs axis, and the CPU runtime "
@@ -253,11 +237,7 @@ def run_sweep() -> dict:
         "local_dense_ops_per_sec": local_opsps,
         "mesh_vs_local_1shard": round(rungs[0]["ops_per_sec"] / local_opsps,
                                       3),
-        "local_dense_ab": {"n_docs": ab_docs, "on": ab["on"],
-                           "off": ab["off"],
-                           "improvement": _ratio("pipeline_ops_per_sec"),
-                           "improvement_basis": "host_pipeline",
-                           "improvement_wall": _ratio("ops_per_sec")},
+        "local_dense_ab": None,
         "ok": True,
         "rc": 0,
     }

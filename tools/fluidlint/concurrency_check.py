@@ -130,7 +130,6 @@ CLASS_CALL_LOCKS = {("PlacementDir", "_lock"): "partition_claim_flock"}
 #: `with self.<attr>:` per class -> registered lock.
 CLASS_ATTR_LOCKS = {
     ("Journal", "_lock"): "journal_lock",
-    ("TpuDocumentApplier", "_lock"): "applier_lock",
 }
 
 _AFFINITY_DECOS = ("loop_only", "ticker_thread", "any_thread",

@@ -110,7 +110,7 @@ def check_hygiene(repo_root: Optional[str] = None,
         silent = r in import_silent_roots
         for path in _py_files(root):
             out.extend(check_file(path, repo_root, import_silent=silent))
-    # top-level scripts (bench.py, __graft_entry__.py, ...)
+    # top-level scripts (chip_smoke.py, __graft_entry__.py, ...)
     for fn in sorted(os.listdir(repo_root)):
         if fn.endswith(".py"):
             out.extend(check_file(os.path.join(repo_root, fn), repo_root))

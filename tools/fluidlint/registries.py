@@ -316,7 +316,6 @@ FT_CODECS = {
 LOCK_ORDER = (
     "epoch_table_flock",      # placement_plane._flock(table.lock)
     "partition_claim_flock",  # placement.PlacementDir._lock(k)
-    "applier_lock",           # tpu_applier.TpuDocumentApplier._lock
     "journal_lock",           # obs.journal.Journal._lock
 )
 
@@ -326,8 +325,6 @@ LOCK_DOC = {
                          "(service/placement_plane.py _flock)",
     "partition_claim_flock": "per-partition lease files "
                              "(service/placement.py PlacementDir._lock)",
-    "applier_lock": "the applier's staging double-buffer "
-                    "(service/tpu_applier.py, worker vs ingest)",
     "journal_lock": "the audit journal's append stream "
                     "(obs/journal.py Journal._lock)",
 }

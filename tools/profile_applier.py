@@ -15,11 +15,12 @@ rig. Usage:  python tools/profile_applier.py [--docs D] [--k K]
 The applier also accounts its own halves per lane —
 ``applier.stage.seconds`` (host wave assembly + transfer, with the
 hidden-behind-execute fraction) vs ``applier.dispatch.seconds`` (the
-step's enqueue, not its device time) — so a throughput swing is attributable from its counters: a
-stage swing is host/link time, a dispatch swing is the enqueue (or,
-with overlap off, device time) or a kernel change. This profile prints that split below (``stage/execute split``)
-for the dense lane and, when the machine has several devices, the mesh
-lane; the manual pack/h2d/step rows remain the finer microscope.
+step's enqueue, not its device time) — so a throughput swing is
+attributable from its counters: a stage swing is host/link time, a
+dispatch swing is the enqueue or a kernel change. This profile prints
+that split below (``stage/execute split``) for the dense lane and, when
+the machine has several devices, the mesh lane; the manual
+pack/h2d/step rows remain the finer microscope.
 """
 
 from __future__ import annotations
@@ -61,8 +62,7 @@ def main() -> None:
     D, K, T = args.docs, args.k, args.trials
     print(f"device: {dev.platform} {getattr(dev, 'device_kind', '?')}")
 
-    app = TpuDocumentApplier(max_docs=D, ops_per_dispatch=K,
-                             async_dispatch=False)
+    app = TpuDocumentApplier(max_docs=D, ops_per_dispatch=K)
     # register docs + seed a little text so applies do real work
     for d in range(D):
         app.slot_of("t", f"doc{d}")
@@ -82,7 +82,7 @@ def main() -> None:
     # stage/execute rows below report steady-state waves, not the
     # compile wave
     stage_full_wave(2)
-    app._flush_sync()
+    app.flush()
     app._sync(0)
     _reset_split(app)
 
@@ -92,14 +92,14 @@ def main() -> None:
     t0 = time.perf_counter()
     for t in range(T):
         stage_full_wave(2 + (t + 1) * K)
-        app._flush_sync()
+        app.flush()
     jax.block_until_ready(app.state.length)
     e2e = (time.perf_counter() - t0) / T
 
     # ---- pack only: _dispatch_wave minus the device calls ----
     # re-measure by timing the numpy assembly on a staged wave
     stage_full_wave(2 + (T + 1) * K)
-    parts = app._take_wave_locked()  # sync mode: no worker, no lock
+    parts = app._take_wave()
     all_chunks, slots, lens = [], [], []
     for slot, chunks, count in parts:
         if count:
@@ -173,7 +173,7 @@ def main() -> None:
 
         n_sh = len(jax.devices())
         mesh_app = TpuDocumentApplier(
-            max_docs=D, ops_per_dispatch=K, async_dispatch=False,
+            max_docs=D, ops_per_dispatch=K,
             mesh=make_mesh(n_sh, seg_shards=1))
         for d in range(D):
             mesh_app.slot_of("t", f"doc{d}")
@@ -190,11 +190,11 @@ def main() -> None:
                 mesh_app._push_chunk(d, rows)
             if not warmed:
                 # first wave compiles; keep it out of the split rows
-                mesh_app._flush_sync()
+                mesh_app.flush()
                 jax.block_until_ready(mesh_app.state.length)
                 _reset_split(mesh_app)
                 warmed = True
-        mesh_app._flush_sync()
+        mesh_app.flush()
         jax.block_until_ready(mesh_app.state.length)
         split_row("mesh", mesh_app)
 
