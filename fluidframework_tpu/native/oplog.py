@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import ctypes
 
+import numpy as np
+
 from .build import load_library
 
 
@@ -57,6 +59,13 @@ class NativeOpLog:
         self._lib.oplog_seg_refresh.restype = ctypes.c_int64
         self._lib.oplog_seg_refresh.argtypes = [
             ctypes.c_void_p, ctypes.c_char_p]
+        self._lib.oplog_seg_refresh_many.restype = ctypes.c_int
+        self._lib.oplog_seg_refresh_many.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_void_p]
+        self._lib.oplog_seg_read_many.restype = ctypes.c_int64
+        self._lib.oplog_seg_read_many.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p]
         self._lib.oplog_seg_tear.restype = ctypes.c_int
         self._lib.oplog_seg_tear.argtypes = [
             ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
@@ -66,6 +75,7 @@ class NativeOpLog:
         self._lib.oplog_open_files.restype = ctypes.c_int64
         self._lib.oplog_open_files.argtypes = [ctypes.c_void_p]
         self.readonly = readonly
+        self._read_buf = None  # seg_read_many's, made at its first call
         # topic-name encode cache: append/length/read run per record on
         # the durable hot path; str.encode is measurable there
         self._names: dict[str, bytes] = {}
@@ -173,6 +183,40 @@ class NativeOpLog:
         """Tail blocks another process appended; refreshed block count."""
         n = self._lib.oplog_seg_refresh(self._handle, self._name(stream))
         return 0 if n < 0 else n
+
+    def seg_refresh_many(self, names: bytes, n: int) -> np.ndarray:
+        """:meth:`seg_refresh` over ``n`` streams in one native call;
+        ``names`` holds their names, each ended by a NUL byte. Returns
+        the block counts as an int64 array."""
+        if names.count(0) != n:
+            raise ValueError(f"{n} stream names expected")
+        counts = np.empty(n, np.int64)
+        if self._lib.oplog_seg_refresh_many(
+                self._handle, names, n, counts.ctypes.data) != 0:
+            raise OSError("segment refresh failed")
+        return counts
+
+    def seg_read_many(self, stream: str, first: int,
+                      count: int) -> tuple[bytes, list, list]:
+        """Blocks ``first`` onward, at most ``count`` of them and as many
+        as fit in the read buffer (always at least one): ``(data, lens,
+        btypes)``, the payloads back to back in ``data``."""
+        lens = np.empty(count, np.int64)
+        btypes = np.empty(count, np.int64)
+        if self._read_buf is None:
+            self._read_buf = ctypes.create_string_buffer(1 << 20)
+        while True:
+            buf = self._read_buf
+            k = self._lib.oplog_seg_read_many(
+                self._handle, self._name(stream), first, count, buf,
+                len(buf), lens.ctypes.data, btypes.ctypes.data)
+            if k < 0:
+                raise IndexError(f"no blocks {first}+{count} in {stream!r}")
+            if k > 0 or count == 0:
+                break
+            self._read_buf = ctypes.create_string_buffer(int(lens[0]))
+        lens, btypes = lens[:k].tolist(), btypes[:k].tolist()
+        return ctypes.string_at(buf, sum(lens)), lens, btypes
 
     def seg_tear(self, stream: str, first_seq: int, last_seq: int,
                  block: bytes, btype: int, mode: int = 0) -> None:

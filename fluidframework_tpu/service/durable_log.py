@@ -29,6 +29,7 @@ as in the reference).
 
 from __future__ import annotations
 
+import itertools
 import os
 import struct
 from dataclasses import replace
@@ -345,16 +346,25 @@ class DurableLog(OrderedLogBase):
         self._san_cache: dict[str, str] = {}
         self._seg_route: dict[str, Optional[str]] = {}
         self._seg_last: dict[str, int] = {}  # highest indexed seq span end
-        # reader LRU: each SegmentReader pins 1 fd per mmap (CPython
-        # dups the fd behind mmap.mmap), so resident readers are fd
-        # budget exactly like native handles — cold ones close and
-        # rebuild on demand (refresh revalidates from the index, no
-        # record decodes)
+        # reader LRU, for writers and random-access reads (backfill, the
+        # cold-boot entry, segment_reader()) only — a read-only handle
+        # tails through the native index (_tail_load). Each
+        # SegmentReader pins 1 fd per mmap (CPython dups the fd behind
+        # mmap.mmap), so resident readers are fd budget exactly like
+        # native handles — cold ones close and rebuild on demand
+        # (refresh revalidates from the index, no record decodes)
         from collections import OrderedDict
         cap = LOG_FD_CAP if fd_cap is None else fd_cap
         self._reader_cap = max(32, cap // 4) if cap else 0
         self._readers: "OrderedDict[str, SegmentReader]" = OrderedDict()
         self._torn_count = 0
+        # a read-only handle's tail: the seg-lane topics of _order with
+        # their packed stream names and the counts the last poll read
+        # (_tail_streams), and per topic the blocks its last native
+        # batch read brought in (_tail_load)
+        self._tail_set: Optional[tuple] = None
+        self._tail_key = -1  # len(_order) when _tail_set was built
+        self._tail: dict[str, _TailBlocks] = {}
 
     # ------------------------------------------------------ topic routing
 
@@ -393,6 +403,7 @@ class DurableLog(OrderedLogBase):
             flush = None if self.readonly else self._log.flush
             r = self._readers[stream] = SegmentReader(
                 self.directory, stream, flush=flush)
+            self.counters.inc("storage.segment.reader_builds")
             while self._reader_cap and len(self._readers) > self._reader_cap:
                 _, cold = self._readers.popitem(last=False)
                 cold.close()
@@ -402,25 +413,59 @@ class DurableLog(OrderedLogBase):
 
     # ---------------------------------------------------------- tailing
 
+    def _reroute(self, topic: str) -> bool:
+        """Move a seg-lane topic whose stream reads no block to the
+        record lane when a record-format ``.idx`` exists: the producer
+        opened a pre-segment directory. Call before anyone reads it."""
+        if not os.path.exists(os.path.join(self.directory,
+                                           self._san(topic) + ".idx")):
+            return False
+        self._seg_route[topic] = None
+        self._tail_key = -1  # the tail set is rebuilt at the next poll
+        return True
+
     def _refresh_one(self, topic: str) -> int:
         stream = self._seg_stream(topic)
         if stream is not None:
             n = self._log.seg_refresh(stream)
-            if n == 0 and os.path.exists(
-                    os.path.join(self.directory, self._san(topic)
-                                 + ".idx")):
-                # the producer turned out to be record-format (it opened
-                # a pre-segment directory): reroute before anyone reads
-                self._seg_route[topic] = None
+            if n == 0 and self._reroute(topic):
                 n = self._log.refresh(self._san(topic))
         else:
             n = self._log.refresh(self._san(topic))
         self._len_cache[topic] = n
         return n
 
+    def _tail_streams(self) -> tuple:
+        """``(seg-lane topics, record-lane topics, packed stream names,
+        last counts)`` over the subscription order, rebuilt only when the
+        order grows or a topic is rerouted; a topic new to the set starts
+        at count -1, so its first poll examines it."""
+        if self._tail_key != len(self._order):
+            t = self._tail_set
+            seen = {} if t is None else dict(zip(t[0], t[3].tolist()))
+            seg, rec = [], []
+            for topic in self._order:
+                (seg if self._seg_stream(topic) is not None
+                 else rec).append(topic)
+            names = b"".join(self._seg_stream(topic).encode() + b"\0"
+                             for topic in seg)
+            last = np.array([seen.get(topic, -1) for topic in seg],
+                            np.int64)
+            self._tail_set = (seg, rec, names, last)
+            self._tail_key = len(self._order)
+        return self._tail_set
+
+    def _lags(self, topic: str, n: int) -> bool:
+        return any(pos[0] < n for _, pos in self._subs.get(topic, ()))
+
     def poll(self) -> bool:
         """Refresh every subscribed topic from disk; mark grown topics
-        dirty. Returns True when drain() has new work."""
+        dirty. Returns True when drain() has new work.
+
+        The seg-lane topics refresh in ONE native call (a stat of each
+        stream's index, entries read only where it grew); Python then
+        visits the grown ones alone, and those still at 0 blocks (the
+        reroute check). Record-lane topics refresh one by one."""
         if self.fault_plane is not None:
             # chaos seam, read side: a consumer process resuming from a
             # stale position (lost position file, conservative restart)
@@ -431,12 +476,32 @@ class DurableLog(OrderedLogBase):
                 for topic in self._order:
                     self.rewind_subscribers(topic, 1)
         grew = False
-        for topic in self._order:
+        seg, rec, names, last = self._tail_streams()
+        if seg:
+            counts = self._log.seg_refresh_many(names, len(seg))
+            grown = counts != last
+            self.counters.inc("storage.tail.streams", len(seg))
+            self.counters.inc("storage.tail.grown",
+                              int(np.count_nonzero(grown)))
+            for i in np.flatnonzero(grown | (counts == 0)).tolist():
+                topic = seg[i]
+                n = int(counts[i])
+                if n == 0 and self._reroute(topic):
+                    n = self._log.refresh(self._san(topic))
+                self._len_cache[topic] = n
+                if self._lags(topic, n):
+                    self._dirty[topic] = None
+                    grew = True
+            last[:] = counts
+        for topic in rec:
             n = self._refresh_one(topic)
-            if any(pos[0] < n for _, pos in self._subs.get(topic, ())):
+            if self._lags(topic, n):
                 self._dirty[topic] = None
                 grew = True
-        return grew
+        # a topic that lags from before this poll (a rewind, a stepped
+        # pause, a handler that raised) is already dirty
+        return grew or any(self._lags(topic, self._stored_length(topic))
+                           for topic in self._dirty)
 
     def list_topics(self, prefix: str = "") -> list[str]:
         """Topics present on disk (desanitized), optionally filtered by
@@ -556,22 +621,57 @@ class DurableLog(OrderedLogBase):
                     boxcar=box, base_seq=base_seq, msns=msns,
                     timestamp=ts)}
 
+    def _block_value(self, topic: str, btype: int, payload: bytes) -> Any:
+        if btype == binwire.SEG_COLS:
+            self.counters.inc("storage.segment.decodes")
+            return self._seg_decode(topic, payload)
+        self.counters.inc("storage.log.legacy_json")
+        return _decode_value(payload)
+
+    def _tail_load(self, topic: str, stream: str, offset: int) -> Any:
+        """A read-only handle's seg-lane read: from the blocks the last
+        native batch read brought in, else one batch read of every block
+        from ``offset`` to the stored length (as many as the buffer
+        holds). The batch's bytes go once its last block is decoded; the
+        last value decoded stays, shared by the topic's subscribers."""
+        t = self._tail.get(topic)
+        if t is not None:
+            if t.memo_offset == offset:
+                return t.memo_value
+            i = offset - t.start
+            if t.data is None or not 0 <= i < len(t.btypes):
+                t = None
+        if t is None:
+            n = self._stored_length(topic)
+            if offset >= n:
+                n = self._len_cache[topic] = self._log.seg_refresh(stream)
+            if not 0 <= offset < n:
+                raise IndexError(f"no block {offset} in {stream!r}")
+            data, lens, btypes = self._log.seg_read_many(
+                stream, offset, n - offset)
+            self.counters.inc("storage.tail.blocks", len(btypes))
+            t = self._tail[topic] = _TailBlocks(offset, data, lens, btypes)
+            i = 0
+        value = self._block_value(
+            topic, t.btypes[i], t.data[t.ends[i]:t.ends[i + 1]])
+        t.memo_offset, t.memo_value = offset, value
+        if i == len(t.btypes) - 1:
+            t.data = None
+        return value
+
     def _load(self, topic: str, offset: int) -> Any:
+        stream = self._seg_stream(topic)
+        if stream is not None and self.readonly:
+            return self._tail_load(topic, stream, offset)
         cached = self._read_cache.get(topic)
         if cached is not None and cached[0] == offset:
             return cached[1]
-        stream = self._seg_stream(topic)
         if stream is not None:
             reader = self.segment_reader(topic)
             if offset >= reader.count:
                 reader.refresh()
             btype, _, _, payload = reader.block(offset)
-            if btype == binwire.SEG_COLS:
-                value = self._seg_decode(topic, payload)
-                self.counters.inc("storage.segment.decodes")
-            else:
-                value = _decode_value(payload)
-                self.counters.inc("storage.log.legacy_json")
+            value = self._block_value(topic, btype, payload)
         else:
             value = _decode_value(self._log.read(self._san(topic), offset))
         self._read_cache[topic] = (offset, value)
@@ -667,4 +767,23 @@ class DurableLog(OrderedLogBase):
         for r in self._readers.values():
             r.close()
         self._readers.clear()
+        self._tail.clear()
         self._log.close()
+
+
+class _TailBlocks:
+    """Blocks ``start``.. of one seg-lane topic as one native batch read
+    them (a read-only handle's tail): payload ``i`` is
+    ``data[ends[i]:ends[i + 1]]``. ``data`` is dropped once the last
+    block is decoded; the last decoded value stays."""
+
+    __slots__ = ("start", "data", "ends", "btypes", "memo_offset",
+                 "memo_value")
+
+    def __init__(self, start: int, data: bytes, lens: list, btypes: list):
+        self.start = start
+        self.data: Optional[bytes] = data
+        self.ends = [0, *itertools.accumulate(lens)]
+        self.btypes = btypes
+        self.memo_offset = -1
+        self.memo_value = None

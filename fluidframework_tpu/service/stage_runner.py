@@ -453,7 +453,8 @@ class ApplierStage(_StageHostBase):
 
     def _serve_trace_request(self) -> None:
         """Start or stop the program's spans (utils/telemetry.py) over
-        :attr:`trace_dir`, with the stage's counters; with
+        :attr:`trace_dir`, with the stage's counters and its shared
+        log's (``storage.tail.*``, ``storage.segment.*``); with
         :attr:`trace_profile` the JAX profiler runs into the same
         directory over the window. A stop prints ``SPANS <path>``. A
         repeated request is a no-op."""
@@ -463,7 +464,8 @@ class ApplierStage(_StageHostBase):
         if req == "start" and not tracing_armed():
             if self.trace_profile:
                 jax.profiler.start_trace(self.trace_dir)
-            tracing_start(self.trace_dir, "stage", counters=(self.counters,))
+            tracing_start(self.trace_dir, "stage",
+                          counters=(self.counters, self.shared.counters))
         elif req == "stop" and tracing_armed():
             path = tracing_stop()
             if self.trace_profile:

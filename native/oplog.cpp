@@ -79,7 +79,9 @@ static_assert(sizeof(SegEntry) == 32, "segidx entry layout is on-disk ABI");
 
 struct SegStream {
     FILE* index = nullptr;
-    FILE* data = nullptr;       // tail segment (writer only)
+    FILE* data = nullptr;       // writer: the tail segment; read-only:
+                                // the segment its reads last opened
+    uint32_t data_seg = 0;      // the segment `data` is open on (read-only)
     uint32_t cur_seg = 0;
     uint64_t cur_off = 0;       // validated byte extent of the tail segment
     std::vector<SegEntry> entries;
@@ -292,12 +294,40 @@ std::string seg_path(OpLog* log, const char* name, uint32_t seg) {
 
 // physical size of segment file <name>.seg<k>, or 0 when absent
 uint64_t seg_file_size(OpLog* log, const char* name, uint32_t seg) {
-    FILE* f = fopen(seg_path(log, name, seg).c_str(), "rb");
-    if (!f) return 0;
-    fseek(f, 0, SEEK_END);
-    uint64_t n = (uint64_t)ftell(f);
-    fclose(f);
-    return n;
+    struct stat st;
+    if (stat(seg_path(log, name, seg).c_str(), &st) != 0) return 0;
+    return (uint64_t)st.st_size;
+}
+
+// byte size of an open file (one fstat), or -1
+int64_t open_file_size(FILE* f) {
+#ifdef _WIN32
+    struct _stat64 st;
+    return _fstat64(_fileno(f), &st) == 0 ? (int64_t)st.st_size : -1;
+#else
+    struct stat st;
+    return fstat(fileno(f), &st) == 0 ? (int64_t)st.st_size : -1;
+#endif
+}
+
+// read exactly `len` bytes at byte `off`, past the FILE's own buffer: a
+// tailing reader's buffer may still hold torn bytes the writer has since
+// cut and rewritten
+bool read_at(FILE* f, void* buf, size_t len, uint64_t off) {
+#ifdef _WIN32
+    return fseek(f, (long)off, SEEK_SET) == 0 &&
+           fread(buf, 1, len, f) == len;
+#else
+    char* p = static_cast<char*>(buf);
+    while (len > 0) {
+        ssize_t got = pread(fileno(f), p, len, (off_t)off);
+        if (got <= 0) return false;
+        p += got;
+        off += (uint64_t)got;
+        len -= (size_t)got;
+    }
+    return true;
+#endif
 }
 
 // reopen an evicted stream's handles, trusting the resident metadata
@@ -385,6 +415,37 @@ SegStream* get_seg(OpLog* log, const char* name) {
     log->open_files += res.first->second.data ? 2 : 1;
     evict_excess(log);
     return &res.first->second;
+}
+
+// Admit index entries another process appended, in order, each only once
+// its block bytes have fully landed in the segment file (a mid-write tail
+// stays invisible until then); returns the admitted count. Nothing is
+// truncated: the tail rule of oplog_seg_refresh and its batch form.
+int64_t seg_tail(OpLog* log, const char* name, SegStream* s) {
+    size_t have = s->entries.size();
+    int64_t index_bytes = open_file_size(s->index);
+    if (index_bytes < 0) return (int64_t)have;
+    size_t disk_n = (size_t)((uint64_t)index_bytes / sizeof(SegEntry));
+    if (disk_n <= have) return (int64_t)have;
+    std::vector<SegEntry> fresh(disk_n - have);
+    if (!read_at(s->index, fresh.data(), fresh.size() * sizeof(SegEntry),
+                 (uint64_t)have * sizeof(SegEntry)))
+        return (int64_t)have;
+    uint32_t sized_seg = 0;
+    uint64_t sized_bytes = 0;
+    bool sized = false;
+    for (const SegEntry& e : fresh) {
+        if (!sized || e.seg != sized_seg) {
+            sized_seg = e.seg;
+            sized_bytes = seg_file_size(log, name, e.seg);
+            sized = true;
+        }
+        if ((uint64_t)e.off + e.len > sized_bytes) break;  // mid-write tail
+        s->entries.push_back(e);
+        s->cur_seg = e.seg;
+        s->cur_off = (uint64_t)e.off + e.len;
+    }
+    return (int64_t)s->entries.size();
 }
 
 // drop in-process knowledge of deliberate torn bytes (oplog_seg_tear) by
@@ -507,7 +568,9 @@ int64_t oplog_seg_count(void* handle, const char* stream) {
 
 // Read block `ordinal`; same contract as oplog_read (returns the needed
 // size when buflen is too small; -1 on bad args / unknown block). Cold
-// path — the hot read path is the Python-side mmap of the segment files.
+// path: a read-only tail reads through oplog_seg_read_many, and writers
+// and random-access reads (backfill) through the Python-side mmap of the
+// segment files (service/segment_store.py).
 int64_t oplog_seg_read(void* handle, const char* stream, int64_t ordinal,
                        void* buf, int64_t buflen) {
     auto* log = static_cast<OpLog*>(handle);
@@ -552,30 +615,74 @@ int64_t oplog_seg_refresh(void* handle, const char* stream) {
     if (!log || !stream) return -1;
     std::lock_guard<std::mutex> lk(log->mu);
     SegStream* s = get_seg(log, stream);
-    if (!s) return -1;
-    fseek(s->index, 0, SEEK_END);
-    uint64_t index_bytes = (uint64_t)ftell(s->index);
-    size_t disk_n = (size_t)(index_bytes / sizeof(SegEntry));
-    size_t have = s->entries.size();
-    if (disk_n <= have) return (int64_t)have;
-    fseek(s->index, (long)(have * sizeof(SegEntry)), SEEK_SET);
-    SegEntry e;
-    uint32_t sized_seg = 0;
-    uint64_t sized_bytes = 0;
-    bool sized = false;
-    while (s->entries.size() < disk_n &&
-           fread(&e, sizeof(e), 1, s->index) == 1) {
-        if (!sized || e.seg != sized_seg) {
-            sized_seg = e.seg;
-            sized_bytes = seg_file_size(log, stream, e.seg);
-            sized = true;
-        }
-        if ((uint64_t)e.off + e.len > sized_bytes) break;  // mid-write tail
-        s->entries.push_back(e);
-        s->cur_seg = e.seg;
-        s->cur_off = (uint64_t)e.off + e.len;
+    return s ? seg_tail(log, stream, s) : -1;
+}
+
+// oplog_seg_refresh over many streams in one call: `names` holds n
+// NUL-terminated stream names back to back, and counts[i] gets stream i's
+// admitted block count (0 while its producer has not created it). One
+// fstat of each index file; entries are read only where it grew. The
+// read-only stage's poll over every subscribed stream.
+int oplog_seg_refresh_many(void* handle, const char* names, int64_t n,
+                           int64_t* counts) {
+    auto* log = static_cast<OpLog*>(handle);
+    if (!log || !names || n < 0 || (n > 0 && !counts)) return -1;
+    std::lock_guard<std::mutex> lk(log->mu);
+    const char* name = names;
+    for (int64_t i = 0; i < n; ++i) {
+        SegStream* s = get_seg(log, name);
+        counts[i] = s ? seg_tail(log, name, s) : 0;
+        name += strlen(name) + 1;
     }
-    return (int64_t)s->entries.size();
+    return 0;
+}
+
+// Copy admitted blocks [from, from + count) back to back into buf, as
+// many whole blocks as fit in buflen, with each one's length and block
+// type in lens / btypes. Returns the number of blocks copied (0, with
+// lens[0] = the first block's length, when even that one does not fit),
+// or -1 on bad args / an unknown block / a writer's handle (writers read
+// through service/segment_store.py's mmaps). The read-only stage's drain
+// reads its tail through this: the handle keeps one segment file of the
+// stream open for it, counted under the fd cap like a writer's tail.
+int64_t oplog_seg_read_many(void* handle, const char* stream, int64_t from,
+                            int64_t count, void* buf, int64_t buflen,
+                            int64_t* lens, int64_t* btypes) {
+    auto* log = static_cast<OpLog*>(handle);
+    if (!log || !log->readonly || !stream || from < 0 || count < 0 ||
+        !buf || buflen < 0 || (count > 0 && (!lens || !btypes)))
+        return -1;
+    std::lock_guard<std::mutex> lk(log->mu);
+    SegStream* s = get_seg(log, stream);
+    if (!s || (uint64_t)(from + count) > s->entries.size()) return -1;
+    char* out = static_cast<char*>(buf);
+    int64_t used = 0, k = 0;
+    bool opened = false, ok = true;
+    for (; k < count; ++k) {
+        const SegEntry& e = s->entries[(size_t)(from + k)];
+        if (used + (int64_t)e.len > buflen) {
+            if (k == 0) lens[0] = (int64_t)e.len;
+            break;
+        }
+        if (!s->data || s->data_seg != e.seg) {
+            if (s->data) {
+                fclose(s->data);
+                s->data = nullptr;
+                log->open_files--;
+            }
+            s->data = fopen(seg_path(log, stream, e.seg).c_str(), "rb");
+            if (!s->data) { ok = false; break; }
+            s->data_seg = e.seg;
+            log->open_files++;
+            opened = true;
+        }
+        if (!read_at(s->data, out + used, e.len, e.off)) { ok = false; break; }
+        lens[k] = (int64_t)e.len;
+        btypes[k] = (int64_t)e.btype;
+        used += (int64_t)e.len;
+    }
+    if (opened) evict_excess(log);
+    return ok ? k : -1;
 }
 
 // Chaos-plane seam: leave a deliberately torn tail on disk, exactly the

@@ -620,6 +620,11 @@ def test_armed_tracing_writes_both_span_files(tmp_path):
     assert stage["counters"]["stage.checkpoint.bytes"] > 0
     assert stage["counters"]["stage.checkpoint.delta_docs"] >= 4
     assert stage["counters"]["stage.records.drained"] > 0
+    # the shared log's tail: its streams refreshed in one native call a
+    # poll, their blocks read in batches, no SegmentReader built
+    assert stage["counters"]["storage.tail.streams"] > 0
+    assert stage["counters"]["storage.tail.blocks"] > 0
+    assert stage["counters"].get("storage.segment.reader_builds", 0) == 0
     assert window_start_ns(str(trace_dir)) is not None
     assert stage["overflow"] == core["overflow"] == 0
 

@@ -43,6 +43,10 @@ STORAGE_METRICS = frozenset({
     "storage.segment.appends",    # segment blocks appended (both lanes' tears re-append)
     "storage.segment.decodes",    # SEG_COLS payloads decoded (backfill must NOT move this)
     "storage.segment.torn",       # chaos torn-tails left + recovered on a segment stream
+    "storage.segment.reader_builds",  # SegmentReaders built (a read-only tail builds none)
+    "storage.tail.streams",       # seg-lane streams a read-only poll refreshed (one native call)
+    "storage.tail.grown",         # of those, streams whose block count changed
+    "storage.tail.blocks",        # blocks a read-only tail read through the batch read
     "storage.backfill.byterange", # raw block payloads served by delta_blocks
     "storage.log.legacy_json",    # deltas-lane records still riding the compat shim
     # snapshot fast-boot plane (the net-smoke catch-up gate keys on these)
