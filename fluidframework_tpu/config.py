@@ -38,6 +38,11 @@ class Config:
     # of two; L = 0 leaves the tier out.
     applier_long_docs: int = 0           # long rows [L]
     applier_long_slots: int = 4096       # segment slots per long row
+    # SharedMatrix grids beside the farm: each grid's row and column
+    # vectors are two farm rows, its cells a [G, H, H] int32 device store
+    # indexed by (row handle, column handle); G = 0 leaves grids out
+    applier_grids: int = 0               # grids [G]
+    applier_grid_handles: int = 1536     # handles per vector [H]
     # contract-kernel selection for the apply step: "auto" picks the
     # Pallas VMEM-resident kernel on real TPU devices and the XLA scan
     # everywhere else (on a TPU it falls back to XLA, with a warning,

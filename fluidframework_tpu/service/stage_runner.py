@@ -25,7 +25,10 @@ Stages:
   idempotent by sequence number).
 - ``applier`` — the TPU device farm (TpuDocumentApplier) out of
   process: the deli/broadcast hot path never shares a GIL with device
-  work. Consumes deltas chanops; after every turn that consumed records
+  work. Consumes deltas chanops of each doc's text channel and, where
+  the applier holds grids, of its SharedMatrix channel once an attach
+  names one (a grid, on the same device); after every turn that
+  consumed records
   it checkpoints the docs that moved (a delta on the farm's base,
   tpu_applier.ApplierCheckpointer) and reports their applied seqs on
   its backchannel as status records.
@@ -53,7 +56,7 @@ from ..utils.telemetry import (sample, span, tracing_armed, tracing_start,
                                tracing_stop)
 from .core import InMemoryDb, summary_versions_collection
 from .durable_log import DurableLog, log_channel_stream
-from .synthetic import chanop_of
+from .synthetic import GRID_CHANNEL, chanop_of
 
 BACKCHANNEL_TOPIC = "backchannel"
 POLL_INTERVAL_S = 0.002
@@ -62,6 +65,18 @@ POLL_INTERVAL_S = 0.002
 def _doc_of(topic: str) -> tuple[str, str]:
     _, tenant, doc = topic.split("/", 2)
     return tenant, doc
+
+
+def attaches_grid(m, ds_id: str) -> bool:
+    """Whether a sequenced message attaches a SharedMatrix channel named
+    ``GRID_CHANNEL`` in data store ``ds_id``."""
+    env = m.contents
+    if type(env) is not dict or env.get("kind") != "chanop" \
+            or env.get("address") != ds_id:
+        return False
+    inner = env.get("contents")
+    return (type(inner) is dict and inner.get("address") == GRID_CHANNEL
+            and (inner.get("attach") or {}).get("type") == "shared-matrix")
 
 
 def doc_partition(tenant: str, doc: str, n_partitions: int) -> int:
@@ -383,6 +398,9 @@ class ApplierStage(_StageHostBase):
         self.applier.set_replay_source(
             lambda t, d: log_channel_stream(self.shared, t, d,
                                             self.ds_id, self.channel_id))
+        self.applier.set_grid_replay_source(
+            lambda t, d: log_channel_stream(self.shared, t, d,
+                                            self.ds_id, GRID_CHANNEL))
         # a base at start: the deltas that follow need one, and it drops
         # whatever delta files a crash left
         self._ckpt = ApplierCheckpointer(self.applier, ckpt)
@@ -439,15 +457,25 @@ class ApplierStage(_StageHostBase):
             # window of already-applied ops — skip by sequence number
             # (double-applying an insert would corrupt the doc)
             applied = self.applier.applied_seq(tenant, doc)
-            pairs = []
+            pairs, grid_pairs = [], []
             for m in msgs:
                 if m.sequence_number <= applied:
                     continue
                 wire = chanop_of(m, self.ds_id, self.channel_id)
                 if wire is not None:
                     pairs.append((m, wire))
+                    continue
+                if not self.applier.grids:
+                    continue
+                wire = chanop_of(m, self.ds_id, GRID_CHANNEL)
+                if wire is not None:
+                    grid_pairs.append((m, wire))
+                elif attaches_grid(m, self.ds_id):
+                    self.applier.attach_grid(tenant, doc)
             if pairs:
                 self.applier.ingest_batch(tenant, doc, pairs)
+            if grid_pairs:
+                self.applier.ingest_grid_batch(tenant, doc, grid_pairs)
 
         self.shared.subscribe(topic, on_deltas, from_offset=start)
 
@@ -493,8 +521,10 @@ class ApplierStage(_StageHostBase):
         clock = CompileClock()
         stage = cls(log_dir, state_dir, partition=partition)
         a = stage.applier
-        # the long tier's step and promotion compile here, before READY
+        # the long tier's step and promotion, and the grids' cells step,
+        # compile here, before READY
         a.warm_long_tier()
+        a.warm_grids()
         signal.signal(signal.SIGINT,
                       lambda *_: setattr(stage, "stopping", True))
         if trace_dir:
@@ -509,7 +539,8 @@ class ApplierStage(_StageHostBase):
             "kernel_lane": a.kernel_lane, "max_docs": a.max_docs,
             "max_slots": a.max_slots, "ops_per_dispatch": a.K,
             "long_docs": a.long_docs, "long_slots": a.long_slots,
-            "long_kernel_lane": a.long_kernel_lane}), flush=True)
+            "long_kernel_lane": a.long_kernel_lane, "grids": a.grids,
+            "grid_handles": a.grid_handles}), flush=True)
         try:
             stage.run_forever()
         except PlacementFull as e:
@@ -521,6 +552,11 @@ class ApplierStage(_StageHostBase):
             "host_escalations": a.host_escalations,
             "promotions": a.promotions, "long_dispatches": a.long_dispatches,
             "long_slots_peak": a.long_slots_peak,
+            "grid_dispatches": a.grid_dispatches,
+            "grid_cells_set": a.grid_cells_set,
+            "grid_dead_writes": a.grid_dead_writes,
+            "grid_handles_peak": a.grid_handles_peak,
+            "grid_escalations": a.grid_escalations,
             "compile_s": clock.seconds, "compiles": clock.compiles,
             "peak_bytes_in_use": peak_bytes_in_use(dev)}),
             flush=True)
@@ -555,14 +591,24 @@ class ApplierStage(_StageHostBase):
         self.counters.inc("stage.checkpoint.delta_docs", written.docs)
         self.counters.inc("stage.checkpoint.bases", written.bases)
         a = self.applier
-        if a.long_state is None:
-            return
-        self.counters.inc("stage.checkpoint.long_bytes", written.long_bytes)
         # the applier's own tallies, as deltas since the last checkpoint
-        # (the peak rises by what it rose: the counter reads the peak)
-        for name, now in (("applier.promotions", a.promotions),
-                          ("applier.long.dispatches", a.long_dispatches),
-                          ("applier.long.slots_peak", a.long_slots_peak)):
+        # (a peak rises by what it rose: the counter reads the peak)
+        tallies = []
+        if a.long_state is not None:
+            self.counters.inc("stage.checkpoint.long_bytes",
+                              written.long_bytes)
+            tallies += [("applier.promotions", a.promotions),
+                        ("applier.long.dispatches", a.long_dispatches),
+                        ("applier.long.slots_peak", a.long_slots_peak)]
+        if a.grids:
+            self.counters.inc("stage.checkpoint.grid_bytes",
+                              written.grid_bytes)
+            tallies += [("applier.grid.dispatches", a.grid_dispatches),
+                        ("applier.grid.cells_set", a.grid_cells_set),
+                        ("applier.grid.dead_writes", a.grid_dead_writes),
+                        ("applier.grid.handles_peak", a.grid_handles_peak),
+                        ("applier.grid.escalations", a.grid_escalations)]
+        for name, now in tallies:
             self.counters.inc(name, now - self._tallied.get(name, 0))
             self._tallied[name] = now
 

@@ -18,6 +18,8 @@ from ..protocol.messages import DocumentMessage, MessageType
 
 DS_ID = "default"
 CHANNEL_ID = "text"
+#: a grid's SharedMatrix channel, under the same data store as text
+GRID_CHANNEL = "matrix"
 
 _TEXT_POOL = "abcdefgh" * 4  # payload source: slicing beats per-char joins
 

@@ -24,6 +24,14 @@ Semantics guardrails:
   so device zamboni (ops/apply.compact) runs fused after every wave at
   the exact collaboration-window floor — slot usage stays bounded under
   churn instead of growing until escalation.
+- SharedMatrix grids ride beside the text docs (the grids section below):
+  each grid's row and column vectors are two farm rows of their own (a
+  doc may hold text beside its grid), and its cells an [H, H] plane of a
+  device store that ops/matrix_cells.py writes. Handles are never reused
+  within H, unlike upstream's handle recycling (packages/dds/matrix
+  handletable.ts): a removed row's or column's cells become unreachable
+  by position, and a grid whose inserts would pass H escalates to a host
+  SharedMatrix (dds/matrix.py).
 """
 
 from __future__ import annotations
@@ -36,12 +44,14 @@ import threading
 import time
 import warnings
 from dataclasses import replace
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..dds.matrix import HANDLE_BASE, SharedMatrix
 from ..mergetree.client import MergeTreeClient
 from ..ops.apply import (
     F_CLIENT,
@@ -68,6 +78,7 @@ from ..ops.apply import (
     unpack_wave16,
     wave_min_seq,
 )
+from ..ops.matrix_cells import CELL_FIELDS, matrix_cells, written_cells
 from ..ops.doc_state import (FLAG_MARKER, NO_KEY, NO_SEQ, DocState, PropTable,
                              TextArena, decode_state)
 from ..protocol.messages import MessageType, SequencedDocumentMessage
@@ -76,9 +87,18 @@ from ..parallel.placement import DocPlacement
 from ..utils.contracts import register_kernel_contract
 from ..utils.affinity import blocking
 from ..utils.telemetry import span
+from .synthetic import GRID_CHANNEL
 
 MARKER_GLYPH = "￼"  # arena placeholder byte for markers (flags classify)
 _INT16_MAX = 32767
+
+#: a grid's row and column vectors are placed under its doc id plus
+#: these (a doc id holds no NUL), beside the doc's own text row
+ROWS_SUFFIX = "\x00rows"
+COLS_SUFFIX = "\x00cols"
+#: SharedMatrix vector op -> (vector: 0 rows, 1 cols; is an insert)
+_VECTOR_OPS = {"insertRows": (0, True), "insertCols": (1, True),
+               "removeRows": (0, False), "removeCols": (1, False)}
 
 # SYSTEM_CLIENT / PACK_SYSTEM and the int16-delta wire format now live in
 # ops/apply.py (shared with the mesh lane's packed sharded step)
@@ -275,6 +295,33 @@ def _promote_fn():
     return _PROMOTE_FN
 
 
+_CELLS_FN = None
+_WRITTEN_FN = None
+
+#: cells a grid a base reads back from the grid store in one dispatch
+GRID_PAGE = 4096
+
+
+def _cells_fn():
+    """``ops/matrix_cells.matrix_cells`` jitted under its own name, the
+    store and its marks donated where the backend supports donation."""
+    global _CELLS_FN
+    if _CELLS_FN is None:
+        from ..parallel.sharded_apply import donation_supported
+
+        _CELLS_FN = jax.jit(
+            matrix_cells,
+            donate_argnums=(0, 1) if donation_supported() else ())
+    return _CELLS_FN
+
+
+def _written_fn():
+    global _WRITTEN_FN
+    if _WRITTEN_FN is None:
+        _WRITTEN_FN = jax.jit(partial(written_cells, page=GRID_PAGE))
+    return _WRITTEN_FN
+
+
 def _contract_build():
     """The int16 packed wave applier at a small fixed geometry."""
     D, K = 8, 4
@@ -389,6 +436,8 @@ class TpuDocumentApplier:
         kernel: Optional[str] = None,
         long_docs: Optional[int] = None,
         long_slots: Optional[int] = None,
+        grids: Optional[int] = None,
+        grid_handles: Optional[int] = None,
     ):
         from ..config import DEFAULT as _CFG
 
@@ -400,6 +449,9 @@ class TpuDocumentApplier:
                      else _CFG.applier_long_docs)
         long_slots = (long_slots if long_slots is not None
                       else _CFG.applier_long_slots)
+        grids = grids if grids is not None else _CFG.applier_grids
+        grid_handles = (grid_handles if grid_handles is not None
+                        else _CFG.applier_grid_handles)
         ops_per_dispatch = (ops_per_dispatch if ops_per_dispatch is not None
                             else _CFG.applier_ops_per_dispatch)
         overflow_check_every = (
@@ -537,6 +589,7 @@ class TpuDocumentApplier:
                 pallas_interpret=pallas_interpret)
         self._init_long_tier(long_docs, long_slots, use_pallas,
                              pallas_interpret)
+        self._init_grids(grids, grid_handles)
         self.dispatches = 0
         self.ops_applied = 0
         self.host_escalations = 0
@@ -705,6 +758,346 @@ class TpuDocumentApplier:
             if slot is not None and slot not in self._host_docs:
                 self._escalate(slot, None, None)
 
+    # -------------------------------------------------------------- grids
+    #
+    # A SharedMatrix document (a grid, dds/matrix.py) holds two merge-tree
+    # permutation vectors and a cell map. Grid g's row and column vectors
+    # are the farm rows of its doc's id plus ROWS_SUFFIX and COLS_SUFFIX,
+    # both applied by the farm's wave, so a doc that holds text beside
+    # its grid keeps its own text row; its cells are ``grid_cells[g]``,
+    # an [H, H] int32 plane indexed by (row handle, column handle), with
+    # ``grid_written[g]`` marking the cells ever written, both written
+    # by ops/matrix_cells.py. Handles are allocated in insert order, one
+    # arena character each, so a vector slot's text_start is its first
+    # handle. A grid's staged ops stay in seq order in ``_grid_q``; each
+    # wave takes a prefix of them of the form setCells, vector ops,
+    # setCells (``_take_grid_wave``): the first setCells run before the
+    # farm's step and the others after it, so that a setCell sees every
+    # vector op of lower seq and none of higher, and a wave splits only
+    # where a vector op follows. A grid whose inserts would pass H, whose
+    # vector row overflows S, or whose op the device does not model (a
+    # cell value that is not an int32) escalates to a host SharedMatrix
+    # replayed from its log; so does a grid past the G the store holds.
+
+    def _init_grids(self, G: int, H: int) -> None:
+        self.grids, self.grid_handles = G, H
+        self.grid_cells = self.grid_written = None
+        self._vec_grid: dict[int, int] = {}  # either vector's slot -> grid
+        self._grid_slots: list[tuple[int, int]] = []  # (row, col) slots
+        self._grid_next: list[list[int]] = []  # handles allocated [r, c]
+        self._grid_q: dict[int, list] = {}    # staged items, seq order
+        self._host_grids: dict[int, SharedMatrix] = {}
+        self._host_grid_seq: dict[int, int] = {}
+        # each dispatch's (written index, written value, dead writes) on
+        # the device, then the turn's writes on the host for the delta
+        self._grid_pending: list = []
+        self._grid_turn: list = []
+        self._grid_slots_dev = None
+        self._grid_marker = None
+        self.grid_dispatches = self.grid_cells_set = 0
+        self.grid_dead_writes = self.grid_handles_peak = 0
+        self.grid_escalations = 0
+        self.grid_cells_held = 0  # cells written, as the last base read
+        if not G:
+            return
+        if self._mesh is not None or self.long_state is not None:
+            raise ValueError("grids run beside a local farm without the "
+                             "long tier")
+        if G * H * H >= 1 << 31:
+            raise ValueError(f"grid store {G} x {H} x {H}: a cell's flat "
+                             "index must fit int32")
+        self.grid_cells = jnp.zeros((G, H, H), jnp.int32)
+        self.grid_written = jnp.zeros((G, H, H), jnp.int8)
+
+    def warm_grids(self) -> None:
+        """Compile the cells step (an empty wave on the store) and the
+        base's page read, so that nothing compiles when the first setCell
+        arrives or a base is taken."""
+        if self.grid_cells is None:
+            return
+        empty = np.zeros((self.grids, self.K, CELL_FIELDS), np.int32)
+        slots = jax.device_put(np.zeros((2, self.grids), np.int32))
+        self.grid_cells, self.grid_written, *_ = _cells_fn()(
+            self.grid_cells, self.grid_written, self.state, slots[0],
+            slots[1], jax.device_put(empty))
+        jax.block_until_ready(_written_fn()(
+            self.grid_cells, self.grid_written,
+            jnp.zeros(self.grids, jnp.int32)))
+
+    def attach_grid(self, tenant_id: str, document_id: str) -> int:
+        """Make the doc a grid (its attach names a SharedMatrix): farm
+        rows for its two vectors, apart from the doc's text row, and the
+        next grid index; past the store's G, a host SharedMatrix.
+        Returns the grid's index."""
+        rs = self.slot_of(tenant_id, document_id + ROWS_SUFFIX)
+        if rs in self._vec_grid:
+            return self._vec_grid[rs]
+        cs = self.slot_of(tenant_id, document_id + COLS_SUFFIX)
+        g = len(self._grid_slots)
+        self._grid_slots.append((rs, cs))
+        self._grid_next.append([0, 0])
+        self._vec_grid[rs] = self._vec_grid[cs] = g
+        self._grid_slots_dev = None
+        self._marks.grids.add(g)
+        self._marks.dirty.update((rs, cs))
+        if g >= self.grids:
+            self._escalate_grid(g)
+        return g
+
+    def _grid_client(self, g: int, client_id: Optional[str]) -> int:
+        """A client's id, the same in both vectors' tables."""
+        if client_id is None:
+            return SYSTEM_CLIENT
+        rs, cs = self._grid_slots[g]
+        table = self._client_ids.setdefault(rs, {})
+        cid = table.get(client_id)
+        if cid is None:
+            cid = table[client_id] = len(table)
+            self._client_ids.setdefault(cs, {})[client_id] = cid
+            self._marks.dirty.add(cs)
+        return cid
+
+    def ingest_grid_batch(self, tenant_id: str, document_id: str,
+                          pairs: list) -> None:
+        """Stage a batch of sequenced SharedMatrix wire ops of one grid
+        (dds/matrix.py's wire) in seq order."""
+        g = self.attach_grid(tenant_id, document_id)
+        rs = self._grid_slots[g][0]
+        self._marks.dirty.add(rs)
+        if pairs:
+            self._applied_seq[rs] = max(self._applied_seq.get(rs, 0),
+                                        pairs[-1][0].sequence_number)
+            self._first_seq.setdefault(rs, pairs[0][0].sequence_number)
+            if rs in self._restore_applied:
+                self._post_restore_first.setdefault(
+                    rs, pairs[0][0].sequence_number)
+        if g in self._host_grids:
+            for msg, op in pairs:
+                self._apply_host_grid(g, msg, op)
+            return
+        q = self._grid_q.setdefault(g, [])
+        for i, (msg, op) in enumerate(pairs):
+            item = self._grid_item(g, msg, op)
+            if item is None:
+                # the replay reads this batch from the log
+                self._escalate_grid(g)
+                for msg2, op2 in pairs[i + 1:]:
+                    self._apply_host_grid(g, msg2, op2)
+                return
+            q.append(item)
+        if not q:
+            del self._grid_q[g]
+
+    def _grid_item(self, g: int, msg, op) -> Optional[tuple]:
+        """A staged item: ("c", setCell row) or ("v", axis, farm op row);
+        None for an op the device does not model."""
+        if type(op) is not dict:
+            return None
+        kind = op.get("op")
+        seq, ref = msg.sequence_number, msg.reference_sequence_number
+        client = self._grid_client(g, msg.client_id)
+        if kind == "setCell":
+            v = op.get("value")
+            if type(v) is not int or not -(1 << 31) <= v < 1 << 31 \
+                    or type(op.get("row")) is not int \
+                    or type(op.get("col")) is not int:
+                return None
+            # ops/matrix_cells.py's row: VALID, ROW, COL, REF, CLIENT, VALUE
+            return ("c", (1, op["row"], op["col"], ref, client, v))
+        axis, insert = _VECTOR_OPS.get(kind, (None, None))
+        count = op.get("count")
+        if axis is None or type(count) is not int or count < 1 \
+                or type(op.get("pos")) is not int:
+            return None
+        slot = self._grid_slots[g][axis]
+        self._marks.dirty.add(slot)
+        pos, msn = op["pos"], msg.minimum_sequence_number
+        if not insert:
+            return ("v", axis, (OP_REMOVE, pos, pos + count, seq, ref,
+                                client, 0, 0, msn, 0, 0, 0))
+        nxt = self._grid_next[g]
+        h0 = nxt[axis]
+        if h0 + count > self.grid_handles:
+            return None
+        nxt[axis] = h0 + count
+        self.grid_handles_peak = max(self.grid_handles_peak, h0 + count)
+        self._marks.grids.add(g)
+        # the arena holds only handles, so the run starts at handle h0
+        start = self.arenas[slot].append(
+            "".join(map(chr, range(HANDLE_BASE + h0,
+                                   HANDLE_BASE + h0 + count))))
+        return ("v", axis, (OP_INSERT, pos, 0, seq, ref, client, count,
+                            start, msn, 0, 0, 0))
+
+    def _take_grid_wave(self):
+        """Pop each staged grid's next wave: up to K setCells that come
+        before its vector ops, up to K vector ops a vector, then up to K
+        setCells, stopping at a vector op that follows those. Returns
+        (farm parts, setCells before the farm's step, setCells after),
+        the setCells as [(grid, rows)]."""
+        K = self.K
+        parts, pre, post = [], [], []
+        for g in list(self._grid_q):
+            q = self._grid_q[g]
+            vec = ([], [])
+            cells = ([], [])  # before, after the vector ops
+            n = 0
+            for item in q:
+                if item[0] == "c":
+                    rows = cells[any(vec)]
+                    if len(rows) == K:
+                        break
+                    rows.append(item[1])
+                elif cells[1] or len(vec[item[1]]) == K:
+                    break
+                else:
+                    vec[item[1]].append(item[2])
+                n += 1
+            del q[:n]
+            if not q:
+                del self._grid_q[g]
+            for axis, ops in enumerate(vec):
+                if ops:
+                    parts.append((self._grid_slots[g][axis],
+                                  [np.asarray(ops, np.int32)], len(ops)))
+            for out, rows in zip((pre, post), cells):
+                if rows:
+                    out.append((g, rows))
+        return parts, pre, post
+
+    def _dispatch_cells(self, cells) -> int:
+        """Stage and execute one wave of setCells: [G, K] rows, then the
+        cells step on the farm as the wave's step left it."""
+        with span("applier.grid_stage_wave"):
+            wave = np.zeros((self.grids, self.K, CELL_FIELDS), np.int32)
+            n = 0
+            for g, rows in cells:
+                wave[g, :len(rows)] = rows
+                n += len(rows)
+            if self._grid_slots_dev is None:
+                slots = np.zeros((2, self.grids), np.int32)
+                for g, pair in enumerate(self._grid_slots[:self.grids]):
+                    slots[:, g] = pair
+                self._grid_slots_dev = (jax.device_put(slots[0]),
+                                        jax.device_put(slots[1]))
+            wave = jax.device_put(wave)
+        with span("applier.grid_dispatch"):
+            self.grid_cells, self.grid_written, idx, val, dead = _cells_fn()(
+                self.grid_cells, self.grid_written, self.state,
+                *self._grid_slots_dev, wave)
+            self._grid_pending.append((idx, val, dead))
+            self._grid_marker = dead
+        self.grid_dispatches += 1
+        return n
+
+    def _collect_grid_writes(self) -> None:
+        """Bring the dispatched waves' writes to the host: the turn's list
+        for the delta, the counters."""
+        if not self._grid_pending:
+            return
+        got = jax.device_get(self._grid_pending)
+        self._grid_pending = []
+        idx = np.concatenate([i.ravel() for i, _v, _d in got])
+        val = np.concatenate([v.ravel() for _i, v, _d in got])
+        self.grid_dead_writes += int(sum(d for _i, _v, d in got))
+        keep = idx >= 0
+        idx, val = idx[keep].astype(np.int64), val[keep]
+        self.grid_cells_set += len(idx)
+        self._grid_turn.append((idx, val))
+
+    def _take_grid_turn(self) -> tuple[np.ndarray, np.ndarray]:
+        """(flat index, value) of each cell written since the last call,
+        its last write only."""
+        self._collect_grid_writes()
+        turn, self._grid_turn = self._grid_turn, []
+        if not turn:
+            return np.zeros(0, np.int64), np.zeros(0, np.int32)
+        return _last_writes(np.concatenate([i for i, _v in turn]),
+                            np.concatenate([v for _i, v in turn]))
+
+    def _grid_store_values(self) -> tuple[np.ndarray, np.ndarray]:
+        """(flat index, value) of every cell ever written, read back from
+        the device store a page of GRID_PAGE cells a grid at a time:
+        one dispatch per page of the fullest grid, and the host holds
+        only what it reads."""
+        start = np.zeros(self.grids, np.int32)
+        idx, val = [], []
+        while True:
+            i, v, n = jax.device_get(_written_fn()(
+                self.grid_cells, self.grid_written, jax.device_put(start)))
+            keep = i >= 0
+            idx.append(i[keep].astype(np.int64))
+            val.append(v[keep])
+            start += GRID_PAGE
+            if (start >= n).all():
+                self.grid_cells_held = int(n.sum())
+                return np.concatenate(idx), np.concatenate(val)
+
+    def _live_handles(self, slot: int) -> list[int]:
+        """A vector row's handles in order, removed items left out."""
+        row = self._device_slot(slot)
+        out = []
+        for i in range(int(row.count)):
+            if row.rem_seq[i] == NO_SEQ:
+                ts = int(row.text_start[i])
+                out.extend(range(ts, ts + int(row.length[i])))
+        return out
+
+    def get_grid(self, tenant_id: str, document_id: str) -> dict:
+        """{(row, col): value} of the grid's set cells at their current
+        positions (a query: fences the device)."""
+        g = self._vec_grid[self._placed_row(tenant_id,
+                                            document_id + ROWS_SUFFIX)]
+        rs, cs = self._grid_slots[g]
+        self.finalize()
+        if g in self._host_grids:
+            lists = self._host_grids[g].to_lists()
+            return {(r, c): v for r, vals in enumerate(lists)
+                    for c, v in enumerate(vals) if v is not None}
+        self._collect_grid_writes()  # the counters, as of this query
+        rpos = {h: i for i, h in enumerate(self._live_handles(rs))}
+        cpos = {h: i for i, h in enumerate(self._live_handles(cs))}
+        plane = np.asarray(self.grid_cells[g])
+        return {(rpos[rh], cpos[ch]): int(plane[rh, ch])
+                for rh, ch in zip(*np.nonzero(np.asarray(
+                    self.grid_written[g])))
+                if rh in rpos and ch in cpos}
+
+    def _escalate_grid(self, g: int) -> None:
+        """Move grid ``g`` to a host SharedMatrix, replayed from its log
+        when it was on the device."""
+        rs, _cs = self._grid_slots[g]
+        tenant_id, name = self._doc_keys[rs]
+        document_id = name.removesuffix(ROWS_SUFFIX)
+        self._drain_device()
+        on_device = g < self.grids
+        if on_device and self._grid_replay_log is None:
+            raise RuntimeError(
+                f"grid {tenant_id}/{document_id} needs host escalation but "
+                "no grid replay source is configured")
+        self.grid_escalations += 1
+        replica = SharedMatrix(GRID_CHANNEL)
+        self._host_grids[g] = replica
+        self._host_grid_seq[g] = 0
+        self._grid_q.pop(g, None)
+        self._marks.grids.add(g)
+        self._marks.dirty.add(rs)
+        if on_device:
+            for m in self._grid_replay_log(tenant_id, document_id):
+                self._apply_host_grid(g, m, m.contents)
+
+    def _apply_host_grid(self, g: int, msg, op) -> None:
+        if msg.sequence_number <= self._host_grid_seq[g]:
+            return  # covered by the escalation replay
+        self._host_grids[g].process(replace(msg, contents=op), local=False)
+        self._host_grid_seq[g] = msg.sequence_number
+
+    _grid_replay_log = None
+
+    def set_grid_replay_source(self, fn) -> None:
+        """fn(tenant, doc) -> the grid channel's sequenced messages."""
+        self._grid_replay_log = fn
+
     # ------------------------------------------------------------- ingest
 
     def slot_of(self, tenant_id: str, document_id: str) -> int:
@@ -716,6 +1109,13 @@ class TpuDocumentApplier:
             self._doc_keys[row] = (tenant_id, document_id)
             self._marks.dirty.add(row)
         return row
+
+    def _placed_row(self, tenant_id: str, document_id: str
+                    ) -> Optional[int]:
+        """A doc's state row if it has one; places nothing."""
+        at = self.placement.lookup(tenant_id, document_id)
+        return (None if at is None
+                else at[0] * self.placement.slots_per_shard + at[1])
 
     def _intern_client(self, slot: int, client_id: Optional[str]) -> int:
         if client_id is None:
@@ -958,11 +1358,20 @@ class TpuDocumentApplier:
         (every ``overflow_check_every`` dispatches) or by
         ``finalize()``/queries."""
         total = 0
-        while self._staged:
+        while self._staged or self._grid_q:
             # one dispatch path for both lanes: _dispatch_wave routes the
             # packed wave to the local dense step or the mesh's sharded
-            # step (per-shard staging + pre-partitioned transfer)
-            total += self._dispatch_wave(self._take_wave())
+            # step (per-shard staging + pre-partitioned transfer); a
+            # grid's vector ops ride the farm's wave, its setCells follow
+            parts, pre, post = self._take_wave() or [], None, None
+            if self._grid_q:
+                vec, pre, post = self._take_grid_wave()
+                parts += vec
+            if pre:  # setCells of lower seq than the wave's vector ops
+                total += self._dispatch_cells(pre)
+            total += self._dispatch_wave(parts)
+            if post:
+                total += self._dispatch_cells(post)
         self.ops_applied += total
         if self._dispatches_since_check >= self.overflow_check_every:
             self._check_overflow()
@@ -1070,6 +1479,8 @@ class TpuDocumentApplier:
             jax.block_until_ready(self._exec_marker)
         if self._long_marker is not None:
             jax.block_until_ready(self._long_marker)
+        if self._grid_marker is not None:
+            jax.block_until_ready(self._grid_marker)
 
     def _stage_wave(self, parts) -> Optional[_StagedWave]:
         """The HOST half of a dispatch: concat chunks → pack_wave_rows →
@@ -1396,11 +1807,18 @@ class TpuDocumentApplier:
         return replica
 
     def applied_seq(self, tenant_id: str, document_id: str) -> int:
-        """Highest sequence number ingested for the doc (0 if none).
-        Summary writers compare this against the stream's last channel op
-        to refuse writing a summary from lagging device state."""
-        return self._applied_seq.get(
-            self.slot_of(tenant_id, document_id), 0)
+        """Highest sequence number ingested for the doc, its text and its
+        grid (0 if none). Summary writers compare this against the
+        stream's last channel op to refuse writing a summary from lagging
+        device state. Without grids a doc takes its row here, at the
+        stage's first sight of it; with grids it takes its rows as its
+        text or its grid arrives (a grid alone holds no text row)."""
+        if not self.grids:
+            return self._applied_seq.get(
+                self.slot_of(tenant_id, document_id), 0)
+        return max(self._applied_seq.get(self._placed_row(
+            tenant_id, name), 0) for name in (document_id,
+                                              document_id + ROWS_SUFFIX))
 
     def first_seq(self, tenant_id: str, document_id: str) -> int:
         """First sequence number ever ingested for the doc (0 if none)."""
@@ -1462,6 +1880,11 @@ class TpuDocumentApplier:
     def _escalate(self, slot: int, msg, wire_op) -> None:
         """Rebuild the doc on the scalar oracle from its authoritative op
         log and continue host-side (SURVEY §7(e) escape hatch)."""
+        g = self._vec_grid.get(slot)
+        if g is not None:  # a grid's vector row: the whole grid goes
+            if g not in self._host_grids:
+                self._escalate_grid(g)
+            return
         tenant_id, document_id = self._doc_keys[slot]
         # strict wave order at the escalation seam: the doc leaves the
         # device farm only after its last in-flight wave lands
@@ -1564,10 +1987,12 @@ class _CheckpointMarks:
     length and the property table's size when it was taken, and the
     number of the last delta its state reflects."""
 
-    __slots__ = ("dirty", "promoted", "arena_len", "props", "seq")
+    __slots__ = ("dirty", "promoted", "grids", "arena_len", "props", "seq")
 
     def __init__(self, max_docs: int):
         self.dirty: set[int] = set()
+        # grids whose sidecar changed: added, handles allocated, escalated
+        self.grids: set[int] = set()
         # moved to the long tier since: the delta carries their emptied
         # farm rows once
         self.promoted: set[int] = set()
@@ -1579,6 +2004,7 @@ class _CheckpointMarks:
         """Everything the applier holds is saved."""
         self.dirty.clear()
         self.promoted.clear()
+        self.grids.clear()
         self.arena_len = [len(a) for a in applier.arenas]
         snap = applier.prop_table.snapshot()
         self.props = (len(snap["keys"]), len(snap["vals"]))
@@ -1586,6 +2012,37 @@ class _CheckpointMarks:
 
 #: key prefix of the long tier's arrays in a base's ``.npz``
 LONG_PREFIX = "long_"
+#: a base's grid cells, sparse: ``grid_idx`` (flat index
+#: ``(g * H + rh) * H + ch``, int64) and ``grid_val`` (int32)
+GRID_PREFIX = "grid_"
+
+
+def _last_writes(idx: np.ndarray, val: np.ndarray
+                 ) -> tuple[np.ndarray, np.ndarray]:
+    """Of cell writes (flat index, value) in the order made, the last
+    write of each cell."""
+    _, last = np.unique(idx[::-1], return_index=True)
+    pick = len(idx) - 1 - last
+    return idx[pick], val[pick]
+
+
+def _grid_entry(applier: "TpuDocumentApplier", g: int) -> list:
+    """A grid's sidecar: [row slot, col slot, row handles, col handles,
+    [host snapshot, host seq] or None]."""
+    host = applier._host_grids.get(g)
+    return [*applier._grid_slots[g], *applier._grid_next[g],
+            None if host is None else [host.snapshot(),
+                                       applier._host_grid_seq[g]]]
+
+
+def _grid_base(applier: "TpuDocumentApplier") -> tuple[dict, int]:
+    """A base's grid arrays (every cell written, read back from the
+    store) and their bytes; nothing without grids."""
+    if applier.grid_cells is None:
+        return {}, 0
+    idx, val = applier._grid_store_values()
+    return ({GRID_PREFIX + "idx": idx, GRID_PREFIX + "val": val},
+            idx.nbytes + val.nbytes)
 
 
 def _device_get(applier: "TpuDocumentApplier", long: bool = True) -> dict:
@@ -1620,6 +2077,7 @@ def save_applier_checkpoint(applier: "TpuDocumentApplier",
         applier.finalize()
         with span("checkpoint.device_get"):
             arrays = _device_get(applier)
+            arrays.update(_grid_base(applier)[0])
         return _write_base(arrays, _base_meta(applier), path)
 
 
@@ -1658,6 +2116,11 @@ def _base_meta(applier: "TpuDocumentApplier") -> dict:
             "docs": applier.long_docs, "slots": applier.long_slots,
             "rows": {str(row): [slot, *applier._doc_keys[slot]]
                      for slot, row in applier._long_rows.items()}}
+    if applier._grid_slots:
+        meta["grids"] = {
+            "grids": applier.grids, "handles": applier.grid_handles,
+            "docs": {str(g): _grid_entry(applier, g)
+                     for g in range(len(applier._grid_slots))}}
     return meta
 
 
@@ -1688,13 +2151,15 @@ def _write_base(arrays: dict, meta: dict, path: str) -> int:
 
 
 def _delta_payload(applier: "TpuDocumentApplier", arrays: dict
-                   ) -> tuple[bytes, int, int]:
+                   ) -> tuple[bytes, int, int, int]:
     """One delta's payload: the rows of the slots marked since the last
     checkpoint, taken from the host copy ``arrays``, and their sidecar;
     marks them saved. A doc on the long tier ships its long row (after
     the farm's rows), and its farm row only in the delta that follows
-    its promotion. Returns it, the number of slots and the long rows'
-    bytes."""
+    its promotion. A grid's cells written since ship as (flat index,
+    value) rows after those, and its changed sidecar in the header.
+    Returns it, the number of slots, the long rows' bytes and the grid
+    cells' bytes."""
     m = applier._marks
     slots = sorted(m.dirty)
     on_long = applier._long_rows
@@ -1733,9 +2198,17 @@ def _delta_payload(applier: "TpuDocumentApplier", arrays: dict
             for f in _STATE_FIELDS]
         long_bytes = sum(r.nbytes for r in long_rows)
         rows += long_rows
+    grid_bytes = 0
+    if applier.grid_cells is not None:
+        cells = applier._take_grid_turn()
+        if len(cells[0]):
+            rows += list(cells)
+            grid_bytes = cells[0].nbytes + cells[1].nbytes
+    grids = {str(g): _grid_entry(applier, g) for g in sorted(m.grids)}
     m.seq += 1
     m.dirty.clear()
     m.promoted.clear()
+    m.grids.clear()
     m.props = (len(snap["keys"]), len(snap["vals"]))
     head = {
         "seq": m.seq, "slots": slots, "sidecar": sidecar,
@@ -1745,9 +2218,15 @@ def _delta_payload(applier: "TpuDocumentApplier", arrays: dict
         head["farm_slots"] = farm_slots
     if long_slots:
         head["long_slots"], head["long_width"] = long_slots, width
+    if grids:
+        head["grids"] = grids
+        head["grid_meta"] = [applier.grids, applier.grid_handles]
+    if grid_bytes:
+        head["grid_cells"] = len(rows[-1])
     head = json.dumps(head).encode()
     return (b"".join([_DELTA_HEAD.pack(len(head)), head]
-                     + [r.tobytes() for r in rows]), len(slots), long_bytes)
+                     + [r.tobytes() for r in rows]), len(slots), long_bytes,
+            grid_bytes)
 
 
 def _decode_delta(payload: bytes) -> tuple[dict, list]:
@@ -1762,7 +2241,8 @@ def _decode_delta(payload: bytes) -> tuple[dict, list]:
         rows.append(np.frombuffer(payload, dtype, n, off).reshape(shape))
         off += n * dtype.itemsize
     tiers = 2 if head.get("long_slots") else 1
-    if off != len(payload) or len(rows) != tiers * len(_STATE_FIELDS):
+    grid = 2 if head.get("grid_cells") else 0
+    if off != len(payload) or len(rows) != tiers * len(_STATE_FIELDS) + grid:
         raise ValueError("delta rows do not fill the record")
     return head, rows
 
@@ -1828,6 +2308,16 @@ def _replay_delta(meta: dict, arrays: dict, head: dict, rows: list) -> None:
             else:
                 a[lidx, :width] = r
                 a[lidx, width:] = _SLOT_FILL.get(f, 0)
+    if head.get("grids"):
+        G, H = head["grid_meta"]
+        meta.setdefault("grids", {"grids": G, "handles": H, "docs": {}})[
+            "docs"].update(head["grids"])
+    if head.get("grid_cells"):
+        idx, val = rows[-2:]
+        arrays[GRID_PREFIX + "idx"] = np.concatenate(
+            [arrays.get(GRID_PREFIX + "idx", np.zeros(0, np.int64)), idx])
+        arrays[GRID_PREFIX + "val"] = np.concatenate(
+            [arrays.get(GRID_PREFIX + "val", np.zeros(0, np.int32)), val])
     long_rows = meta["long"]["rows"] if "long" in meta else {}
     sps = meta["placement"]["slots_per_shard"]
     anchored = set(meta.get("anchored", ()))
@@ -1866,6 +2356,7 @@ class CheckpointWrite(NamedTuple):
     docs: int    # slots the delta holds
     bases: int   # bases committed by the call, or since the last call
     long_bytes: int = 0  # the long tier's rows in it (raw, in a base)
+    grid_bytes: int = 0  # the grid cells in it (sparse, in a base too)
 
 
 class ApplierCheckpointer:
@@ -1899,10 +2390,13 @@ class ApplierCheckpointer:
         # the deltas after it start from here (a save of the applier
         # elsewhere leaves the marks to their owner, this chain)
         self.applier._marks.reset(self.applier)
+        self.applier._grid_turn = []  # the base holds every cell written
         self._file, self._since_base = None, 0
         self._remove_deltas(None)
+        # int64 + int32 a cell the base read back
+        grid_bytes = 12 * self.applier.grid_cells_held
         return CheckpointWrite(nbytes, 0, bases + 1,
-                               (bases + 1) * self.long_raw)
+                               (bases + 1) * self.long_raw, grid_bytes)
 
     def delta(self) -> CheckpointWrite:
         """Append one delta of the slots changed since the last
@@ -1911,14 +2405,15 @@ class ApplierCheckpointer:
         nbytes, bases = self._collect(wait=False)
         a = self.applier
         a.finalize()
-        if not a._marks.dirty:
+        if not a._marks.dirty and not a._grid_pending \
+                and not a._grid_turn:
             return CheckpointWrite(nbytes, 0, bases, bases * self.long_raw)
         with span("checkpoint.delta"):
             with span("checkpoint.delta.device_get"):
                 # the long tier's arrays only when one of its docs moved
                 arrays = _device_get(a, long=not a._marks.dirty.isdisjoint(
                     a._long_rows))
-            payload, docs, long_bytes = _delta_payload(a, arrays)
+            payload, docs, long_bytes, grid_bytes = _delta_payload(a, arrays)
             with span("checkpoint.delta.write"):
                 frame = frame_record(payload)
                 mode = "ab"
@@ -1932,9 +2427,13 @@ class ApplierCheckpointer:
         if self._since_base > self.limit and self._writer is None:
             if a.long_state is not None and not _long_nbytes(arrays):
                 arrays.update(_device_get(a, long=True))
+            cells, nbytes_grid = _grid_base(a)
+            arrays.update(cells)
+            grid_bytes += nbytes_grid
             self._start_base(arrays)
         return CheckpointWrite(nbytes + len(frame), docs, bases,
-                               long_bytes + bases * self.long_raw)
+                               long_bytes + bases * self.long_raw,
+                               grid_bytes)
 
     def _start_base(self, arrays: dict) -> None:
         """Snapshot a base here (the host arrays the delta just took, and
@@ -2001,11 +2500,15 @@ def load_applier_checkpoint(path: str, **applier_kwargs
     if long is not None:
         applier_kwargs = {**applier_kwargs, "long_docs": long["docs"],
                           "long_slots": long["slots"]}
+    grids = meta.get("grids")
+    if grids is not None:
+        applier_kwargs = {**applier_kwargs, "grids": grids["grids"],
+                          "grid_handles": grids["handles"]}
     applier = TpuDocumentApplier(max_docs=meta["max_docs"],
                                  max_slots=meta["max_slots"],
                                  **applier_kwargs)
     applier.state = _DS(**{k: jnp.asarray(v) for k, v in arrays.items()
-                           if not k.startswith(LONG_PREFIX)})
+                           if not k.startswith((LONG_PREFIX, GRID_PREFIX))})
     if long is not None:
         applier.long_state = _DS(**{
             k[len(LONG_PREFIX):]: jnp.asarray(v) for k, v in arrays.items()
@@ -2068,6 +2571,34 @@ def load_applier_checkpoint(path: str, **applier_kwargs
         slot = int(k)
         applier._restore_applied[slot] = min(
             v, applier._restore_applied.get(slot, v))
+    if grids is not None:
+        _restore_grids(applier, grids, arrays)
     applier._marks.reset(applier)
     applier._marks.seq = seq
     return applier
+
+
+def _restore_grids(applier: "TpuDocumentApplier", grids: dict,
+                   arrays: dict) -> None:
+    """The grids of a loaded checkpoint: their slots, handle counts and
+    host replicas, and the device store rebuilt from the cells written
+    (the last write of each)."""
+    for g in range(len(grids["docs"])):
+        rs, cs, nr, nc, host = grids["docs"][str(g)]
+        applier._grid_slots.append((rs, cs))
+        applier._grid_next.append([nr, nc])
+        applier._vec_grid[rs] = applier._vec_grid[cs] = g
+        applier.grid_handles_peak = max(applier.grid_handles_peak, nr, nc)
+        if host is not None:
+            replica = SharedMatrix(GRID_CHANNEL)
+            replica.load_core(host[0])
+            applier._host_grids[g] = replica
+            applier._host_grid_seq[g] = host[1]
+    idx = arrays.get(GRID_PREFIX + "idx")
+    if idx is None or not len(idx):
+        return
+    idx, val = _last_writes(idx, arrays[GRID_PREFIX + "val"])
+    H = applier.grid_handles
+    at = (idx // (H * H), (idx // H) % H, idx % H)
+    applier.grid_cells = applier.grid_cells.at[at].set(val)
+    applier.grid_written = applier.grid_written.at[at].set(1)

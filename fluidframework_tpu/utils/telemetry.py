@@ -153,6 +153,9 @@ SPANS = {
     "applier.promote": "one farm doc moved to the long tier on the device",
     "applier.long_stage_wave": "host half of a long-tier wave: rows, put",
     "applier.long_dispatch": "enqueue of one long-tier step (asynchronous)",
+    "applier.grid_stage_wave": "host half of a grid cells wave: rows, put",
+    "applier.grid_dispatch": "enqueue of one matrix_cells step "
+                             "(asynchronous)",
     "checkpoint.save": "a base: save_applier_checkpoint, or its write "
                        "on the applier-base thread",
     "checkpoint.device_get": "copy of the state arrays to the host",

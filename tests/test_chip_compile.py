@@ -2,10 +2,12 @@
 
 Compiles for a described v5e:2x2 (``jax.experimental.topologies``): the
 served packed step and the batched apply on both kernel lanes at
-1024 docs x 256 slots x 64 ops, and the 4-chip doc-mesh packed step at
-8192 x 256 x 64. What the chip's compiler would refuse (a Mosaic kernel
-that does not tile, a program past the chip's 16 GB) fails here at no
-chip time. Nothing runs: a compile that passes is not a chip run.
+1024 docs x 256 slots x 64 ops, the 4-chip doc-mesh packed step at
+8192 x 256 x 64, the long tier, the grid cell's cells step, in place,
+and a base's page read of its store. What the chip's compiler would
+refuse (a Mosaic kernel that does not tile, a program past the chip's
+16 GB) fails here at no chip time. Nothing runs: a compile that passes
+is not a chip run.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load libtpu, and each xdist worker imports every
@@ -190,4 +192,61 @@ def test_promotion_compiles(topo, no_compile_cache):
     idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
     compiled = jax.jit(_promote_rows).lower(
         _state_shapes(D, one), long, idx, idx).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+GRID_G, GRID_H, GRID_D = 100, 1536, 1200
+
+
+def _grid_shapes(topo):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sd(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    store = (sd((GRID_G, GRID_H, GRID_H)),
+             sd((GRID_G, GRID_H, GRID_H), jnp.int8))
+    return one, sd, store
+
+
+def test_matrix_cells_compiles_in_place(topo, no_compile_cache):
+    """The grid cell's cells step: a [100, 1536, 1536] store and its
+    int8 marks beside the [1200, 256] farm, 32 setCells a grid. Both are
+    donated and written in place: the step's scratch stays far below one
+    copy of the store (0.94 GB), which a scatter that flattens it would
+    take."""
+    import jax
+
+    from fluidframework_tpu.ops.matrix_cells import (CELL_FIELDS,
+                                                      matrix_cells)
+
+    one, sd, store = _grid_shapes(topo)
+    compiled = jax.jit(matrix_cells, donate_argnums=(0, 1)).lower(
+        *store, _state_shapes(GRID_D, one),
+        sd((GRID_G,)), sd((GRID_G,)), sd((GRID_G, 32, CELL_FIELDS))
+    ).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == GRID_G * GRID_H * GRID_H * 5
+    assert m.temp_size_in_bytes < 64 * 2**20
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_written_cells_reads_a_page_without_copying_the_store(
+        topo, no_compile_cache):
+    """A base's page read of the same store: its scratch is one plane's
+    ranks and the page, not a copy of the store or of its marks."""
+    from functools import partial
+
+    import jax
+
+    from fluidframework_tpu.ops.matrix_cells import written_cells
+
+    _one, sd, store = _grid_shapes(topo)
+    compiled = jax.jit(partial(written_cells, page=4096)).lower(
+        *store, sd((GRID_G,))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert _device_bytes(compiled) < HBM_BYTES

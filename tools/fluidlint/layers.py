@@ -61,9 +61,10 @@ ALLOWED = {
     "framework": {"loader", "runtime", "dds", "mergetree", "protocol",
                   "utils"},
     # the service branch: protocol + utils + the TPU kernel stack; the
-    # wire helpers live in driver (shared transport), NEVER runtime/loader
+    # wire helpers live in driver (shared transport), NEVER runtime/loader;
+    # dds for the applier's host replica of an escalated grid (SharedMatrix)
     "service": {"protocol", "utils", "ops", "parallel", "mergetree",
-                "driver", "native", "obs"},
+                "driver", "native", "obs", "dds"},
     "native": {"utils"},
     # obs: the replay tool reports history-first vs legacy whole-log
     # boots into the shared metrics registry (history.replay.legacy)

@@ -173,7 +173,13 @@ LOCKED_FAMILIES = {
                            # (service/stage_runner.py ApplierStage._count)
                            "applier.promotions",
                            "applier.long.dispatches",
-                           "applier.long.slots_peak"}),
+                           "applier.long.slots_peak",
+                           # the grids', as the long tier's
+                           "applier.grid.dispatches",
+                           "applier.grid.cells_set",
+                           "applier.grid.dead_writes",
+                           "applier.grid.handles_peak",
+                           "applier.grid.escalations"}),
     # the placement control plane: the net-smoke migration gate, the
     # admin CLI, and the chaos migration campaign key on these exact
     # names (service/placement_plane.py); placement.heat.* are the
