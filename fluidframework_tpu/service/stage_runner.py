@@ -521,8 +521,9 @@ class ApplierStage(_StageHostBase):
         clock = CompileClock()
         stage = cls(log_dir, state_dir, partition=partition)
         a = stage.applier
-        # the long tier's step and promotion, and the grids' cells step,
-        # compile here, before READY
+        # the farm's packed and tile steps, the long tier's step and
+        # promotion, and the grids' cells step compile here, before READY
+        a.warm_farm()
         a.warm_long_tier()
         a.warm_grids()
         signal.signal(signal.SIGINT,
@@ -549,6 +550,9 @@ class ApplierStage(_StageHostBase):
         print("STOPPED " + json.dumps({
             "docs": len(a.placement.snapshot()["map"]),
             "ops_applied": a.ops_applied, "dispatches": a.dispatches,
+            "waves_tiled": a.waves_tiled, "waves_full": a.waves_full,
+            "wave_rows": a.wave_rows,
+            "wave_rows_padded": a.wave_rows_padded,
             "host_escalations": a.host_escalations,
             "promotions": a.promotions, "long_dispatches": a.long_dispatches,
             "long_slots_peak": a.long_slots_peak,

@@ -134,7 +134,7 @@ class _StagedWave:
 
     def __init__(self, lane: str, wide: bool, arrays: tuple, n: int,
                  nbytes: int):
-        self.lane = lane        # "dense" | "mesh" (metrics label)
+        self.lane = lane        # "dense" | "tile" | "mesh" (metrics label)
         self.wide = wide        # int32 escape lane (range / force_wide)
         self.arrays = arrays    # device arrays, step-call order
         self.n = n              # op rows in the wave
@@ -180,6 +180,16 @@ def _count_trace(kernel: str, shape: str) -> None:
                        kernel=kernel, shape=shape)
 
 
+def _farm_apply(use_pallas: bool, pallas_interpret: bool):
+    """The farm's batched apply: the Pallas kernel (tiles of R=8 rows)
+    or the XLA scan."""
+    if not use_pallas:
+        return apply_ops_batch
+    from ..ops.pallas_apply import pallas_apply_ops_batch
+
+    return partial(pallas_apply_ops_batch, interpret=pallas_interpret)
+
+
 def _dense_step_for(D: int, K: int, use_pallas: bool = False,
                     pallas_interpret: bool = False):
     """The wave arrives PACKED from the host: int16[D, K, F] deltas plus
@@ -188,23 +198,16 @@ def _dense_step_for(D: int, K: int, use_pallas: bool = False,
     format — rationale and codec in ops/apply.py's packed-wave section).
 
     Why this shape: the host↔device link is the op path's bottleneck, so
-    bytes-per-op is the number to minimize. Device-side scatter/row-gather
-    of compact rows would avoid padding but costs ~400-550 ms per 64k rows
-    on TPU; shipping the padded [D, K] wave and halving it to int16 is
-    both simpler and faster. The host checks the delta ranges and falls
-    back to the int32 wave when any field escapes (huge docs, giant
-    windows).
+    bytes-per-op is the number to minimize, and halving the padded wave
+    to int16 does that. The host checks the delta ranges and falls back
+    to the int32 wave when any field escapes (huge docs, giant windows).
+    This step covers all D rows; a wave that occupies few of them takes
+    the tile step instead (``_tile_step_for``), so the full step is left
+    to waves that touch much of the farm: set-up, replay, catch-up.
     """
     fn = _DENSE_STEP_CACHE.get((D, K, use_pallas, pallas_interpret))
     if fn is None:
-        if use_pallas:
-            from ..ops.pallas_apply import pallas_apply_ops_batch
-
-            def apply_fn(state, wave):
-                return pallas_apply_ops_batch(
-                    state, wave, interpret=pallas_interpret)
-        else:
-            apply_fn = apply_ops_batch
+        apply_fn = _farm_apply(use_pallas, pallas_interpret)
 
         def dense_step(state, wave16, bases):
             _count_trace("dense_step", f"{D}x{K}")
@@ -226,6 +229,62 @@ def _dense_step_for(D: int, K: int, use_pallas: bool = False,
         fn = (jax.jit(dense_step, donate_argnums=don),
               jax.jit(dense_step_wide, donate_argnums=don))
         _DENSE_STEP_CACHE[(D, K, use_pallas, pallas_interpret)] = fn
+    return fn
+
+
+def _tile_sizes(D: int) -> tuple:
+    """The row counts of the tile step for a farm of D rows: about D/16
+    and D/4, each rounded up to a multiple of 8 (the Pallas kernel's R),
+    at least 8 and at most D/2. None where D is below 16."""
+    sizes = []
+    for frac in (16, 4):
+        t = max(8, (-(-D // frac) + 7) // 8 * 8)
+        if t <= D // 2 and t not in sizes:
+            sizes.append(t)
+    return tuple(sizes)
+
+
+def _tile_rows(slots: np.ndarray, T: int) -> np.ndarray:
+    """A tile's farm rows, int32 [T], sorted: the wave's ``slots``
+    (distinct, at most T) and, as padding, the lowest rows it does not
+    occupy. Of the first T + n rows at most n are occupied, so they hold
+    the T - n padding rows."""
+    free = np.ones(T + len(slots), bool)
+    free[slots[slots < len(free)]] = False
+    pad = np.flatnonzero(free)[:T - len(slots)]
+    return np.sort(np.concatenate([slots, pad])).astype(np.int32)
+
+
+def _tile_step_for(K: int, use_pallas: bool = False,
+                   pallas_interpret: bool = False):
+    """The packed step on a tile of T farm rows: gather the rows' state,
+    unpack the int16 [T, K, F] wave and its [T, 2] bases as the full step
+    does, apply, zamboni, and scatter the rows back into the donated
+    farm. A row the wave does not occupy comes out of the full step
+    bit-identical (NOOP ops; its zamboni floor lies below every remove
+    seq), so stepping the occupied rows, padded to T with unoccupied
+    ones, gives the same farm for T rows of work instead of D. One
+    compile per (D, T, K)."""
+    key = ("tile", K, use_pallas, pallas_interpret)
+    fn = _DENSE_STEP_CACHE.get(key)
+    if fn is None:
+        apply_fn = _farm_apply(use_pallas, pallas_interpret)
+        at = dict(indices_are_sorted=True, unique_indices=True,
+                  mode="promise_in_bounds")
+
+        def tile_step(state, rows, wave16, bases):
+            _count_trace("tile_step", f"{rows.shape[0]}x{K}")
+            tile = jax.tree.map(lambda a: a.at[rows].get(**at), state)
+            wave = unpack_wave16(wave16, bases)
+            tile = compact_batch(apply_fn(tile, wave), wave_min_seq(wave))
+            return jax.tree.map(lambda a, t: a.at[rows].set(t, **at),
+                                state, tile), {}
+
+        from ..parallel.sharded_apply import donation_supported
+
+        fn = jax.jit(tile_step,
+                     donate_argnums=(0,) if donation_supported() else ())
+        _DENSE_STEP_CACHE[key] = fn
     return fn
 
 
@@ -387,6 +446,42 @@ register_kernel_contract(
 )
 
 
+def _contract_build_tile(use_pallas: bool = False):
+    """The tile step at a small fixed geometry: 8 rows of a 16-row farm
+    (on the Pallas lane in interpret mode, as the dense twin's)."""
+    D, T, K = 16, 8, 4
+    fn = _tile_step_for(K, use_pallas=use_pallas,
+                        pallas_interpret=use_pallas)
+
+    def example():
+        S = 16
+        state = jax.vmap(lambda _: DocState.empty(S))(jnp.arange(D))
+        rows = jnp.arange(1, D, 2, dtype=jnp.int32)
+        wave16 = jnp.zeros((T, K, OP_FIELDS), jnp.int16)
+        bases = jnp.zeros((T, 2), jnp.int32)
+        return (state, rows, wave16, bases), {}
+
+    return fn, example
+
+
+# contract: the tile step is the packed step on T gathered rows, under
+# the same wire rules; its gathers are zamboni's repack (10) plus one
+# row gather per DocState field (12), its scatters one row scatter per
+# field back into the farm (12), all once per wave
+for _name, _pallas in (("service.tile_step_packed", False),
+                       ("service.tile_step_packed_pallas", True)):
+    register_kernel_contract(
+        _name,
+        build=partial(_contract_build_tile, use_pallas=_pallas),
+        max_gathers=22,
+        max_scatters=12,
+        no_int16_arithmetic=True,
+        single_jit=True,
+        notes="int16 packed wave on a gathered tile of farm rows: row "
+              "gather + apply + fused zamboni + row scatter-back",
+    )
+
+
 def channel_stream(server, tenant_id: str, document_id: str,
                    ds_id: str, channel_id: str, from_seq: int = 0):
     """Extract one channel's merge-tree messages from the document's
@@ -544,6 +639,11 @@ class TpuDocumentApplier:
         # step, not its device time (that is in the profiler's trace)
         self.dispatch_seconds = 0.0
         self.waves_staged = 0
+        # the local farm's waves by step: the tile step's or the full
+        # one's, the rows they occupied and the rows the steps covered
+        self.waves_tiled = self.waves_full = 0
+        self.wave_rows = self.wave_rows_padded = 0
+        self._tiles: tuple = ()
         self._registry = None
         # contract-kernel selection: auto = Pallas on real TPU, XLA scan
         # elsewhere; dense lane's tile is max_docs (= slots_per_shard of
@@ -582,10 +682,14 @@ class TpuDocumentApplier:
             self._zero_shards: dict = {}
         else:
             # dense dispatch: ship the padded [D, K, F] wave packed to
-            # int16 deltas (see _dense_step_for for the wire format and
-            # why device-side scatter lost)
+            # int16 deltas (see _dense_step_for for the wire format), or,
+            # where it occupies at most a tile's rows, only those rows
             self._dense_step = _dense_step_for(
                 max_docs, self.K, use_pallas=use_pallas,
+                pallas_interpret=pallas_interpret)
+            self._tiles = _tile_sizes(max_docs)
+            self._tile_step = _tile_step_for(
+                self.K, use_pallas=use_pallas,
                 pallas_interpret=pallas_interpret)
         self._init_long_tier(long_docs, long_slots, use_pallas,
                              pallas_interpret)
@@ -654,9 +758,9 @@ class TpuDocumentApplier:
                                          pallas_interpret)
 
     def warm_long_tier(self) -> None:
-        """Compile the tier's step, the promotion and the farm's packed
-        step on throwaway state of the same shapes, so that nothing
-        compiles when a doc first grows past the farm."""
+        """Compile the tier's step and the promotion on throwaway state
+        of the same shapes, so that nothing compiles when a doc first
+        grows past the farm."""
         if self.long_state is None:
             return
         L, D, K = self.long_docs, self.max_docs, self.K
@@ -667,10 +771,30 @@ class TpuDocumentApplier:
         farm, long = _promote_fn()(farm, long, 0, 0)
         long = self._long_step(
             long, jax.device_put(np.zeros((L, K, OP_FIELDS), np.int32)))
-        farm, _ = self._dense_step[0](
-            farm, jax.device_put(np.zeros((D, K, OP_FIELDS), np.int16)),
-            jax.device_put(np.zeros((D, 2), np.int32)))
         jax.block_until_ready((farm, long))
+
+    def warm_farm(self) -> None:
+        """Compile the local farm's packed step and its tile steps, so
+        that no wave compiles later, whatever rows it occupies. Each runs
+        an all-NOOP wave on the farm itself, which leaves every row
+        bit-identical (as a row no op touches comes out of a step), so no
+        second farm is held on the device."""
+        if self._mesh is not None:
+            return
+        D, K = self.max_docs, self.K
+        self._drain_device()
+        self.state, _ = self._dense_step[0](
+            self.state,
+            jax.device_put(np.zeros((D, K, OP_FIELDS), np.int16)),
+            jax.device_put(np.zeros((D, 2), np.int32)))
+        for T in self._tiles:
+            self.state, _ = self._tile_step(
+                self.state, jax.device_put(np.arange(T, dtype=np.int32)),
+                jax.device_put(np.zeros((T, K, OP_FIELDS), np.int16)),
+                jax.device_put(np.zeros((T, 2), np.int32)))
+        self._stage_inflight = [None, None]
+        self._exec_marker = self.state.count
+        jax.block_until_ready(self.state)
 
     def _route_parts(self, parts):
         """Split one wave's parts into (farm, long). A farm doc moves to
@@ -1540,16 +1664,25 @@ class TpuDocumentApplier:
                 flat, packed if fits16 else None, doc_idx, pos_idx,
                 slots_a, seq_base, text_base, n)
         elif fits16:
-            wave16 = self._stage_buffer((self.max_docs, K, OP_FIELDS),
+            # the smallest tile that holds the wave's rows, else all D
+            T = next((t for t in self._tiles if t >= len(slots_a)), 0)
+            rows = _tile_rows(slots_a, T) if T else None
+            at = slots_a if rows is None else np.searchsorted(rows, slots_a)
+            wave16 = self._stage_buffer((T or self.max_docs, K, OP_FIELDS),
                                         np.int16)
-            wave16[doc_idx, pos_idx] = packed.astype(np.int16)
-            bases = self._stage_buffer((self.max_docs, 2), np.int32)
-            bases[slots_a, 0] = seq_base
-            bases[slots_a, 1] = text_base
-            staged = _StagedWave(
-                "dense", False,
-                (jax.device_put(wave16), jax.device_put(bases)),
-                n, wave16.nbytes + bases.nbytes)
+            wave16[np.repeat(at, lens_a), pos_idx] = packed.astype(np.int16)
+            bases = self._stage_buffer((T or self.max_docs, 2), np.int32)
+            bases[at, 0] = seq_base
+            bases[at, 1] = text_base
+            arrays = (jax.device_put(wave16), jax.device_put(bases))
+            nbytes = wave16.nbytes + bases.nbytes
+            if rows is None:
+                staged = _StagedWave("dense", False, arrays, n, nbytes)
+            else:
+                staged = _StagedWave("tile", False,
+                                     (jax.device_put(rows),) + arrays, n,
+                                     nbytes + rows.nbytes)
+            self._count_wave(len(slots_a), T)
         else:
             # a field escaped int16 (giant doc, huge window): ship the
             # wave at full width — rare, pays a 2x transfer + one extra
@@ -1559,6 +1692,7 @@ class TpuDocumentApplier:
             wave[doc_idx, pos_idx] = flat
             staged = _StagedWave("dense", True, (jax.device_put(wave),),
                                  n, wave.nbytes)
+            self._count_wave(len(slots_a), 0)
         staged.flip = self._stage_flip
         dt = time.perf_counter() - t0
         # overlap accounting: this stage half counts as HIDDEN time when
@@ -1587,14 +1721,34 @@ class TpuDocumentApplier:
             self.fault_plane("applier.stage.staged", ops=n)
         return staged
 
+    def _count_wave(self, rows: int, tile: int) -> None:
+        """Count one local farm wave: ``rows`` occupied, stepped by the
+        tile step of ``tile`` rows, or by the full step where 0."""
+        reg = self._metrics()
+        if tile:
+            self.waves_tiled += 1
+            reg.inc("applier.wave.tiled")
+        else:
+            self.waves_full += 1
+            reg.inc("applier.wave.full")
+        covered = tile or self.max_docs
+        self.wave_rows += rows
+        self.wave_rows_padded += covered
+        reg.inc("applier.wave.rows", rows)
+        reg.inc("applier.wave.rows_padded", covered)
+
     def _execute_wave(self, staged: _StagedWave) -> int:
         """The DEVICE half: one jitted-step dispatch on already-resident
         buffers. The dispatch is asynchronous — the caller's next stage
         half runs while the device executes."""
         t0 = time.perf_counter()
-        packed_fn, wide_fn = (self._sharded_step if staged.lane == "mesh"
-                              else self._dense_step)
-        fn = wide_fn if staged.wide else packed_fn
+        if staged.lane == "tile":
+            fn = self._tile_step
+        else:
+            packed_fn, wide_fn = (self._sharded_step
+                                  if staged.lane == "mesh"
+                                  else self._dense_step)
+            fn = wide_fn if staged.wide else packed_fn
         self.state, _ = fn(self.state, *staged.arrays)
         self._exec_marker = self.state.count
         # the wave's staging buffers may be reused (and on CPU, where

@@ -21,6 +21,8 @@ must satisfy:
   deliberate once-per-wave gather (zamboni compaction's argsort repack)
   onto the gather-free per-op path. The budget catches a NEW gather
   creeping into the K-amplified part.
+- ``max_scatters`` — the same budget for ``scatter*`` primitives, for
+  kernels that write a gathered tile of rows back once per wave.
 - ``max_dynamic_slices`` — budget for ``dynamic_slice`` equations, the
   second computed-index shape XLA can sink to the slow path.
 - ``no_int16_arithmetic`` — no arithmetic primitive consumes an int16
@@ -60,6 +62,7 @@ class KernelContract:
     no_gather: bool = False
     no_scatter: bool = False
     max_gathers: Optional[int] = None
+    max_scatters: Optional[int] = None
     max_dynamic_slices: Optional[int] = None
     no_int16_arithmetic: bool = False
     single_jit: bool = False
@@ -78,6 +81,7 @@ def kernel_contract(
     no_gather: bool = False,
     no_scatter: bool = False,
     max_gathers: Optional[int] = None,
+    max_scatters: Optional[int] = None,
     max_dynamic_slices: Optional[int] = None,
     no_int16_arithmetic: bool = False,
     single_jit: bool = False,
@@ -95,6 +99,7 @@ def kernel_contract(
             no_gather=no_gather,
             no_scatter=no_scatter,
             max_gathers=max_gathers,
+            max_scatters=max_scatters,
             max_dynamic_slices=max_dynamic_slices,
             no_int16_arithmetic=no_int16_arithmetic,
             single_jit=single_jit,

@@ -4,7 +4,8 @@ Compiles for a described v5e:2x2 (``jax.experimental.topologies``): the
 served packed step and the batched apply on both kernel lanes at
 1024 docs x 256 slots x 64 ops, the 4-chip doc-mesh packed step at
 8192 x 256 x 64, the long tier, the grid cell's cells step, in place,
-and a base's page read of its store. What the chip's compiler would
+a base's page read of its store, and the farm's tile steps at the grid
+cell's 1,200 rows, in place. What the chip's compiler would
 refuse (a Mosaic kernel that does not tile, a program past the chip's
 16 GB) fails here at no chip time. Nothing runs: a compile that passes
 is not a chip run.
@@ -250,3 +251,41 @@ def test_written_cells_reads_a_page_without_copying_the_store(
         *store, sd((GRID_G,))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("tile", [80, 304])
+@pytest.mark.parametrize("lane", ["xla", "pallas"])
+def test_tile_step_compiles_in_place(topo, no_compile_cache, lane, tile):
+    """The farm's tile step at the grid cell's [1200, 256] farm and 32
+    ops a row: the tile's rows gathered, stepped and written back into
+    the donated farm in place. Its scratch is the full step's at T rows
+    and the tile's own rows, far below one copy of the farm."""
+    import jax
+    import jax.numpy as jnp
+
+    from fluidframework_tpu.ops.apply import OP_FIELDS
+    from fluidframework_tpu.service.tpu_applier import (_dense_step_for,
+                                                        _tile_sizes,
+                                                        _tile_step_for)
+
+    assert tile in _tile_sizes(GRID_D)
+    one, sd, _store = _grid_shapes(topo)
+    pallas = lane == "pallas"
+    wave = (sd((tile, 32, OP_FIELDS), jnp.int16), sd((tile, 2)))
+
+    def compiled(fn, n_rows, *args):
+        return jax.jit(fn.__wrapped__, donate_argnums=(0,)).lower(
+            _state_shapes(n_rows, one), *args, *wave).compile()
+
+    farm = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(_state_shapes(GRID_D, one)))
+    step = compiled(_tile_step_for(32, use_pallas=pallas), GRID_D,
+                    sd((tile,)))
+    alone = compiled(_dense_step_for(tile, 32, use_pallas=pallas)[0], tile)
+    assert ("tpu_custom_call" in step.as_text()) == pallas
+    m = step.memory_analysis()
+    # every farm array aliased (the device pads the per-row ones)
+    assert m.alias_size_in_bytes >= farm
+    assert m.temp_size_in_bytes < (
+        alone.memory_analysis().temp_size_in_bytes + farm // 2)
+    assert _device_bytes(step) < HBM_BYTES

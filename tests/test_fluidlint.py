@@ -13,6 +13,7 @@ import importlib.util
 import os
 import subprocess
 import sys
+from dataclasses import replace
 
 import jax.numpy as jnp
 import numpy as np
@@ -189,6 +190,44 @@ def test_pallas_packed_contracts_hold():
     sub = {n: reg[n] for n in names}
     vs = jaxpr_check.check_kernels(registry=sub, required=())
     assert vs == [], [str(v) for v in vs]
+
+
+def test_tile_step_contracts_hold():
+    """The farm's tile step on both lanes: its gathers are zamboni's
+    repack plus one row gather per DocState field, its scatters one row
+    write-back per field, within the declared budgets, and both names
+    are pinned in REQUIRED_KERNELS."""
+    names = ("service.tile_step_packed", "service.tile_step_packed_pallas")
+    reg = jaxpr_check.load_registry()
+    for name in names:
+        assert name in jaxpr_check.REQUIRED_KERNELS, name
+        assert (reg[name].max_gathers, reg[name].max_scatters) == (22, 12)
+    vs = jaxpr_check.check_kernels(registry={n: reg[n] for n in names},
+                                   required=())
+    assert vs == [], [str(v) for v in vs]
+
+
+def test_a_scatter_past_the_budget_fails_lint():
+    """One row write-back too many in the tile step is flagged by
+    ``max_scatters``."""
+    good = jaxpr_check.load_registry()["service.tile_step_packed"]
+
+    def regressed_build():
+        fn, example = good.build()
+
+        def regressed(state, rows, wave16, bases):
+            state, stats = fn(state, rows, wave16, bases)
+            return replace(state, count=state.count.at[rows].add(0)), stats
+
+        return regressed, example
+
+    sub: dict = {}
+    register_kernel_contract(
+        "fixture.tile_scatter", build=regressed_build, max_scatters=12,
+        registry=sub)
+    vs = jaxpr_check.check_kernels(registry=sub, required=())
+    assert len(vs) == 1 and "over the budget of 12" in vs[0].message, \
+        [str(v) for v in vs]
 
 
 def test_pallas_contract_regression_fails_lint():

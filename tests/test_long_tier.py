@@ -12,8 +12,8 @@ slots (K = 4, so a doc moves above 8) beside 8 long rows of 256.
 - ``answers.device_texts`` still reads a farm-only checkpoint, and a
   tiered one's farm docs, as before;
 - a long row past S_long escalates to the host, and the text holds;
-- after ``warm_long_tier`` a promotion and the tier's waves compile
-  nothing.
+- after ``warm_farm`` and ``warm_long_tier`` a promotion and both
+  tiers' waves compile nothing.
 """
 
 import random
@@ -256,6 +256,7 @@ def test_nothing_compiles_after_the_warm_up():
     from fluidframework_tpu.utils.chip import CompileClock
 
     a = TpuDocumentApplier(**GEOMETRY, **TIER)
+    a.warm_farm()
     a.warm_long_tier()
     clock = CompileClock()
     _feed(a, _docs(42))

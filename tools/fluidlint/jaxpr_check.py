@@ -7,7 +7,8 @@ recursively — through scan/while/cond bodies, pjit calls, and
 inside a nested program. Checks:
 
 - forbidden primitives: ``gather`` / ``scatter*`` when the contract bans
-  them, budgets (``max_gathers``, ``max_dynamic_slices``) otherwise;
+  them, budgets (``max_gathers``, ``max_scatters``,
+  ``max_dynamic_slices``) otherwise;
 - dynamic-index ``while`` bodies: a ``gather``/``dynamic_slice`` inside
   a ``while`` is flagged even under a budget — a computed-index read in
   a device loop body is the K-amplified slow path by construction;
@@ -51,6 +52,8 @@ REQUIRED_KERNELS = (
     "parallel.sharded_step_packed_pallas",
     "service.dense_step_packed",
     "service.dense_step_packed_pallas",
+    "service.tile_step_packed",
+    "service.tile_step_packed_pallas",
 )
 
 #: Primitives that do arithmetic (an int16 operand here = silent
@@ -185,6 +188,14 @@ def check_contract(contract) -> list[Violation]:
             path, line,
             "scatter is the TPU slow path; use jnp.where onto a "
             "precomputed mask instead"))
+    elif contract.max_scatters is not None and \
+            scatters > contract.max_scatters:
+        out.append(v(
+            f"jaxpr contains {scatters} scatter primitive(s), over the "
+            f"budget of {contract.max_scatters}",
+            path, line,
+            "a new scatter crept in; keep scatters confined to the "
+            "once-per-wave row write-back"))
     if contract.max_dynamic_slices is not None and \
             dyn > contract.max_dynamic_slices:
         out.append(v(

@@ -169,6 +169,12 @@ LOCKED_FAMILIES = {
                            "applier.stage.bytes",
                            "applier.stage.overlap_ratio",
                            "applier.dispatch.seconds",
+                           # the local farm's waves by step (tile or
+                           # full) and the rows they occupied / covered
+                           "applier.wave.tiled",
+                           "applier.wave.full",
+                           "applier.wave.rows",
+                           "applier.wave.rows_padded",
                            # the long tier's, on the stage's counters
                            # (service/stage_runner.py ApplierStage._count)
                            "applier.promotions",
